@@ -1,8 +1,9 @@
 #!/usr/bin/env bash
 # Multi-process protocol deployment (reference server.py + N client.py
 # parity): one broker, one server, three clients, over real TCP sockets.
-# Runs on CPU so all processes fit on one machine; on TPU hardware, run
-# each client on its own host/chip instead.
+# CPU by choice: an accelerator belongs to one process, and these are
+# five.  (How the protocol path gets one chip for each process is
+# ROADMAP S0/S3; until then it is CPU-only.)
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

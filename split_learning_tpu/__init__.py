@@ -11,7 +11,6 @@ topology.
 
 __version__ = "0.1.0"
 
-import split_learning_tpu.compat  # noqa: F401  (jax.shard_map bridge)
 from split_learning_tpu.planner import (  # noqa: F401
     partition,
     auto_threshold,

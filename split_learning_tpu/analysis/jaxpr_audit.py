@@ -298,13 +298,10 @@ def _audit_update_jaxpr(root: pathlib.Path) -> list[Finding]:
     (JX002), or every round fetches (and pins in the shadow) double
     the bytes the wire carries."""
     import jax
+    import ml_dtypes
     import numpy as np
 
-    try:
-        import ml_dtypes
-        bf16 = np.dtype(ml_dtypes.bfloat16)
-    except ImportError:  # pragma: no cover - jax ships it
-        bf16 = np.dtype(np.float16)
+    bf16 = np.dtype(ml_dtypes.bfloat16)
     from split_learning_tpu.runtime.aggregate import (
         MeshFoldBackend, _StageFold,
     )

@@ -8,13 +8,14 @@ the gap the jaxpr audit leaves open — JX001-007 prove the hot path has
 no host round-trips, but nothing proved the kernels the config claims
 are on actually ARE the compiled path.
 
-PK001: for every kernel the plane can enable, trace the REAL hot-path
-entry point with that kernel enabled and require a ``pallas_call``
-primitive somewhere in the jaxpr (recursing through sub-jaxprs, so
-jit/custom-vjp wrapping doesn't hide it):
+PK001 has two halves.  The dispatch half: for every kernel the plane
+can enable, trace the REAL hot-path entry point with that kernel
+enabled and require a ``pallas_call`` primitive somewhere in the jaxpr
+(recursing through sub-jaxprs, so jit/custom-vjp wrapping doesn't hide
+it):
 
 * quantize — ``_quantize_dev`` (int8 and the int4 nibble-pack shape)
-  with a kernel block;
+  with the kernel on;
 * dequantize — ``_dequantize_dev`` mirror;
 * stage_update — a real :class:`MeshFoldBackend` built with
   ``stage_update`` enabled, driven through ``stage_update`` exactly
@@ -26,12 +27,27 @@ jit/custom-vjp wrapping doesn't hide it):
 
 :func:`check_lowering` is a pure jaxpr->findings helper so the
 negative test can prove the gate actually fires on a pallas-free
-program.  Requires tracing (jax): a ``--no-trace`` run skips this
-analyzer entirely.
+program.
+
+The lowering half: a ``pallas_call`` in the jaxpr says nothing about
+whether Mosaic accepts it — every quantize/dequantize variant and the
+flash kernels sat behind a green dispatch half while their block
+shapes were illegal on TPU.  So every kernel in
+:func:`lowering_cases` — the shapes ``chip_smoke.py`` runs on the chip
+— is lowered for TPU with ``interpret=False``
+(``jit(f).trace(...).lower(lowering_platforms=("tpu",))``): the
+Pallas-to-Mosaic lowering is Python and needs no TPU, so a refused
+block shape or an op Mosaic has no rule for is a finding on any host.
+It is evidence that a kernel lowers, never that it runs; the chip run
+is ``chip_smoke.py``.
+
+Requires tracing (jax): a ``--no-trace`` run skips this analyzer
+entirely.
 """
 
 from __future__ import annotations
 
+import functools
 import pathlib
 
 from split_learning_tpu.analysis.findings import Finding
@@ -39,6 +55,18 @@ from split_learning_tpu.analysis.findings import Finding
 _REL_QUANT = "split_learning_tpu/runtime/codec/quant.py"
 _REL_AGG = "split_learning_tpu/runtime/aggregate.py"
 _REL_FLASH = "split_learning_tpu/ops/flash_attention.py"
+_REL_KQUANT = "split_learning_tpu/ops/kernels/quant.py"
+_REL_KUPDATE = "split_learning_tpu/ops/kernels/update.py"
+
+#: (B, S, H, D) the flash kernels are held to: TinyLlama's 32 x 64
+#: heads and the 16 x 128 heads of ROADMAP R1, at sequence 2048
+FLASH_SHAPES = ((2, 2048, 32, 64), (2, 2048, 16, 128))
+#: one microbatch of the VGG16 cut-7 boundary (configs/baseline1.yaml):
+#: the activation the codec quantizes, and its gradient
+CUT7_BOUNDARY = (32, 16, 16, 64)
+#: codec tiles: the smoke's ``int8:64`` / ``int4:64`` and the spec
+#: grammar's default
+QUANT_TILES = (64, 256)
 
 
 def contains_pallas_call(jaxpr) -> bool:
@@ -83,7 +111,85 @@ def check_lowering(jaxpr, rel: str, where: str) -> list[Finding]:
         "fell back to the XLA chain")]
 
 
-def _check_codec_kernels(block: int) -> list[Finding]:
+def lowering_cases() -> list[tuple]:
+    """``(name, rel, fn, abstract_args)`` for every Pallas kernel at
+    the shapes the chip smoke runs: flash forward and backward, the
+    quantize/dequantize passes over the cut-7 boundary, and both stage
+    updates over every distinct leaf shape of the VGG16 tree (conv
+    kernels and 1-D biases included)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from split_learning_tpu.models import build_model
+    from split_learning_tpu.ops.flash_attention import flash_attention
+    from split_learning_tpu.ops.kernels import quant as kquant
+    from split_learning_tpu.ops.kernels import update as kupd
+
+    def abstract(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    cases: list[tuple] = []
+    for shape in FLASH_SHAPES:
+        qkv = (abstract(shape, jnp.bfloat16),) * 3
+        cases.append((f"flash_fwd{shape}", _REL_FLASH, flash, qkv))
+        cases.append((
+            f"flash_bwd{shape}", _REL_FLASH,
+            jax.grad(lambda q, k, v: flash(q, k, v).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)), qkv))
+    n = int(np.prod(CUT7_BOUNDARY))
+    for tile in QUANT_TILES:
+        t = n // tile
+        for bits in (8, 4):
+            cases.append((
+                f"quantize_int{bits}:{tile}", _REL_KQUANT,
+                functools.partial(kquant.quantize_tiles, bits=bits,
+                                  interpret=False),
+                (abstract((t, tile)),)))
+        cases.append((
+            f"dequantize:{tile}", _REL_KQUANT,
+            functools.partial(kquant.dequantize_tiles, interpret=False),
+            (abstract((t, tile), jnp.int8), abstract((t,)))))
+    model = build_model("VGG16_CIFAR10")
+    tree = jax.eval_shape(
+        lambda: model.init(jax.random.key(0),
+                           jnp.zeros((1, 32, 32, 3)), train=False))
+    shapes = sorted({tuple(leaf.shape) for leaf in
+                     jax.tree_util.tree_leaves(tree["params"])})
+    scalar = abstract(())
+    for shape in shapes:
+        leaf = abstract(shape)
+        cases.append((
+            f"finalize_leaf{shape}", _REL_KUPDATE,
+            lambda a, tw: kupd.finalize_leaf(a, tw, jnp.bfloat16,
+                                             interpret=False),
+            (leaf, scalar)))
+        cases.append((
+            f"momentum_leaf{shape}", _REL_KUPDATE,
+            lambda a, b, v, tw, m: kupd.momentum_leaf(
+                a, b, v, tw, m, jnp.float32, interpret=False),
+            (leaf, leaf, leaf, scalar, scalar)))
+    return cases
+
+
+def check_tpu_lowering(name: str, rel: str, fn, args) -> list[Finding]:
+    """PK001 on one kernel: it must lower for TPU natively."""
+    import jax
+    try:
+        jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    except Exception as e:  # noqa: BLE001 — any refusal is the finding
+        first = (str(e).strip().splitlines() or [type(e).__name__])[0]
+        return [Finding(
+            "PK001", rel, 0, name,
+            f"kernel {name!r} does not lower for TPU "
+            f"(interpret=False): {type(e).__name__}: {first[:300]}")]
+    return []
+
+
+def _check_codec_kernels() -> list[Finding]:
     import jax
     import numpy as np
 
@@ -96,7 +202,7 @@ def _check_codec_kernels(block: int) -> list[Finding]:
     for bits, tile in ((8, 64), (4, 7)):
         jaxpr = jax.make_jaxpr(
             lambda a, b=bits, t=tile: _quantize_dev(
-                a, t, b, kernel_block=block))(x)
+                a, t, b, kernel=True))(x)
         findings += check_lowering(jaxpr, _REL_QUANT,
                                    f"quantize:int{bits}")
     # mirror: well-formed tiled codes for both widths
@@ -106,14 +212,14 @@ def _check_codec_kernels(block: int) -> list[Finding]:
                          // tile,), np.float32)
         jaxpr = jax.make_jaxpr(
             lambda q, s, b=bits, t=tile: _dequantize_dev(
-                q, s, t, b, 160, (160,), kernel_block=block))(
+                q, s, t, b, 160, (160,), kernel=True))(
             codes, scale)
         findings += check_lowering(jaxpr, _REL_QUANT,
                                    f"dequantize:int{bits}")
     return findings
 
 
-def _check_stage_update_kernel(block: int) -> list[Finding]:
+def _check_stage_update_kernel() -> list[Finding]:
     """Build a mesh backend with the stage-update kernel enabled,
     drive one real stage_update (compiling + caching its fused
     program), then trace each cached program and require the
@@ -127,8 +233,7 @@ def _check_stage_update_kernel(block: int) -> list[Finding]:
     )
 
     findings: list[Finding] = []
-    be = MeshFoldBackend(kernels=KernelPlan(stage_update=True,
-                                            block=block))
+    be = MeshFoldBackend(kernels=KernelPlan(stage_update=True))
     st = _StageFold(["c0"])
     st.dtype = {"layer0/k": np.dtype(np.float32),
                 "layer0/step": np.dtype(np.int32)}
@@ -178,9 +283,9 @@ def _check_flash_lowering() -> list[Finding]:
 def run(root: pathlib.Path, trace: bool = True) -> list[Finding]:
     if not trace:
         return []
-    from split_learning_tpu.config import KernelsConfig
-    block = KernelsConfig().block
-    findings = _check_codec_kernels(block)
-    findings += _check_stage_update_kernel(block)
+    findings = _check_codec_kernels()
+    findings += _check_stage_update_kernel()
     findings += _check_flash_lowering()
+    for case in lowering_cases():
+        findings += check_tpu_lowering(*case)
     return findings

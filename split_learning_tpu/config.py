@@ -853,12 +853,6 @@ class KernelsConfig:
     # fused FedAvg divide + FedAvgM momentum + wire-dtype cast inside
     # the sharded round-boundary update (aggregation.sharded)
     stage_update: bool = False
-    # grid block target (tiles per quantize instance / axis-0 rows per
-    # update instance); auto-shrunk to the largest exact divisor
-    block: int = 128
-
-    def validate(self):
-        _check(self.block >= 1, "kernels.block must be >= 1")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -872,11 +866,6 @@ class Config:
     debug: bool = False
     log_path: str = "."
     compute_dtype: str = "bfloat16"     # bfloat16 | float32
-    # Persistent XLA compilation cache directory (default off): every
-    # entry point applies it via platform.apply_compile_cache, so a
-    # restarted process (the protocol deployment's cold round) reuses
-    # compiled programs instead of re-paying the compile tax.
-    compile_cache_dir: str | None = None
     model_kwargs: Any = None            # overrides for the model builder
     synthetic_size: int | None = None   # force synthetic datasets (tests)
     val_batch_size: int = 200
@@ -914,7 +903,7 @@ class Config:
         for sub in (self.learning, self.distribution, self.topology,
                     self.aggregation, self.transport, self.broker,
                     self.chaos, self.observability, self.perf,
-                    self.scheduler, self.pipeline, self.kernels):
+                    self.scheduler, self.pipeline):
             sub.validate()
         if self.scheduler.enabled:
             # the scheduler's only senses are the fleet-telemetry

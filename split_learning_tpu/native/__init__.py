@@ -4,8 +4,9 @@ toolchain.
 ``NativeBroker`` wraps ``src/broker.cpp`` (shipped as package data so
 installed distributions can build it too) — the framework's native
 message broker (the role RabbitMQ plays for the reference,
-``/root/reference/README.md:43-69``): compile (cached by source mtime),
-spawn as a subprocess, parse the bound port, and manage lifetime.  The
+``/root/reference/README.md:43-69``): compile (cached by a hash of
+the source and the build command), spawn as a subprocess, parse the
+bound port, and manage lifetime.  The
 Python ``TcpTransport`` speaks to it unchanged; ``python -m
 split_learning_tpu.broker`` prefers it and falls back to the threaded
 Python broker when no compiler is available.
@@ -17,6 +18,7 @@ Built artifacts go next to the sources when that directory is writable
 
 from __future__ import annotations
 
+import hashlib
 import os
 import pathlib
 import shutil
@@ -60,22 +62,38 @@ def _compiler() -> str:
 
 def _build(src: pathlib.Path, dest: pathlib.Path,
            extra: list | None = None, force: bool = False) -> pathlib.Path:
-    """Compile ``src`` -> ``dest`` unless the cached artifact is fresh."""
+    """Compile ``src`` -> ``dest`` unless ``dest`` was built from this
+    very source with these flags.
+
+    The artifact directory is not under version control, and a copied
+    or checked-out tree does not keep mtimes, so freshness is keyed on
+    a hash (source bytes + flags) kept in a sidecar next to the
+    artifact: a binary of unknown origin is rebuilt, never run."""
     if not src.exists():
         raise NativeBuildError(f"missing source {src}")
-    if not force and dest.exists() \
-            and dest.stat().st_mtime >= src.stat().st_mtime:
+    flags = ["-O2", "-std=c++17", *(extra or [])]
+    digest = hashlib.sha256(
+        src.read_bytes() + "\0".join(flags).encode()).hexdigest()
+    stamp = dest.with_name(dest.name + ".srchash")
+    if not force and dest.exists() and stamp.exists() \
+            and stamp.read_text().strip() == digest:
         return dest
     try:
         _BIN_DIR.mkdir(parents=True, exist_ok=True)
     except OSError as e:
         raise NativeBuildError(f"cannot create bin dir {_BIN_DIR}: {e}")
-    cmd = [_compiler(), "-O2", "-std=c++17", *(extra or []),
-           "-o", str(dest), str(src)]
+    # build beside the target and rename into place: a concurrent
+    # builder (several test processes share the directory) never
+    # executes a half-written binary
+    tmp = dest.with_name(f"{dest.name}.{os.getpid()}.tmp")
+    cmd = [_compiler(), *flags, "-o", str(tmp), str(src)]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
+        tmp.unlink(missing_ok=True)
         raise NativeBuildError(
             f"build of {src.name} failed:\n{proc.stderr[-2000:]}")
+    os.replace(tmp, dest)
+    stamp.write_text(digest + "\n")
     return dest
 
 

@@ -98,12 +98,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     # logsumexp of the SCALED scores: exp(s - lse) rebuilds softmax rows
     # exactly in the backward kernels
-    lse_ref[0] = (m + jnp.log(l_safe))[:, 0]
+    lse_ref[0] = m + jnp.log(l_safe)
 
 
 def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
                     block_q: int, block_k: int):
-    """(BH, S, D) flattened forward via pallas_call -> (o, lse)."""
+    """(BH, S, D) flattened forward via pallas_call -> (o, lse).
+
+    ``lse`` (and the backward's ``delta``) are per-row values kept as
+    ``(BH, S, 1)`` columns: a ``(block_q, 1)`` block is legal on TPU
+    where a ``(1, block_q)`` slice of a ``(BH, S)`` array is not, and
+    it is the shape the kernels' row statistics already have."""
     bh, s, d = q.shape
     scale = 1.0 / np.sqrt(d)
     grid = (bh, s // block_q)
@@ -114,7 +119,7 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
     return pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((bh, s), jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -122,8 +127,9 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
             pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, block_q), lambda b, i: (b, i))],
+                   pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))],
         interpret=interpret,
+        name="slt_flash_fwd",
     )(q, k, v)
 
 
@@ -147,8 +153,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk, dv = carry
         q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
         do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q)]
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q)]
+        lse = lse_ref[0, pl.ds(qb * block_q, block_q), :]   # (bq, 1)
+        delta = delta_ref[0, pl.ds(qb * block_q, block_q), :]
         s = _dot(q, k, ((1,), (1,)), precision) * scale  # (bq, bk)
         if causal:
             q_pos = qb * block_q + jax.lax.broadcasted_iota(
@@ -156,10 +162,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                  # exact softmax rows
+        p = jnp.exp(s - lse)                           # exact softmax rows
         dv_new = dv + _dot(p, do, ((0,), (0,)), precision)
         dp = _dot(do, v, ((1,), (1,)), precision)      # (bq, bk)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dk_new = dk + _dot(ds, q, ((0,), (0,)), precision)
         return dk_new, dv_new
 
@@ -176,7 +182,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)                   # (block_q, D)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
+    lse = lse_ref[0]                                   # (block_q, 1)
     delta = delta_ref[0]
     s_total = k_ref.shape[1]
     nk = s_total // block_k
@@ -194,9 +200,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = _dot(do, v, ((1,), (1,)), precision)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         return dq + _dot(ds, k, ((1,), (0,)), precision)
 
     dq = jax.lax.fori_loop(0, nk_eff, body,
@@ -221,10 +227,12 @@ def _flash_bwd_rule(causal, interpret, block_q, block_k, res, do):
     scale = 1.0 / np.sqrt(d)
     precision = _pick_precision(q.dtype)
     # delta = rowsum(dO * O): cheap elementwise pre-pass, XLA fuses it
-    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(axis=-1)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        axis=-1, keepdims=True)
 
     full = pl.BlockSpec((1, s, d), lambda b, j: (b, 0, 0))
-    row_full = pl.BlockSpec((1, s), lambda b, j: (b, 0))
+    row_full = pl.BlockSpec((1, s, 1), lambda b, j: (b, 0, 0))
+    row_q = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q,
@@ -240,6 +248,7 @@ def _flash_bwd_rule(causal, interpret, block_q, block_k, res, do):
         out_specs=[pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
                    pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0))],
         interpret=interpret,
+        name="slt_flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     dq = pl.pallas_call(
@@ -251,10 +260,10 @@ def _flash_bwd_rule(causal, interpret, block_q, block_k, res, do):
         in_specs=[pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
                   full, full,
                   pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-                  pl.BlockSpec((1, block_q), lambda b, i: (b, i))],
+                  row_q, row_q],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         interpret=interpret,
+        name="slt_flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     return dq, dk, dv

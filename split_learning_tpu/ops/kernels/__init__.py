@@ -37,21 +37,19 @@ import contextlib
 import dataclasses
 
 from split_learning_tpu.ops.kernels.util import (  # noqa: F401
-    pick_block, pick_pair_block, resolve_interpret,
+    pick_block, resolve_interpret, vmem_block,
 )
 
 __all__ = ["KernelPlan", "DISABLED", "as_plan", "configure", "plan",
-           "override", "pick_block", "pick_pair_block",
-           "resolve_interpret"]
+           "override", "pick_block", "resolve_interpret", "vmem_block"]
 
 
 @dataclasses.dataclass(frozen=True)
 class KernelPlan:
-    """Which Pallas kernels are live, and their grid block target."""
+    """Which Pallas kernels are live."""
     quantize: bool = False
     dequantize: bool = False
     stage_update: bool = False
-    block: int = 128
 
     @property
     def any(self) -> bool:
@@ -74,8 +72,7 @@ def as_plan(obj) -> KernelPlan:
     return KernelPlan(
         quantize=bool(getattr(obj, "quantize", False)),
         dequantize=bool(getattr(obj, "dequantize", False)),
-        stage_update=bool(getattr(obj, "stage_update", False)),
-        block=int(getattr(obj, "block", 128)))
+        stage_update=bool(getattr(obj, "stage_update", False)))
 
 
 def configure(obj) -> KernelPlan:

@@ -14,9 +14,10 @@ These kernels collapse each leaf's finish into one VMEM-resident pass:
 
 The op order inside the kernel matches the jnp oracle exactly, so mesh
 and host folds stay bit-identical on CPU (the 2-round velocity-carry
-parity test pins it).  Leaves are viewed as ``(d0, rest)`` — axis 0
-preserved — and the grid blocks along axis 0, composing with the
-ZeRO-style leaf-axis-0 ``agg`` sharding the backend applies.  The
+parity test pins it).  The math is elementwise, so a leaf is viewed as
+``(rows, last_dim)`` and gridded in blocks sized by
+:func:`~.util.vmem_block`: legal on TPU for every leaf shape (conv
+kernels, 1-D biases) and bounded in VMEM however large the leaf.  The
 jit/donation wrapper stays in ``runtime/aggregate.py`` (JX007 audits
 it there); these are pure per-leaf ops traced into that program.
 
@@ -34,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from split_learning_tpu.ops.kernels.util import (
-    pick_block, resolve_interpret,
+    resolve_interpret, vmem_block,
 )
 
 
@@ -45,9 +46,17 @@ def kernel_ok(leaf) -> bool:
 
 
 def _rows(x):
-    """Leaf -> (d0, rest) view: axis 0 (the ``agg`` shard axis) kept,
-    the rest flattened."""
-    return x.reshape(x.shape[0], -1)
+    """Leaf -> (rows, last_dim) view: the minor axis stays on the
+    lanes, everything before it is flattened."""
+    return x.reshape(-1, x.shape[-1])
+
+
+def _grid(x):
+    """(grid, leaf BlockSpec, scalar BlockSpec) for a 2-D operand."""
+    br, bc = vmem_block(*x.shape)
+    grid = (pl.cdiv(x.shape[0], br), pl.cdiv(x.shape[1], bc))
+    return (grid, pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+            pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
 
 
 def _finalize_kernel(acc_ref, tw_ref, out_ref, *, rnd: bool):
@@ -58,22 +67,21 @@ def _finalize_kernel(acc_ref, tw_ref, out_ref, *, rnd: bool):
 
 
 def finalize_leaf(acc, tw, dtype, *, rnd: bool = False,
-                  block: int = 128, interpret: bool | None = None):
+                  interpret: bool | None = None):
     """``(acc / tw)`` (+ round for int wire dtypes) cast to ``dtype``,
     one pass."""
     interpret = resolve_interpret(interpret)
     x = _rows(acc)
-    d0, rest = x.shape
-    b = pick_block(d0, block)
+    grid, leaf2, scalar = _grid(x)
     tw2 = jnp.reshape(tw, (1, 1)).astype(jnp.float32)
     out = pl.pallas_call(
         functools.partial(_finalize_kernel, rnd=rnd),
-        out_shape=jax.ShapeDtypeStruct((d0, rest), dtype),
-        grid=(d0 // b,),
-        in_specs=[pl.BlockSpec((b, rest), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((b, rest), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, dtype),
+        grid=grid,
+        in_specs=[leaf2, scalar],
+        out_specs=leaf2,
         interpret=interpret,
+        name="slt_finalize_update",
     )(x, tw2)
     return out.reshape(acc.shape)
 
@@ -86,25 +94,23 @@ def _momentum_kernel(acc_ref, base_ref, vel_ref, tw_ref, m_ref,
     p_ref[...] = (base_ref[...] - nv).astype(p_ref.dtype)
 
 
-def momentum_leaf(acc, base, vel, tw, m, dtype, *, block: int = 128,
+def momentum_leaf(acc, base, vel, tw, m, dtype, *,
                   interpret: bool | None = None):
     """FedAvgM finish for one leaf: returns ``(params.astype(dtype),
     new_velocity f32)`` in one pass, oracle op order."""
     interpret = resolve_interpret(interpret)
     x = _rows(acc)
-    d0, rest = x.shape
-    b = pick_block(d0, block)
-    leaf2 = pl.BlockSpec((b, rest), lambda i: (i, 0))
-    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    grid, leaf2, scalar = _grid(x)
     tw2 = jnp.reshape(tw, (1, 1)).astype(jnp.float32)
     m2 = jnp.reshape(m, (1, 1)).astype(jnp.float32)
     p, nv = pl.pallas_call(
         _momentum_kernel,
-        out_shape=[jax.ShapeDtypeStruct((d0, rest), dtype),
-                   jax.ShapeDtypeStruct((d0, rest), jnp.float32)],
-        grid=(d0 // b,),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, dtype),
+                   jax.ShapeDtypeStruct(x.shape, jnp.float32)],
+        grid=grid,
         in_specs=[leaf2, leaf2, leaf2, scalar, scalar],
         out_specs=[leaf2, leaf2],
         interpret=interpret,
+        name="slt_momentum_update",
     )(x, _rows(base), _rows(vel), tw2, m2)
     return p.reshape(acc.shape), nv.reshape(acc.shape)

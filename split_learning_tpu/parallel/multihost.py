@@ -54,7 +54,7 @@ def ensure_initialized(topo: HostTopology | None = None) -> bool:
     topo = topo or HostTopology.from_env()
     if topo.coordinator is None or topo.num_processes <= 1:
         return False
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return True
     jax.distributed.initialize(
         coordinator_address=topo.coordinator,

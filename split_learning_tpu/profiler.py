@@ -126,8 +126,6 @@ def profile_model(model_key: str, batch_size: int = 32,
         fn = jax.jit(lambda v, x, m=m: m.apply(v, x, train=False))
         if method == "flops":
             cost = fn.lower(sub, x_in).compile().cost_analysis()
-            if isinstance(cost, (list, tuple)):   # jax < 0.5 spelling
-                cost = cost[0] if cost else {}
             flops = float((cost or {}).get("flops", 0.0))
             # param-free reshapes report 0 flops; floor at bytes-touched
             # so no layer is free (the planner divides by these)
@@ -194,8 +192,11 @@ def write_profile(path: str, profile: dict) -> None:
 
 
 def main(argv=None):
-    from split_learning_tpu.platform import apply_platform_env
+    from split_learning_tpu.platform import (
+        apply_compile_cache, apply_platform_env,
+    )
     apply_platform_env()
+    apply_compile_cache()
     ap = argparse.ArgumentParser(
         description="Profile a model + link for the partition planner "
                     "(reference profiling.py parity).")

@@ -58,16 +58,17 @@ def run_local(cfg: Config, devices=None,
         ctx.shutdown()
 
 
-def main(argv=None):
-    from split_learning_tpu.platform import apply_platform_env
+def main(argv=None) -> int:
+    from split_learning_tpu.platform import (
+        apply_compile_cache, apply_platform_env,
+    )
     apply_platform_env()
+    apply_compile_cache()
     ap = argparse.ArgumentParser(
         description="Run a full split-learning training cell in-process.")
     ap.add_argument("--config", default="config.yaml")
     args = ap.parse_args(argv)
     cfg = from_yaml(args.config)
-    from split_learning_tpu.platform import apply_compile_cache
-    apply_compile_cache(cfg.compile_cache_dir)
     from split_learning_tpu.runtime import blackbox
     blackbox.install(cfg, "server", role="server")
     result = run_local(cfg)
@@ -76,7 +77,10 @@ def main(argv=None):
                if rec.val_accuracy is not None else "")
         print(f"round {rec.round_idx}: ok={rec.ok} "
               f"samples={rec.num_samples}{acc}")
+    # the loop records a diverged round and carries on (reference
+    # semantics); the process must still say that one happened
+    return 0 if all(rec.ok for rec in result.history) else 1
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

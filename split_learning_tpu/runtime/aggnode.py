@@ -405,7 +405,7 @@ class AggregatorNode:
                         # avoid by owning their own stacks).  The
                         # cascade is bounded (FLUSH_GRACE_S per level
                         # + publish time), so 60 s only fails on a
-                        # wedged transport — then folding the new gen
+                        # stuck transport — then folding the new gen
                         # is impossible anyway: drop the assignment
                         # and let the server's fallback drain recover.
                         worker.flush.set()
@@ -464,13 +464,15 @@ def write_node_config(cfg: Config, path) -> None:
 
 def spawn_node(config_path, node_id: str):
     """Spawn one aggregator subprocess (tcp transport).  The node is
-    host-only — JAX_PLATFORMS is pinned to cpu unless the caller set
-    it — and inherits stdio so its tracebacks surface in CI logs."""
+    host-only, so its JAX_PLATFORMS is cpu whatever the parent's is (a
+    child that inherited the parent's accelerator platform would
+    contend for a chip the parent holds); it inherits stdio so its
+    tracebacks surface in CI logs."""
     import os
     import subprocess
     import sys
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [sys.executable, "-m", "split_learning_tpu.aggregator",
          "--config", str(config_path), "--node-id", node_id], env=env)

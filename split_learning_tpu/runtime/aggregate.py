@@ -320,13 +320,12 @@ class MeshFoldBackend:
                     if path in mom_paths:
                         p, nv = kupd.momentum_leaf(
                             acc[path], base[path], vel[path], tw, m,
-                            dt, block=kplan.block)
+                            dt)
                         nvel[path] = nv
                         params[path] = p
                     else:
                         params[path] = kupd.finalize_leaf(
-                            acc[path], tw, dt, rnd=_is_int_dtype(dt),
-                            block=kplan.block)
+                            acc[path], tw, dt, rnd=_is_int_dtype(dt))
                     continue
                 a32 = acc[path] / tw
                 if path in mom_paths:
@@ -343,7 +342,7 @@ class MeshFoldBackend:
                         stat_acc[path]):
                     stats[path] = kupd.finalize_leaf(
                         stat_acc[path], stat_tw, dt,
-                        rnd=_is_int_dtype(dt), block=kplan.block)
+                        rnd=_is_int_dtype(dt))
                     continue
                 s32 = stat_acc[path] / stat_tw
                 stats[path] = (jnp.round(s32).astype(dt)

@@ -21,7 +21,7 @@ process killed at ANY point leaves either the previous complete
 checkpoint or the new complete checkpoint visible, never a torn one;
 :func:`load_checkpoint` additionally treats an unreadable/truncated
 checkpoint as absent (warn + ``None``) instead of raising, so a corrupt
-file can never wedge a restart.
+file can never block a restart.
 """
 
 from __future__ import annotations

@@ -2320,8 +2320,11 @@ class ProtocolClient:
 
 
 def main(argv=None):
-    from split_learning_tpu.platform import apply_platform_env
+    from split_learning_tpu.platform import (
+        apply_compile_cache, apply_platform_env,
+    )
     apply_platform_env()
+    apply_compile_cache()
     ap = argparse.ArgumentParser(
         description="Split-learning protocol client (reference client.py "
                     "parity).")
@@ -2334,8 +2337,6 @@ def main(argv=None):
                     help="path to profiling.json (optional)")
     args = ap.parse_args(argv)
     cfg = from_yaml(args.config)
-    from split_learning_tpu.platform import apply_compile_cache
-    apply_compile_cache(cfg.compile_cache_dir)
     profile = None
     if args.profile:
         import json

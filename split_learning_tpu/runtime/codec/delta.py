@@ -37,6 +37,7 @@ from __future__ import annotations
 import threading
 from typing import Any
 
+import ml_dtypes
 import numpy as np
 
 from split_learning_tpu.runtime.codec.quant import (
@@ -45,11 +46,7 @@ from split_learning_tpu.runtime.codec.quant import (
 from split_learning_tpu.runtime.codec.specs import CodecSpec
 from split_learning_tpu.runtime.protocol import QuantLeaf
 
-try:
-    import ml_dtypes as _ml_dtypes
-    _BF16 = np.dtype(_ml_dtypes.bfloat16)
-except ImportError:  # pragma: no cover - jax ships it
-    _BF16 = None
+_BF16 = np.dtype(ml_dtypes.bfloat16)
 
 
 def _tree_map_np(fn, *trees):
@@ -104,12 +101,8 @@ class DeltaCodec:
                     self.faults.inc("quant_nonfinite")
                 sent = dequantize_leaf_np(leaf)
             else:
-                if _BF16 is None:  # pragma: no cover - jax ships it
-                    leaf = d
-                    sent = d
-                else:
-                    leaf = d.astype(_BF16)
-                    sent = np.asarray(leaf, np.float32)
+                leaf = d.astype(_BF16)
+                sent = np.asarray(leaf, np.float32)
             self._res[i] = d - sent
             out.append(leaf)
         return jax.tree_util.tree_unflatten(treedef, out)

@@ -62,17 +62,31 @@ def _qmax(bits: int) -> float:
     return 127.0 if bits == 8 else 7.0
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "bits",
-                                             "kernel_block"))
-def _quantize_dev(x, tile: int, bits: int, kernel_block: int = 0):
+def _pack_nibbles(q):
+    """Flat int8 codes in [-7, 7] -> two's-complement nibbles, two per
+    uint8 byte, lo nibble first."""
+    u = q.astype(jnp.uint8) & 0xF
+    return (u[0::2] | (u[1::2] << 4)).astype(jnp.uint8)
+
+
+def _unpack_nibbles(q):
+    """Mirror of :func:`_pack_nibbles`: flat sign-extended codes."""
+    u = q.astype(jnp.uint8)
+    lo, hi = u & 0xF, u >> 4
+    codes = jnp.stack([lo, hi], axis=-1).reshape(-1)
+    return jnp.where(codes < 8, codes, codes.astype(jnp.int32) - 16)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "bits", "kernel"))
+def _quantize_dev(x, tile: int, bits: int, kernel: bool = False):
     """(codes, per-tile scales) for one float leaf, on device.
 
     Codes are the FLAT padded array: int8 for bits=8; for bits=4 two
     two's-complement nibbles packed per uint8 byte (lo nibble first).
-    ``kernel_block > 0`` routes the tiled math through the fused Pallas
-    kernel (``ops/kernels/quant.py``) — one VMEM-resident pass instead
-    of this chain of full-leaf HBM round-trips; the pad/reshape
-    prologue stays here either way."""
+    ``kernel`` routes the tiled math through the fused Pallas kernel
+    (``ops/kernels/quant.py``) — one VMEM-resident pass instead of
+    this chain of full-leaf HBM round-trips; the pad/reshape prologue
+    and the nibble pack stay here either way."""
     qmax = _qmax(bits)
     flat = x.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
@@ -83,48 +97,41 @@ def _quantize_dev(x, tile: int, bits: int, kernel_block: int = 0):
         pad += tile
     flat = jnp.pad(flat, (0, pad))
     tiles = flat.reshape(-1, tile)
-    if kernel_block:
+    if kernel:
         from split_learning_tpu.ops.kernels.quant import quantize_tiles
-        return quantize_tiles(tiles, bits=bits, block=kernel_block)
-    amax = jnp.max(jnp.abs(tiles), axis=1)
-    scale = jnp.where(jnp.isfinite(amax),
-                      jnp.where(amax > 0, amax / qmax, 1.0),
-                      jnp.nan).astype(jnp.float32)
-    codes = jnp.clip(jnp.round(tiles / scale[:, None]), -qmax, qmax)
-    # NaN codes (non-finite tile: scale is NaN) become 0 — the NaN
-    # scale alone carries the divergence, and int8-casting NaN would be
-    # platform-defined where everything else here is deterministic
-    q = jnp.where(jnp.isfinite(codes), codes, 0.0).astype(jnp.int8)
+        q, scale = quantize_tiles(tiles, bits=bits)
+    else:
+        amax = jnp.max(jnp.abs(tiles), axis=1)
+        scale = jnp.where(jnp.isfinite(amax),
+                          jnp.where(amax > 0, amax / qmax, 1.0),
+                          jnp.nan).astype(jnp.float32)
+        codes = jnp.clip(jnp.round(tiles / scale[:, None]), -qmax, qmax)
+        # NaN codes (non-finite tile: scale is NaN) become 0 — the NaN
+        # scale alone carries the divergence, and int8-casting NaN
+        # would be platform-defined where everything else here is
+        # deterministic
+        q = jnp.where(jnp.isfinite(codes), codes, 0.0).astype(jnp.int8)
     q = q.reshape(-1)
     if bits == 4:
-        u = q.astype(jnp.uint8) & 0xF      # two's-complement nibble
-        q = (u[0::2] | (u[1::2] << 4)).astype(jnp.uint8)
+        q = _pack_nibbles(q)
     return q, scale
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "bits", "n",
-                                             "shape", "kernel_block"))
+                                             "shape", "kernel"))
 def _dequantize_dev(q, scale, tile: int, bits: int, n: int,
-                    shape: tuple, kernel_block: int = 0):
+                    shape: tuple, kernel: bool = False):
+    codes = _unpack_nibbles(q) if bits == 4 else q
     # the fused mirror kernel applies only to well-formed tiled codes
     # (exactly scale.count * tile codes — what OUR quantizers emit);
     # anything ragged keeps the legacy XLA chain below
-    expect = scale.shape[0] * tile // (2 if bits == 4 else 1)
-    if kernel_block and q.shape[0] == expect:
+    if kernel and codes.shape[0] == scale.shape[0] * tile:
         from split_learning_tpu.ops.kernels.quant import (
             dequantize_tiles,
         )
-        out = dequantize_tiles(q, scale, tile=tile, bits=bits,
-                               block=kernel_block)
-        return out[:n].reshape(shape)
-    if bits == 4:
-        u = q.astype(jnp.uint8)
-        lo, hi = u & 0xF, u >> 4
-        codes = jnp.stack([lo, hi], axis=-1).reshape(-1)
-        codes = jnp.where(codes < 8, codes,
-                          codes.astype(jnp.int32) - 16)
-    else:
-        codes = q
+        out = dequantize_tiles(
+            codes.astype(jnp.int8).reshape(-1, tile), scale)
+        return out.reshape(-1)[:n].reshape(shape)
     flat = codes.astype(jnp.float32)
     padded = jnp.pad(flat, (0, (-flat.shape[0]) % tile)) \
         if flat.shape[0] % tile else flat
@@ -153,15 +160,14 @@ class QuantCodec:
             faults = default_fault_counters
         self.faults = faults
 
-    def _kernel_block(self) -> int:
+    def _use_kernel(self) -> bool:
         from split_learning_tpu.ops import kernels as kplane
-        kp = kplane.as_plan(self._kernels)
-        return kp.block if kp.quantize else 0
+        return kplane.as_plan(self._kernels).quantize
 
     def prepare(self, tree, key: str = ""):
         """Device-side stage (training thread): float leaves become
         :class:`DevQuant` holders; int/bool leaves pass through."""
-        kb = self._kernel_block()
+        kernel = self._use_kernel()
 
         def conv(leaf):
             ldt = getattr(leaf, "dtype", None)
@@ -170,7 +176,7 @@ class QuantCodec:
                 return leaf
             x = jnp.asarray(leaf)
             q, scale = _quantize_dev(x, self.tile, self.bits,
-                                     kernel_block=kb)
+                                     kernel=kernel)
             return DevQuant(q, scale, self.bits, self.tile, x.shape)
         return jax.tree_util.tree_map(
             conv, tree, is_leaf=lambda o: isinstance(o, DevQuant))
@@ -207,12 +213,11 @@ def dequantize_leaf(leaf: QuantLeaf, kernels=None):
     if leaf.tile == 0 and leaf.shape is None:
         return jnp.asarray(leaf.q, jnp.float32) * np.float32(leaf.scale)
     from split_learning_tpu.ops import kernels as kplane
-    kp = kplane.as_plan(kernels)
-    kb = kp.block if kp.dequantize else 0
+    kernel = kplane.as_plan(kernels).dequantize
     n = int(np.prod(leaf.shape)) if leaf.shape else 1
     return _dequantize_dev(jnp.asarray(leaf.q), jnp.asarray(leaf.scale),
                            leaf.tile, leaf.bits, n, tuple(leaf.shape),
-                           kernel_block=kb)
+                           kernel=kernel)
 
 
 # -- numpy twins (once-per-round Update/delta path; host-side inputs) ------

@@ -70,9 +70,12 @@ from typing import Any, Callable
 PERF_SCHEMA_VERSION = 1
 
 #: Datasheet bf16 peak TFLOP/s per chip, keyed by jax ``device_kind``
-#: (public TPU spec tables; bench.py's MFU section reads this same
-#: table).  CPU has no datasheet row: the measured matmul roofline
-#: (bench.py) or a ``perf.datasheet`` override stands in.
+#: (Google Cloud TPU documentation, per-generation spec tables;
+#: bench.py's MFU section reads this same table).  The host CPU is a
+#: known device with no datasheet peak: its row is None and no MFU is
+#: reported for it unless a ``perf.datasheet`` override supplies a
+#: measured roofline.  A device that is not in the table is an error
+#: (:func:`resolve_peak_tflops`), never a default.
 DATASHEET_BF16_TFLOPS = {
     "TPU v5 lite": 197.0,
     "TPU v5e": 197.0,
@@ -81,6 +84,7 @@ DATASHEET_BF16_TFLOPS = {
     "TPU v4": 275.0,
     "TPU v6 lite": 918.0,
     "TPU v6e": 918.0,
+    "cpu": None,
 }
 
 
@@ -88,15 +92,19 @@ def resolve_peak_tflops(device_kind: str,
                         override: dict | None = None) -> float | None:
     """Datasheet bf16 peak for ``device_kind``; an override mapping
     (``perf.datasheet``) wins — that is also how a CPU proxy run pins
-    its measured roofline as the MFU denominator."""
-    if override:
-        v = override.get(device_kind)
-        if v is not None:
-            try:
-                return float(v)
-            except (TypeError, ValueError):
-                return None
-    return DATASHEET_BF16_TFLOPS.get(device_kind)
+    its measured roofline as the MFU denominator.  Raises for a device
+    neither the override nor the table knows: an MFU against a guessed
+    peak would be a wrong number under a device metric's name."""
+    if override and device_kind in override:
+        return float(override[device_kind])
+    try:
+        return DATASHEET_BF16_TFLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"device_kind {device_kind!r} is not in the peaks table "
+            f"(runtime/perf.py DATASHEET_BF16_TFLOPS: "
+            f"{sorted(DATASHEET_BF16_TFLOPS)}); add its datasheet row "
+            f"or set perf.datasheet") from None
 
 
 def flops_of_compiled(fn, *args, **kwargs) -> float | None:
@@ -106,8 +114,6 @@ def flops_of_compiled(fn, *args, **kwargs) -> float | None:
     try:
         compiled = fn.lower(*args, **kwargs).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # jax < 0.5 spelling
-            cost = cost[0] if cost else {}
         flops = (cost or {}).get("flops")
         return float(flops) if flops else None
     except Exception:  # noqa: BLE001 — cost analysis is best-effort
@@ -703,13 +709,10 @@ class PerfPlane:
     def peak_tflops(self) -> float | None:
         """Datasheet peak for this process's device kind (cached)."""
         if not self._peak_resolved:
+            import jax
+            self._peak_tflops = resolve_peak_tflops(
+                jax.devices()[0].device_kind, self.datasheet)
             self._peak_resolved = True
-            try:
-                import jax
-                kind = jax.devices()[0].device_kind
-            except Exception:  # noqa: BLE001 — no backend at all
-                kind = "cpu"
-            self._peak_tflops = resolve_peak_tflops(kind, self.datasheet)
         return self._peak_tflops
 
     # -- the round record ----------------------------------------------------

@@ -35,13 +35,10 @@ import uuid
 import zlib
 from typing import Any
 
+import ml_dtypes
 import numpy as np
 
-try:                                   # bf16 wire payloads (jax dep)
-    import ml_dtypes as _ml_dtypes
-    _BF16 = np.dtype(_ml_dtypes.bfloat16)
-except ImportError:                    # pragma: no cover - jax ships it
-    _BF16 = None
+_BF16 = np.dtype(ml_dtypes.bfloat16)   # bf16 wire payloads
 
 RPC_QUEUE = "rpc_queue"
 
@@ -638,14 +635,12 @@ def _decode_pickled(raw: bytes):
 #: the pickled skeleton, which the restricted unpickler still guards.
 _DTYPE_BY_CODE: dict[int, np.dtype] = {
     1: np.dtype(np.float32), 2: np.dtype(np.float64),
-    3: np.dtype(np.float16), 5: np.dtype(np.int8),
+    3: np.dtype(np.float16), 4: _BF16, 5: np.dtype(np.int8),
     6: np.dtype(np.int16), 7: np.dtype(np.int32),
     8: np.dtype(np.int64), 9: np.dtype(np.uint8),
     10: np.dtype(np.uint16), 11: np.dtype(np.uint32),
     12: np.dtype(np.uint64), 13: np.dtype(np.bool_),
 }
-if _BF16 is not None:
-    _DTYPE_BY_CODE[4] = _BF16
 _CODE_BY_DTYPE = {dt: c for c, dt in _DTYPE_BY_CODE.items()}
 
 #: per-tensor fixed header: dtype code, flags, ndim, crc32(raw bytes),

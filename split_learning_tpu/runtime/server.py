@@ -2780,8 +2780,11 @@ class ProtocolServer:
 
 
 def main(argv=None):
-    from split_learning_tpu.platform import apply_platform_env
+    from split_learning_tpu.platform import (
+        apply_compile_cache, apply_platform_env,
+    )
     apply_platform_env()
+    apply_compile_cache()
     ap = argparse.ArgumentParser(
         description="Split-learning protocol server (reference server.py "
                     "parity).")
@@ -2796,8 +2799,6 @@ def main(argv=None):
                          "(default: --client_timeout)")
     args = ap.parse_args(argv)
     cfg = from_yaml(args.config)
-    from split_learning_tpu.platform import apply_compile_cache
-    apply_compile_cache(cfg.compile_cache_dir)
     blackbox.install(cfg, "server", role="server")
     brokers = []
     if args.broker and cfg.transport.kind == "tcp":

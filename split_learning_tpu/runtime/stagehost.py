@@ -307,13 +307,19 @@ def spawn_stage_host(config_path, host_id: str, cpu: int | None = None):
     """Spawn one stage-host subprocess (tcp transport).  ``cpu`` pins
     the child to one core via ``taskset``-free sched_setaffinity
     inheritance (the child re-pins itself from ``SLT_PIN_CPU``) — the
-    bench's NUMA proxy.  JAX_PLATFORMS is pinned to cpu unless the
-    caller set it; stdio is inherited so tracebacks surface in CI."""
+    bench's NUMA proxy.  stdio is inherited so tracebacks surface in
+    CI.
+
+    The child runs on the CPU backend whatever the parent runs on: an
+    accelerator belongs to one process, the spawning server may hold
+    it, and a child that inherited ``JAX_PLATFORMS=tpu`` would fail or
+    hang waiting for it.  Until per-process chip pinning is settled
+    (ROADMAP S0/S3) the multi-process protocol path is CPU-only."""
     import os
     import subprocess
     import sys
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     if cpu is not None:
         env["SLT_PIN_CPU"] = str(cpu)
     return subprocess.Popen(
@@ -335,11 +341,17 @@ def main(argv=None):
             os.sched_setaffinity(0, {int(pin)})
         except (OSError, ValueError):
             pass   # a bad pin must not stop the host from serving
+    from split_learning_tpu.platform import (
+        apply_compile_cache, apply_platform_env,
+    )
+    apply_platform_env()
+    apply_compile_cache()
     cfg = from_yaml(args.config)
-    from split_learning_tpu.platform import apply_compile_cache
-    apply_compile_cache(cfg.compile_cache_dir)
     blackbox.install(cfg, args.host_id, role="stage_host")
     host = StageHost(cfg, args.host_id)
+    import jax
+    host.log.info(f"stage host {args.host_id}: jax backend "
+                  f"{jax.default_backend()}")
     host.run()
 
 

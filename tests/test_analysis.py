@@ -874,6 +874,35 @@ def test_pallas_gate_sees_call_through_jit_wrapping():
     assert pallas_check.check_lowering(jaxpr, "x.py", "quantize:int8") == []
 
 
+def test_pallas_gate_fires_on_a_block_mosaic_refuses():
+    """The lowering half has teeth: a ``pallas_call`` that is fine
+    under the interpreter — one row of a (rows, 128) array per grid
+    step, the layout the quantizer's scales used to have — is refused
+    by the TPU lowering, on a host with no TPU."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    from split_learning_tpu.analysis import pallas_check
+
+    def copy_rows(x, interpret):
+        def kernel(x_ref, o_ref):
+            o_ref[...] = x_ref[...]
+        row = pl.BlockSpec((1, 128), lambda i: (i, 0))
+        return pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            grid=(x.shape[0],), in_specs=[row], out_specs=row,
+            interpret=interpret)(x)
+
+    x = jnp.arange(4 * 128, dtype=jnp.float32).reshape(4, 128)
+    assert (copy_rows(x, True) == x).all()
+    fs = pallas_check.check_tpu_lowering(
+        "copy_rows", "x.py", lambda a: copy_rows(a, False),
+        (jax.ShapeDtypeStruct(x.shape, x.dtype),))
+    assert codes(fs) == {"PK001"}
+    assert "does not lower for TPU" in fs[0].message
+
+
 def test_pallas_analyzer_skipped_without_trace():
     from split_learning_tpu.analysis import pallas_check
     from split_learning_tpu.analysis.__main__ import repo_root

@@ -40,6 +40,28 @@ def test_gradients_match_dense(causal):
                                    rtol=5e-4, atol=5e-4)
 
 
+def _flash_lowering_cases():
+    from split_learning_tpu.analysis.pallas_check import lowering_cases
+    return {c[0]: c for c in lowering_cases()
+            if c[0].startswith("flash")}
+
+
+_FLASH_CASES = _flash_lowering_cases()
+
+
+@pytest.mark.parametrize("name", sorted(_FLASH_CASES))
+def test_flash_lowers_for_tpu(name):
+    """Forward and backward kernels lower for TPU natively
+    (``interpret=False``) at the shapes ``chip_smoke.py`` runs: both
+    were refused while ``lse``/``delta`` travelled as ``(1, block_q)``
+    slices of a ``(BH, S)`` array."""
+    from split_learning_tpu.analysis.pallas_check import (
+        check_tpu_lowering,
+    )
+    assert len(_FLASH_CASES) == 4
+    assert check_tpu_lowering(*_FLASH_CASES[name]) == []
+
+
 def test_block_shrink_on_odd_sizes():
     """S=48 auto-picks a dividing block; numerics unchanged."""
     q, k, v = _qkv(jax.random.key(2), s=48)

@@ -29,7 +29,7 @@ import pytest
 
 from split_learning_tpu.ops import kernels as kplane
 from split_learning_tpu.ops.kernels import (
-    DISABLED, KernelPlan, pick_block, pick_pair_block, resolve_interpret,
+    DISABLED, KernelPlan, pick_block, resolve_interpret, vmem_block,
 )
 
 
@@ -57,8 +57,8 @@ class TestKernelPlan:
 
     def test_as_plan_coerces_config_section(self):
         from split_learning_tpu.config import KernelsConfig
-        kp = kplane.as_plan(KernelsConfig(quantize=True, block=64))
-        assert kp == KernelPlan(quantize=True, block=64)
+        kp = kplane.as_plan(KernelsConfig(quantize=True))
+        assert kp == KernelPlan(quantize=True)
         assert kp.any
 
     def test_configure_none_is_a_noop(self):
@@ -85,16 +85,17 @@ class TestKernelPlan:
         from split_learning_tpu.config import from_dict
         cfg = from_dict({"kernels": {"quantize": True,
                                      "dequantize": True,
-                                     "stage_update": True,
-                                     "block": 32}})
+                                     "stage_update": True}})
         kp = kplane.as_plan(cfg.kernels)
         assert kp == KernelPlan(quantize=True, dequantize=True,
-                                stage_update=True, block=32)
+                                stage_update=True)
 
-    def test_config_rejects_bad_block(self):
+    def test_config_has_no_block_option(self):
+        """Block sizes come from the leaf shape and the VMEM budget
+        (``vmem_block``); a user value could only break the lowering."""
         from split_learning_tpu.config import ConfigError, from_dict
         with pytest.raises(ConfigError):
-            from_dict({"kernels": {"block": 0}})
+            from_dict({"kernels": {"block": 128}})
 
     def test_pick_block_divides(self):
         assert pick_block(256) == 128
@@ -104,12 +105,25 @@ class TestKernelPlan:
             b = pick_block(s)
             assert s % b == 0 and b <= 128
 
-    def test_pick_pair_block_keeps_pairs_whole(self):
-        for t, tile in ((3, 64), (12, 7), (1, 2), (5, 14)):
-            b = pick_pair_block(t, tile)
-            assert t % b == 0 and (b * tile) % 2 == 0
+    def test_vmem_block_is_legal_and_bounded(self):
+        """Each block dimension is the whole array dimension or a
+        multiple of the native tile (32 sublanes / 128 lanes), and one
+        lane-padded f32 block stays inside the budget — for tiny,
+        ragged, conv-shaped and huge operands alike."""
+        from split_learning_tpu.ops.kernels.util import (
+            BLOCK_BYTES, LANES, SUBLANES,
+        )
+        for rows, cols in ((1, 10), (27, 64), (4608, 512), (8192, 64),
+                           (4096, 4096), (1, 5_000_000), (1000, 64),
+                           (33, 7), (32000, 2048)):
+            br, bc = vmem_block(rows, cols)
+            assert br == rows or br % SUBLANES == 0, (rows, cols, br)
+            assert bc == cols or bc % LANES == 0, (rows, cols, bc)
+            assert 1 <= br <= rows and 1 <= bc <= cols
+            assert br * -(-bc // LANES) * LANES * 4 <= max(
+                BLOCK_BYTES, SUBLANES * LANES * 4)
         with pytest.raises(ValueError):
-            pick_pair_block(3, 7)   # t*tile odd: unpackable
+            vmem_block(8, 1 << 20, split_cols=False)
 
     def test_resolve_interpret_on_cpu(self):
         import jax
@@ -140,8 +154,8 @@ class TestQuantKernels:
         pad logic adds a whole extra tile to keep the count even)."""
         from split_learning_tpu.runtime.codec.quant import _quantize_dev
         x = self._payload(shape)
-        q0, s0 = _quantize_dev(x, tile, bits, kernel_block=0)
-        q1, s1 = _quantize_dev(x, tile, bits, kernel_block=128)
+        q0, s0 = _quantize_dev(x, tile, bits, kernel=False)
+        q1, s1 = _quantize_dev(x, tile, bits, kernel=True)
         _bit_equal(q0, q1)
         _bit_equal(s0, s1)
 
@@ -153,10 +167,10 @@ class TestQuantKernels:
         )
         x = self._payload(shape, seed=1)
         n = x.size
-        q, s = _quantize_dev(x, tile, bits, kernel_block=0)
-        d0 = _dequantize_dev(q, s, tile, bits, n, shape, kernel_block=0)
+        q, s = _quantize_dev(x, tile, bits, kernel=False)
+        d0 = _dequantize_dev(q, s, tile, bits, n, shape, kernel=False)
         d1 = _dequantize_dev(q, s, tile, bits, n, shape,
-                             kernel_block=128)
+                             kernel=True)
         _bit_equal(d0, d1)
 
     @pytest.mark.parametrize("bits,tile", [(8, 64), (4, 7), (4, 64)])
@@ -170,7 +184,7 @@ class TestQuantKernels:
         x = self._payload((33, 5), seed=2)
         twin = quantize_np(x, tile, bits)
         with kplane.override(quantize=True, dequantize=True):
-            q, s = _quantize_dev(x, tile, bits, kernel_block=128)
+            q, s = _quantize_dev(x, tile, bits, kernel=True)
         _bit_equal(np.asarray(q), twin.q)
         np.testing.assert_allclose(np.asarray(s), twin.scale,
                                    rtol=1e-6)
@@ -179,7 +193,7 @@ class TestQuantKernels:
             _dequantize_dev,
         )
         dev = _dequantize_dev(np.asarray(q), np.asarray(s), tile, bits,
-                              x.size, x.shape, kernel_block=128)
+                              x.size, x.shape, kernel=True)
         np.testing.assert_allclose(np.asarray(dev), back, rtol=1e-6,
                                    atol=1e-7)
 
@@ -193,21 +207,21 @@ class TestQuantKernels:
         x = np.ones((4, 64), np.float32)
         x[1, 3] = np.nan
         x[2, 0] = np.inf
-        q, s = _quantize_dev(x, 64, 8, kernel_block=128)
+        q, s = _quantize_dev(x, 64, 8, kernel=True)
         s = np.asarray(s)
         assert np.isnan(s[1]) and np.isnan(s[2])
         assert np.isfinite(s[[0, 3]]).all()
         q = np.asarray(q).reshape(4, 64)
         assert (q[1] == 0).all() and (q[2] == 0).all()
         back = np.asarray(_dequantize_dev(
-            q.reshape(-1), s, 64, 8, 256, (4, 64), kernel_block=128))
+            q.reshape(-1), s, 64, 8, 256, (4, 64), kernel=True))
         assert np.isnan(back[1]).all() and np.isnan(back[2]).all()
         np.testing.assert_allclose(back[[0, 3]], 1.0, atol=1e-2)
 
     def test_zero_tile_uses_scale_one(self):
         from split_learning_tpu.runtime.codec.quant import _quantize_dev
         q, s = _quantize_dev(np.zeros((2, 64), np.float32), 64, 8,
-                             kernel_block=128)
+                             kernel=True)
         np.testing.assert_array_equal(np.asarray(s), 1.0)
         assert (np.asarray(q) == 0).all()
 
@@ -237,6 +251,42 @@ class TestQuantKernels:
         _bit_equal(off_leaf.q, on_leaf.q)
         _bit_equal(off_leaf.scale, on_leaf.scale)
         _bit_equal(off_back, on_back)
+
+
+# --------------------------------------------------------------------------
+# TPU lowering gate: interpreter parity says nothing about Mosaic
+# --------------------------------------------------------------------------
+
+def _lowering_cases(prefixes):
+    from split_learning_tpu.analysis.pallas_check import lowering_cases
+    return {c[0]: c for c in lowering_cases()
+            if c[0].startswith(prefixes)}
+
+
+_CODEC_UPDATE_CASES = _lowering_cases(
+    ("quantize", "dequantize", "finalize_leaf", "momentum_leaf"))
+
+
+@pytest.mark.parametrize("name", sorted(_CODEC_UPDATE_CASES))
+def test_kernel_lowers_for_tpu(name):
+    """Every quantize / dequantize / stage-update kernel, at the shapes
+    ``chip_smoke.py`` runs on the chip (the cut-7 boundary; every leaf
+    shape of the VGG16 tree), lowers for TPU with ``interpret=False``
+    — on this CPU host, through the Python half of the Mosaic
+    lowering.  All of the codec cases were refused before the scales
+    moved to a ``(T, 1)`` column."""
+    from split_learning_tpu.analysis.pallas_check import (
+        check_tpu_lowering,
+    )
+    assert check_tpu_lowering(*_CODEC_UPDATE_CASES[name]) == []
+
+
+def test_lowering_cases_cover_the_tree_and_the_codec():
+    names = set(_CODEC_UPDATE_CASES)
+    for must in ("quantize_int8:64", "quantize_int4:64", "dequantize:64",
+                 "finalize_leaf(3, 3, 512, 512)", "momentum_leaf(512,)",
+                 "finalize_leaf(10,)", "momentum_leaf(4096, 4096)"):
+        assert must in names, (must, sorted(names))
 
 
 # --------------------------------------------------------------------------

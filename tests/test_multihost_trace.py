@@ -70,10 +70,10 @@ def test_two_process_distributed_train_step_and_fedavg(tmp_path):
         e.update(SLT_COORDINATOR=f"127.0.0.1:{port}",
                  SLT_NUM_PROCESSES="2", SLT_PROCESS_ID=str(pid),
                  PYTHONPATH=repo + os.pathsep + e.get("PYTHONPATH", ""))
-        # the child pins its own platform/device-count before jax init;
-        # the inherited cache namespace was computed under the PARENT's
-        # XLA_FLAGS, so compiling into it with different flags would
-        # re-create mixed-target-tuning pollution — drop both
+        # the child pins its own platform/device-count before jax
+        # init, so it must not inherit the suite's XLA_FLAGS; and two
+        # coordinated processes writing one cache directory at once is
+        # a race this test has no use for — run them uncached
         e.pop("XLA_FLAGS", None)
         e.pop("JAX_COMPILATION_CACHE_DIR", None)
         return e

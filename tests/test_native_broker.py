@@ -106,3 +106,40 @@ def test_full_training_round_over_native_broker(broker, tmp_path):
         TcpTransport(broker.host, broker.port))
     assert result.history[0].ok
     assert result.history[0].num_samples > 0
+
+
+def test_native_build_is_keyed_on_the_source_not_on_mtimes(
+        tmp_path, monkeypatch):
+    """The artifact directory is git-ignored and a copied tree keeps no
+    mtimes: reuse is keyed on a hash of the source (and flags) kept in
+    a sidecar.  A binary with no sidecar, or one built from other
+    source, is rebuilt however new its mtime is."""
+    import os
+
+    from split_learning_tpu import native
+    monkeypatch.setattr(native, "_BIN_DIR", tmp_path)
+    src = tmp_path / "hello.cpp"
+    dest = tmp_path / "hello"
+    stamp = tmp_path / "hello.srchash"
+    src.write_text('#include <cstdio>\nint main(){puts("one");}\n')
+
+    def out():
+        import subprocess
+        return subprocess.run([str(native._build(src, dest))],
+                              capture_output=True, text=True).stdout
+
+    assert out() == "one\n" and stamp.exists()
+    built = dest.stat().st_mtime_ns
+    assert out() == "one\n"
+    assert dest.stat().st_mtime_ns == built          # reused as is
+
+    # new source, OLDER than the artifact by mtime: the old rule reused
+    src.write_text('#include <cstdio>\nint main(){puts("two");}\n')
+    os.utime(src, ns=(built - 10**12, built - 10**12))
+    assert out() == "two\n"
+
+    # an artifact of unknown origin (no sidecar) is never run
+    stamp.unlink()
+    dest.write_text("#!/bin/sh\necho stale\n")
+    dest.chmod(0o755)
+    assert out() == "two\n" and stamp.exists()

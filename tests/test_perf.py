@@ -228,7 +228,18 @@ class TestMemoryAndMfu:
             DATASHEET_BF16_TFLOPS["TPU v5e"]
         assert resolve_peak_tflops("cpu") is None
         assert resolve_peak_tflops("cpu", {"cpu": 0.25}) == 0.25
-        assert resolve_peak_tflops("cpu", {"cpu": "bogus"}) is None
+        assert resolve_peak_tflops("TPU v9", {"TPU v9": 1.5}) == 1.5
+
+    def test_unknown_device_kind_is_an_error(self):
+        """A device outside the table (and outside an explicit
+        perf.datasheet override) must not get a default peak: the MFU
+        it would produce is a wrong number under a device name."""
+        with pytest.raises(ValueError, match="TPU v9"):
+            resolve_peak_tflops("TPU v9")
+        with pytest.raises(ValueError, match="TPU v9"):
+            resolve_peak_tflops("TPU v9", {"cpu": 0.25})
+        # the v5e chip the repo runs on reports this kind
+        assert resolve_peak_tflops("TPU v5 lite") == 197.0
 
     def test_mfu_math_with_fake_datasheet_entry(self):
         """flops x rate / peak: pin the whole MFU pipeline with a fake
@@ -737,15 +748,6 @@ class TestSlPerf:
         p5.write_text(json.dumps({"n": 4, "parsed": None,
                                   "tail": "cpuinfo noise"}))
         assert sp.load_bench(p5) is None
-
-    def test_committed_bench_history_gate_is_green(self):
-        """The CI perf-gate command over the repo's own history."""
-        sp = _sl_perf()
-        root = pathlib.Path(__file__).resolve().parent.parent
-        paths = sorted(root.glob("BENCH_r*.json"))
-        assert len(paths) >= 2
-        rc = sp.main(["--diff"] + [str(p) for p in paths])
-        assert rc == 0
 
     def test_attribution_report_from_metrics(self, tmp_path):
         sp = _sl_perf()

@@ -353,11 +353,10 @@ class TestWireCounters:
 
 
 _CACHE_SCRIPT = """
-import sys
 from split_learning_tpu.platform import apply_platform_env, \
     apply_compile_cache
 apply_platform_env()
-apply_compile_cache(sys.argv[1])
+apply_compile_cache()
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -367,18 +366,23 @@ print(float(np.asarray(out)))
 
 
 def test_compile_cache_populates_and_reuses(tmp_path):
-    """compile-cache-dir smoke: a first run populates the persistent
-    XLA cache; a second run of the same program adds NO new entries
-    (it loaded the compiled executable instead of recompiling)."""
+    """Compile-cache smoke (the directory comes from
+    JAX_COMPILATION_CACHE_DIR, platform.apply_compile_cache): a first
+    run populates the persistent XLA cache; a second run of the same
+    program adds NO new entries (it loaded the compiled executable
+    instead of recompiling)."""
     cache = tmp_path / "xla_cache"
     env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(cache),
                PYTHONPATH=os.pathsep.join(
                    [str(os.path.dirname(os.path.dirname(__file__)))]
                    + [p for p in (os.environ.get("PYTHONPATH"),) if p]))
+    # cache everything: this program compiles in milliseconds
+    env.pop("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", None)
 
     def run():
-        r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT,
-                            str(cache)], env=env, capture_output=True,
+        r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT],
+                           env=env, capture_output=True,
                            text=True, timeout=240)
         assert r.returncode == 0, r.stderr[-2000:]
         return r

@@ -28,10 +28,17 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import pathlib
 import sys
 import threading
 import time
+
+# The cells test the host planes, most of them across several
+# processes.  An accelerator belongs to one process, so the rig runs
+# on the CPU backend by choice — this process and every child it
+# spawns — whatever the machine has.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 sys.path.insert(0, ".")  # run from the repo root
 

@@ -8,26 +8,24 @@ Two data sources, merged into one report:
   (``runtime/perf.py PerfPlane``): per-participant, per-round
   ``compute | compile | dispatch | host | wait`` attribution, MFU,
   HBM watermark, compile counts and retraces;
-* the ``BENCH_r*.json`` history (and the new run-scoped
-  ``bench.json`` artifacts bench.py writes): the stable
+* the run-scoped ``bench.json`` artifacts bench.py writes (and the
+  driver-wrapper shapes older records used): the stable
   regression-tracking keys mirrored at the top of ``extra``.
 
 Modes:
 
     python tools/sl_perf.py --metrics artifacts/runs/<run_id>  # report
     python tools/sl_perf.py --metrics <dir> --report out.json
-    python tools/sl_perf.py --diff BENCH_r*.json               # gate
-    python tools/sl_perf.py --diff BENCH_r04.json BENCH_r05.json \
-        --threshold 0.15
+    python tools/sl_perf.py --diff <old>/bench.json <new>/bench.json \
+        --threshold 0.15                                       # gate
 
 ``--diff`` compares the LAST bench record against the previous one on
 the stable keys and exits 1 on any regression beyond the noise
-threshold (default 15%) — the CI perf-gate job.  Improvements and
+threshold (default 15%).  Improvements and
 within-noise drift pass; keys missing or null on either side are
 skipped (a section that never ran is not a regression).
 
-Stdlib only: runs anywhere the repo does (CI perf-gate installs
-nothing).
+Stdlib only: runs anywhere the repo does.
 """
 
 from __future__ import annotations
