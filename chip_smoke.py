@@ -24,8 +24,11 @@ Four phases, none skipped because an earlier one failed:
    ``tests/test_flash_attention.py`` against its XLA twin.
 4. cache   — the compilation cache directory is not empty.
 
-The last line of standard output is one JSON object naming the device
-as JAX reports it.  Exit code 0 only when every phase passed; without an
+The last line of standard output is one JSON object,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``
+with exactly those keys, naming the device as JAX reports it; the mesh
+and the compile seconds are on the ``summary:`` line before it.
+Exit code 0 only when every phase passed; without an
 accelerator (and without ``--rehearsal``) the script exits non-zero
 before printing any result.  It states no speed: compile seconds are
 printed as a set-up fact.
@@ -528,13 +531,15 @@ def main(argv=None) -> int:
         print(f"chip_smoke: FAILED in phase(s): {', '.join(failed)}",
               file=sys.stderr)
         return 1
+    print(f"summary: mesh {results['round']['mesh']}, "
+          f"{compiles.count} compilations, {compiles.seconds:.1f} s "
+          "compiling (set-up, not a speed)")
+    # the contract's result line: these keys and no others
     dev = jax.devices()[0]
     print(json.dumps({
         "ok": True,
         "device": {"platform": dev.platform, "kind": dev.device_kind,
-                   "count": len(jax.devices())},
-        "mesh": results["round"]["mesh"],
-        "compile_s": round(compiles.seconds, 1)}))
+                   "count": len(jax.devices())}}), flush=True)
     return 0
 
 
