@@ -283,6 +283,9 @@ class PipelineModel:
     # INTERIOR boundary so every stage hop moves one (mb, max_flat)
     # buffer; the final output rides its own exact-width slot.
 
+    # (both ends of a hop, and the ppermute in `tick`, carry its name)
+
+    @jax.named_scope("hop")
     def _to_wire(self, x) -> jnp.ndarray:
         leaves = jax.tree_util.tree_leaves(x)
         flat = jnp.concatenate(
@@ -291,6 +294,7 @@ class PipelineModel:
         pad = self.max_flat - flat.shape[1]
         return jnp.pad(flat, ((0, 0), (0, pad))) if pad else flat
 
+    @jax.named_scope("hop")
     def _from_wire(self, wire, struct):
         leaves, treedef = jax.tree_util.tree_flatten(struct)
         out, off = [], 0
@@ -303,6 +307,7 @@ class PipelineModel:
 
     # -- per-device pipeline body -----------------------------------------
 
+    @jax.named_scope("loss")
     def loss_from_logits(self, logits, labels):
         if self.loss_name == "softmax_cross_entropy":
             return optax.softmax_cross_entropy_with_integer_labels(
@@ -391,6 +396,9 @@ class PipelineModel:
                     return (out, mut.get("batch_stats", {}),
                             moe_aux_loss(mut.get("intermediates", {})))
 
+                # the stage's name, entered INSIDE what jax.checkpoint
+                # wraps, so that the recomputed copy carries it too
+                apply_one = jax.named_scope(f"stage{s + 1}")(apply_one)
                 if self.stage_remat[s]:
                     apply_one = jax.checkpoint(apply_one)
                 out, mut_stats, stage_aux = apply_one(
@@ -465,10 +473,11 @@ class PipelineModel:
         def tick(carry, t):
             act_wire, stats, acc, aux_acc = carry
             inj_idx = jnp.clip(t, 0, M - 1)
-            x_inj = self._to_wire(
-                jax.lax.dynamic_index_in_dim(x_mb, inj_idx, 0,
-                                             keepdims=False))
-            act_in = jnp.where(dev == 0, x_inj, act_wire)
+            with jax.named_scope("hop"):
+                x_inj = self._to_wire(
+                    jax.lax.dynamic_index_in_dim(x_mb, inj_idx, 0,
+                                                 keepdims=False))
+                act_in = jnp.where(dev == 0, x_inj, act_wire)
             mb_idx = jnp.clip(t - dev, 0, M - 1)
             rng_t = jax.random.fold_in(rng, mb_idx)
             if self.seq_axis is not None:
@@ -507,8 +516,9 @@ class PipelineModel:
                     acc)
 
             perm = [(i, i + 1) for i in range(A - 1)]
-            act_next = (jax.lax.ppermute(out_wire, "stage", perm)
-                        if perm else out_wire)
+            with jax.named_scope("hop"):
+                act_next = (jax.lax.ppermute(out_wire, "stage", perm)
+                            if perm else out_wire)
             return (act_next, new_stats, acc, aux_acc), None
 
         del mesh_axes  # only relevant under check_vma, which we disable
@@ -524,9 +534,14 @@ class PipelineModel:
         ticks = M + A - 1
         unroll = (max(2, ticks) if scan_unroll >= ticks
                   else max(1, scan_unroll))
-        (_, stats_f, acc, aux_acc), _ = jax.lax.scan(
-            tick, (act0, stats0, acc0, jnp.zeros(())),
-            jnp.arange(ticks), unroll=unroll)
+        # the tick loop's own name: what it does outside the stages
+        # (stacking each tick's residuals for the backward pass and
+        # reading them back, the carry's copies, the microbatches'
+        # gradient accumulation) carries it without a stage's
+        with jax.named_scope("pipeline"):
+            (_, stats_f, acc, aux_acc), _ = jax.lax.scan(
+                tick, (act0, stats0, acc0, jnp.zeros(())),
+                jnp.arange(ticks), unroll=unroll)
 
         if self.stream_loss:
             # equal microbatch sizes: the mean of per-microbatch means
@@ -665,6 +680,19 @@ def _restore(tree):
     return jax.tree_util.tree_map(lambda a: a[None], tree)
 
 
+# The jitted programs are named by the functions they are made from
+# (``sl_train_step`` in every train-step factory, ``sl_fedavg``): the
+# profiler's ``XLA Modules`` line shows ``jit_sl_train_step(<id>)`` and
+# every operation's ``op_name`` starts ``jit(sl_train_step)/``.
+
+@jax.named_scope("optimizer")
+def _apply_optimizer(optimizer, grads, opt_state, params):
+    """The update, with the leading client axis restored: XLA fuses the
+    update of a leaf into that last reshape and names the fusion after
+    it, so the reshape has to be under the scope too."""
+    updates, new_opt = optimizer.update(grads, opt_state, params)
+    return _restore(optax.apply_updates(params, updates)), _restore(new_opt)
+
 
 def _shmap_kwargs(mesh: Mesh) -> dict:
     """Extra ``jax.shard_map`` kwargs for this mesh.
@@ -701,6 +729,7 @@ def _make_grad_sync(client_sync: dict | None, mesh: Mesh):
                 sizes[col] = len(g)
         group_denom[name] = sizes
 
+    @jax.named_scope("grad_sync")
     def sync(grads_part, c_idx):
         synced = dict(grads_part)
         for name, groups in client_sync.items():
@@ -759,7 +788,7 @@ def make_train_step(pipe: PipelineModel, optimizer: optax.GradientTransformation
     sync_axes = (("stage",) if pipe.seq_axis is None
                  else ("stage", pipe.seq_axis))
 
-    def body(params, opt_state, stats, x, labels, rngs):
+    def sl_train_step(params, opt_state, stats, x, labels, rngs):
         params, opt_state, stats = map(_strip, (params, opt_state, stats))
         x, labels, rng = x[0], labels[0], rngs[0]
 
@@ -773,14 +802,14 @@ def make_train_step(pipe: PipelineModel, optimizer: optax.GradientTransformation
         (_, (loss, new_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
         # each device produced grads for its own stage only; sync replicas
-        grads = jax.tree_util.tree_map(
-            lambda g: jax.lax.psum(g, sync_axes), grads)
+        with jax.named_scope("grad_sync"):
+            grads = jax.tree_util.tree_map(
+                lambda g: jax.lax.psum(g, sync_axes), grads)
         if grad_sync is not None:
             grads = grad_sync(grads, jax.lax.axis_index("client"))
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        return (*map(_restore, (new_params, new_opt, new_stats)),
-                loss[None])
+        new_params, new_opt = _apply_optimizer(optimizer, grads, opt_state,
+                                               params)
+        return new_params, new_opt, _restore(new_stats), loss[None]
 
     spec_c = P("client")
     # x/labels carry the sequence on their last dim (token models):
@@ -790,9 +819,9 @@ def make_train_step(pipe: PipelineModel, optimizer: optax.GradientTransformation
     # check_vma=False: jax 0.9's varying-axis tracker miscompiles the
     # transpose of the scan-of-ppermute pipeline (observed: heap corruption
     # and garbage gradients on the CPU backend). Replication along `stage`
-    # is guaranteed manually by the grad/stats psums in `body`.
+    # is guaranteed manually by the grad/stats psums in `sl_train_step`.
     mapped = jax.shard_map(
-        body, mesh=mesh,
+        sl_train_step, mesh=mesh,
         in_specs=(spec_c, spec_c, spec_c, spec_x, spec_x, spec_c),
         out_specs=(spec_c,) * 4,
         check_vma=False,
@@ -837,7 +866,7 @@ def make_sliced_train_step(pipe: PipelineModel,
     layout = pipe.stage_param_layout(stage_axis)
     unroll = pipe.scan_unroll_for(mesh)
 
-    def body(params, opt_state, stats, x, labels, rngs):
+    def sl_train_step(params, opt_state, stats, x, labels, rngs):
         p = params[0]                      # (seg_len,) own-stage slice
         opt_state, stats = map(_strip, (opt_state, stats))
         x, labels, rng = x[0], labels[0], rngs[0]
@@ -855,10 +884,10 @@ def make_sliced_train_step(pipe: PipelineModel,
         # grads are purely LOCAL (this device's slice): no stage psum.
         # Seq-sharded pipelines still fold token-block partial sums.
         if pipe.seq_axis is not None:
-            grads = jax.lax.psum(grads, pipe.seq_axis)
-        updates, new_opt = optimizer.update(grads, opt_state, p)
-        new_p = optax.apply_updates(p, updates)
-        return (new_p[None], _restore(new_opt), _restore(new_stats),
+            with jax.named_scope("grad_sync"):
+                grads = jax.lax.psum(grads, pipe.seq_axis)
+        new_p, new_opt = _apply_optimizer(optimizer, grads, opt_state, p)
+        return (new_p, new_opt, _restore(new_stats),
                 loss[None])
 
     # optimizer-state specs mirror the flat param wire: vector leaves
@@ -875,7 +904,7 @@ def make_sliced_train_step(pipe: PipelineModel,
     spec_x = (spec_c if pipe.seq_axis is None
               else P("client", None, None, pipe.seq_axis))
     mapped = jax.shard_map(
-        body, mesh=mesh,
+        sl_train_step, mesh=mesh,
         in_specs=(P("client", "stage"), spec_opt, spec_c, spec_x,
                   spec_x, spec_c),
         out_specs=(P("client", "stage"), spec_opt, spec_c, spec_c),
@@ -929,7 +958,7 @@ def make_lora_train_step(pipe: PipelineModel,
     stage_axis = int(mesh.shape["stage"])
     unroll = pipe.scan_unroll_for(mesh)
 
-    def body(frozen, t, opt_state, stats, x, labels, rngs):
+    def sl_train_step(frozen, t, opt_state, stats, x, labels, rngs):
         frozen, t, opt_state, stats = map(_strip,
                                           (frozen, t, opt_state, stats))
         x, labels, rng = x[0], labels[0], rngs[0]
@@ -945,19 +974,19 @@ def make_lora_train_step(pipe: PipelineModel,
 
         (_, (loss, new_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(t)
-        grads = jax.tree_util.tree_map(
-            lambda g: jax.lax.psum(g, "stage"), grads)
+        with jax.named_scope("grad_sync"):
+            grads = jax.tree_util.tree_map(
+                lambda g: jax.lax.psum(g, "stage"), grads)
         if grad_sync is not None:
             c_idx = jax.lax.axis_index("client")
             grads = {"lora": grad_sync(grads["lora"], c_idx),
                      "head": grad_sync(grads["head"], c_idx)}
-        updates, new_opt = optimizer.update(grads, opt_state, t)
-        new_t = optax.apply_updates(t, updates)
-        return (*map(_restore, (new_t, new_opt, new_stats)), loss[None])
+        new_t, new_opt = _apply_optimizer(optimizer, grads, opt_state, t)
+        return new_t, new_opt, _restore(new_stats), loss[None]
 
     spec_c = P("client")
     mapped = jax.shard_map(
-        body, mesh=mesh,
+        sl_train_step, mesh=mesh,
         in_specs=(spec_c,) * 7,
         out_specs=(spec_c,) * 4,
         check_vma=False,
@@ -978,13 +1007,13 @@ def make_fedavg_step(mesh: Mesh, param_spec: P | None = None) -> Callable:
     ``client`` only; each device folds just its own slice)."""
     param_spec = P("client") if param_spec is None else param_spec
 
-    def body(params, weights):
+    def sl_fedavg(params, weights):
         p, w = _strip(params), weights[0]
         avg = fedavg_psum(p, w, "client")
         return _restore(avg)
 
     mapped = jax.shard_map(
-        body, mesh=mesh, in_specs=(param_spec, P("client")),
+        sl_fedavg, mesh=mesh, in_specs=(param_spec, P("client")),
         out_specs=param_spec, check_vma=False,
         **_shmap_kwargs(mesh))
     return jax.jit(mapped)

@@ -219,7 +219,8 @@ def make_zero1_train_step(pipe: PipelineModel, mesh: Mesh,
     grad_sync = _make_grad_sync(client_sync, mesh)
     unroll = pipe.scan_unroll_for(mesh)
 
-    def body(params, opt_state, stats, x, labels, rngs):
+    # named and scoped as pipeline.make_train_step's program is
+    def sl_train_step(params, opt_state, stats, x, labels, rngs):
         # opt moments arrive SHARDED: local block (1, shard_len)
         mu, nu = opt_state["mu"][0], opt_state["nu"][0]
         count = opt_state["count"][0]
@@ -236,11 +237,17 @@ def make_zero1_train_step(pipe: PipelineModel, mesh: Mesh,
 
         (_, (loss, new_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
-        grads = jax.tree_util.tree_map(
-            lambda g: jax.lax.psum(g, "stage"), grads)
+        with jax.named_scope("grad_sync"):
+            grads = jax.tree_util.tree_map(
+                lambda g: jax.lax.psum(g, "stage"), grads)
         if grad_sync is not None:
             grads = grad_sync(grads, jax.lax.axis_index("client"))
+        new_params, new_opt = sharded_adamw(params, grads, mu, nu, count,
+                                            shard_len)
+        return new_params, new_opt, _restore(new_stats), loss[None]
 
+    @jax.named_scope("optimizer")
+    def sharded_adamw(params, grads, mu, nu, count, shard_len):
         # flatten params+grads in one canonical ravel order; slice my shard
         pflat, unravel = ravel_pytree(params)
         gflat, _ = ravel_pytree(grads)
@@ -270,14 +277,15 @@ def make_zero1_train_step(pipe: PipelineModel, mesh: Mesh,
         new_opt = {"mu": mu32.astype(jnp.bfloat16)[None],
                    "nu": nu32.astype(jnp.bfloat16)[None],
                    "count": count[None]}
-        return (_restore(new_params), new_opt, _restore(new_stats),
-                loss[None])
+        # (the client axis is restored under the scope: XLA names a
+        # leaf's fused update after that last reshape)
+        return _restore(new_params), new_opt
 
     spec_c = P("client")
     spec_opt = {"mu": P("client", "stage"), "nu": P("client", "stage"),
                 "count": P("client")}
     mapped = jax.shard_map(
-        body, mesh=mesh,
+        sl_train_step, mesh=mesh,
         in_specs=(spec_c, spec_opt, spec_c, spec_c, spec_c, spec_c),
         out_specs=(spec_c, spec_opt, spec_c, spec_c),
         check_vma=False,

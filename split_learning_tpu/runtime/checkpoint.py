@@ -26,6 +26,7 @@ file can never block a restart.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import pathlib
@@ -79,12 +80,31 @@ def _publish(path: pathlib.Path, slot_name: str) -> None:
 
 def save_checkpoint(directory: str | pathlib.Path, model_key: str,
                     params: Any, batch_stats: Any | None = None,
-                    round_idx: int = 0, extra: dict | None = None) -> None:
+                    round_idx: int = 0, extra: dict | None = None,
+                    tracer=None) -> None:
+    """``tracer`` (``runtime/spans.py``), when given, journals the two
+    halves of the save: ``ckpt_pull`` (device to host) and
+    ``ckpt_store`` (the write and the symlink flip)."""
+    def span(name):
+        return (tracer.span(name) if tracer is not None
+                else contextlib.nullcontext())
+
     path = checkpoint_path(directory, model_key)
     path.parent.mkdir(parents=True, exist_ok=True)
-    tree = {"params": _to_host(params),
-            "batch_stats": _to_host(batch_stats or {}),
-            "meta": {"round_idx": np.int64(round_idx)}}
+    with span("ckpt_pull"):
+        tree = {"params": _to_host(params),
+                "batch_stats": _to_host(batch_stats or {}),
+                "meta": {"round_idx": np.int64(round_idx)}}
+    with span("ckpt_store"):
+        _store(path, model_key, tree)
+    if extra:
+        meta = path.parent / f"{model_key}.meta.json"
+        staged = path.parent / f".{model_key}.meta.json.tmp"
+        staged.write_text(json.dumps(extra))
+        os.replace(staged, meta)
+
+
+def _store(path: pathlib.Path, model_key: str, tree: Any) -> None:
     # write into the slot NOT currently live, then flip the symlink —
     # the previous checkpoint stays intact until the new one is complete
     live = os.readlink(path) if path.is_symlink() else None
@@ -98,11 +118,6 @@ def save_checkpoint(directory: str | pathlib.Path, model_key: str,
     shutil.rmtree(final, ignore_errors=True)
     os.replace(tmp, final)
     _publish(path, slot_name)
-    if extra:
-        meta = path.parent / f"{model_key}.meta.json"
-        staged = path.parent / f".{model_key}.meta.json.tmp"
-        staged.write_text(json.dumps(extra))
-        os.replace(staged, meta)
 
 
 def load_checkpoint(directory: str | pathlib.Path,

@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +46,7 @@ from split_learning_tpu.parallel.pipeline import (
 from split_learning_tpu.runtime.memo import bounded_setdefault
 from split_learning_tpu.runtime.plan import ClusterPlan
 from split_learning_tpu.runtime.protocol import Update
+from split_learning_tpu.runtime.spans import Laps
 from split_learning_tpu.runtime.validation import (
     ValResult, dataset_for_model, dataset_kwargs_for_model,
 )
@@ -99,6 +99,10 @@ class TrainContext:
     # re-pushed every round.
     clients_hold_state = False
 
+    #: span journal (``runtime/spans.py``): a context's own, or the one
+    #: the round loop lends it for a run; None outside one
+    tracer = None
+
     def init_variables(self) -> dict:
         raise NotImplementedError
 
@@ -126,6 +130,10 @@ class TrainContext:
 
 class MeshContext(TrainContext):
     """In-process compiled-mesh backend."""
+
+    #: the last ``train_cluster`` call's wall-clock attribution (the
+    #: resident path returns its own with the outcome)
+    last_timings: dict | None = None
 
     def __init__(self, cfg: Config, devices=None):
         self.cfg = cfg
@@ -493,8 +501,8 @@ class MeshContext(TrainContext):
     # -- the round ----------------------------------------------------------
 
     def _drive_columns(self, step, loaders, c_phys, M, mb, epochs,
-                       round_idx, params_c, opt_c, stats_c, *,
-                       frozen_c=None, timings: dict | None = None):
+                       round_idx, params_c, opt_c, stats_c, laps: Laps, *,
+                       frozen_c=None):
         """Feed host batches through the compiled step for ``epochs``.
 
         Returns (params_c, opt_c, stats_c, loss_host, consumed):
@@ -506,10 +514,11 @@ class MeshContext(TrainContext):
         so each column is capped at its loader's own epoch (and dataset)
         size.
 
-        ``timings``, when given, accumulates wall-clock attribution:
-        ``host_data_s`` (batch build + host->device handoff),
-        ``dispatch_s`` (async step-call returns), ``device_sync_s``
-        (final loss fetch — absorbs queued device execution).
+        The wall clock is read once at each boundary, by ``laps``: the
+        same readings are the spans ``feed`` (batch build), ``upload``
+        (host->device handoff), ``dispatch`` (the async step call's
+        return) a step and ``sync`` (final loss fetch — absorbs queued
+        device execution), and the caller's ``timings``.
         """
         steps_per_epoch = max(1, min(len(ld) for ld in loaders) // M)
         rngs = jax.vmap(jax.random.key)(jnp.arange(c_phys)
@@ -520,11 +529,10 @@ class MeshContext(TrainContext):
             consumed[i] = epochs * min(steps_per_epoch * M * mb,
                                        ld.samples_per_epoch,
                                        len(ld.dataset))
-        t_data = t_dispatch = 0.0
         for _ in range(epochs):
             iters = [iter(ld) for ld in loaders]
             for _ in range(steps_per_epoch):
-                t0 = time.perf_counter()
+                laps.lap("feed", always=False)
                 xs, ys = [], []
                 for it_i, it in enumerate(iters):
                     bx, by = [], []
@@ -538,9 +546,12 @@ class MeshContext(TrainContext):
                         by.append(np.asarray(b[1]))
                     xs.append(np.stack(bx))
                     ys.append(np.stack(by))
-                x = jnp.asarray(np.stack(xs))
-                labels = jnp.asarray(np.stack(ys).astype(np.int32))
-                t1 = time.perf_counter()
+                x_h = np.stack(xs)
+                labels_h = np.stack(ys).astype(np.int32)
+                laps.lap("upload", always=False)
+                x = jnp.asarray(x_h)
+                labels = jnp.asarray(labels_h)
+                laps.lap("dispatch", always=False)
                 if frozen_c is not None:
                     params_c, opt_c, stats_c, loss = step(
                         frozen_c, params_c, opt_c, stats_c, x,
@@ -548,17 +559,21 @@ class MeshContext(TrainContext):
                 else:
                     params_c, opt_c, stats_c, loss = step(
                         params_c, opt_c, stats_c, x, labels, rngs)
-                t2 = time.perf_counter()
-                t_data += t1 - t0
-                t_dispatch += t2 - t1
-        t3 = time.perf_counter()
+        laps.lap("sync")
         loss_h = (np.asarray(loss) if loss is not None
                   else np.zeros(c_phys))
-        if timings is not None:
-            timings["host_data_s"] = round(t_data, 3)
-            timings["dispatch_s"] = round(t_dispatch, 3)
-            timings["device_sync_s"] = round(time.perf_counter() - t3, 3)
         return params_c, opt_c, stats_c, loss_h, consumed
+
+    @staticmethod
+    def _timings(laps: Laps) -> dict:
+        """The round record's ``train_detail`` from the spans' seconds."""
+        total = laps.totals
+        out = {"host_data_s": total["feed"] + total["upload"],
+               "dispatch_s": total["dispatch"],
+               "device_sync_s": total["sync"]}
+        if "fedavg" in total:
+            out["fedavg_dispatch_s"] = total["fedavg"]
+        return {k: round(v, 3) for k, v in out.items()}
 
     def train_cluster_resident(self, plan: ClusterPlan, params, stats, *,
                                round_idx: int = 0, epochs: int = 1,
@@ -581,7 +596,19 @@ class MeshContext(TrainContext):
         keys on the IDENTITY of the params tree returned last round — a
         rollback or NaN skip in the round loop passes a different tree
         and transparently rebuilds from host.
+
+        Spans (children of the loop's ``train``): ``round_setup`` up to
+        the first step, ``feed``/``upload``/``dispatch`` a step, ``sync``,
+        ``fedavg``; their seconds are the outcome's ``timings``.
         """
+        with Laps(self.tracer, round=round_idx) as laps:
+            return self._resident_round(
+                laps, plan, params, stats, round_idx, epochs, lr,
+                sync_all_later_stages)
+
+    def _resident_round(self, laps: Laps, plan: ClusterPlan, params, stats,
+                        round_idx: int, epochs: int, lr: float | None,
+                        sync_all_later_stages: bool):
         import types
 
         par = self._parallel_axis()
@@ -592,6 +619,7 @@ class MeshContext(TrainContext):
         stage1 = plan.stage1_clients
         if not stage1:
             return None
+        laps.lap("round_setup")
         c_phys, s_phys, cuts_phys, tp, sp, ep = self._geometry(
             plan, len(stage1))
         if len(stage1) > c_phys:
@@ -622,14 +650,17 @@ class MeshContext(TrainContext):
             stats_c = shard_to_mesh(stack_for_clients(stats, c_phys),
                                     mesh)
 
-            def _opt_init(p_c):
+            # the functions' names are the programs' names in a trace
+            def sl_opt_init(p_c):
                 p0 = jax.tree_util.tree_map(lambda a: a[0], p_c)
                 return stack_for_clients(optimizer.init(p0), c_phys)
 
-            opt_init = jax.jit(_opt_init)
+            def sl_strip(t):
+                return jax.tree_util.tree_map(lambda a: a[0], t)
+
+            opt_init = jax.jit(sl_opt_init)
             fedavg = make_fedavg_step(mesh)
-            strip = jax.jit(
-                lambda t: jax.tree_util.tree_map(lambda a: a[0], t))
+            strip = jax.jit(sl_strip)
             old = getattr(self, "_resident", None)
             cache = {"key": key, "opt_init": opt_init, "fedavg": fedavg,
                      "strip": strip}
@@ -659,12 +690,11 @@ class MeshContext(TrainContext):
         else:
             opt_c = place_opt(opt_init(params_c), mesh)
 
-        timings: dict = {}
         loaders = [self._loader(c, counts[c], round_idx)
                    for c in stage1]
         params_c, opt_c, stats_c, loss_h, consumed = self._drive_columns(
             step, loaders, c_phys, M, mb, epochs, round_idx,
-            params_c, opt_c, stats_c, timings=timings)
+            params_c, opt_c, stats_c, laps)
 
         if not np.all(np.isfinite(loss_h)):
             # reference: any diverged client fails the whole round
@@ -673,13 +703,13 @@ class MeshContext(TrainContext):
             return types.SimpleNamespace(params=params, stats=stats,
                                          num_samples=0, ok=False)
 
-        t0 = time.perf_counter()
+        laps.lap("fedavg")
         weights = jnp.asarray(np.maximum(consumed, 1).astype(np.float32))
         avg_params_c = fedavg(params_c, weights)
         avg_stats_c = fedavg(stats_c, weights)
         ret_params = strip(avg_params_c)
         ret_stats = strip(avg_stats_c)
-        timings["fedavg_dispatch_s"] = round(time.perf_counter() - t0, 3)
+        laps.stop()
         cache.update(params_c=avg_params_c, stats_c=avg_stats_c,
                      token=id(ret_params), ret=(ret_params, ret_stats))
         if self.cfg.learning.opt_resident:
@@ -692,7 +722,8 @@ class MeshContext(TrainContext):
         self._resident = cache
         return types.SimpleNamespace(params=ret_params, stats=ret_stats,
                                      num_samples=int(consumed.sum()),
-                                     ok=True, timings=timings)
+                                     ok=True,
+                                     timings=self._timings(laps))
 
     def train_cluster(self, plan: ClusterPlan, params, stats, *,
                       round_idx: int = 0, epochs: int = 1,
@@ -702,10 +733,26 @@ class MeshContext(TrainContext):
                       sync_all_later_stages: bool = False,
                       send_params: bool = True,
                       send_weights: bool | dict = True) -> list[Update]:
+        """Spans, per column chunk (children of the caller's span):
+        ``round_setup`` up to the first step, ``feed``/``upload``/
+        ``dispatch`` a step, ``sync``, ``pull`` (trained columns to the
+        host), ``extract``; their seconds are ``last_timings``."""
         # send_params/send_weights are FLEX wire-economy knobs: in-process
         # columns have no wire, so "uploads" are free views and both flags
         # are no-ops here (ProtocolContext honors them)
         del send_params, send_weights
+        with Laps(self.tracer, round=round_idx) as laps:
+            updates = self._host_round(
+                laps, plan, params, stats, round_idx, epochs,
+                client_subset, per_client_params, lr,
+                sync_all_later_stages)
+        self.last_timings = self._timings(laps)
+        return updates
+
+    def _host_round(self, laps: Laps, plan: ClusterPlan, params, stats,
+                    round_idx: int, epochs: int, client_subset,
+                    per_client_params, lr, sync_all_later_stages: bool
+                    ) -> list[Update]:
         stage1 = [c for c in plan.stage1_clients
                   if client_subset is None or c in client_subset]
         if not stage1:
@@ -717,6 +764,7 @@ class MeshContext(TrainContext):
         updates: list[Update] = []
         n_chunks = math.ceil(len(stage1) / c_phys)
         for chunk_i in range(n_chunks):
+            laps.lap("round_setup")
             chunk = stage1[chunk_i * c_phys:(chunk_i + 1) * c_phys]
             pad = c_phys - len(chunk)
             if (self._parallel_axis() is not None and tp == 1
@@ -765,7 +813,8 @@ class MeshContext(TrainContext):
             params_c, opt_c, stats_c, loss_h, consumed = (
                 self._drive_columns(
                     step, loaders, c_phys, M, mb, epochs, round_idx,
-                    params_c, opt_c, stats_c, frozen_c=frozen_c))
+                    params_c, opt_c, stats_c, laps, frozen_c=frozen_c))
+            laps.lap("pull")
             if use_lora:
                 # bake adapters into dense weights per column before shard
                 # extraction (merge_and_unload parity)
@@ -778,6 +827,7 @@ class MeshContext(TrainContext):
                 )(frozen_c, params_c)
             params_h = jax.tree_util.tree_map(np.asarray, params_c)
             stats_h = jax.tree_util.tree_map(np.asarray, stats_c)
+            laps.lap("extract")
             updates.extend(self._extract_updates(
                 plan, chunk, cols, params_h, stats_h, loss_h, consumed,
                 client_sync))

@@ -44,6 +44,16 @@ class TrainResult:
     history: list
 
 
+def _write_checkpoint(tracer, parent: str | None, r: int, directory,
+                      model_key: str, params, stats) -> None:
+    """Round ``r``'s save, on the pool's thread: one ``checkpoint_write``
+    span over the whole of it, a child of the round's ``checkpoint``
+    span on the loop's thread (hence the explicit parent)."""
+    with tracer.span("checkpoint_write", parent=parent, round=r):
+        save_checkpoint(directory, model_key, params, stats,
+                        round_idx=r + 1, tracer=tracer)
+
+
 def run_training(cfg: Config, ctx: TrainContext,
                  plans: list[ClusterPlan],
                  logger: Logger | None = None,
@@ -53,12 +63,14 @@ def run_training(cfg: Config, ctx: TrainContext,
     strategy = make_strategy(cfg)
     # round tracing (runtime/spans.py): the context's tracer when it
     # has one (ProtocolContext), else a loop-owned one (in-process
-    # mesh runs) closed on exit
+    # mesh runs), lent to the context for the run so the spans of its
+    # own work (MeshContext: feed, upload, dispatch, fedavg, ...) land
+    # under this loop's, and closed on exit
     from split_learning_tpu.runtime.spans import make_tracer
     tracer = getattr(ctx, "tracer", None)
     own_tracer = tracer is None
     if own_tracer:
-        tracer = make_tracer(cfg, "server")
+        tracer = ctx.tracer = make_tracer(cfg, "server")
 
     start_round = 0
     params, stats = init_params, init_stats
@@ -191,12 +203,13 @@ def run_training(cfg: Config, ctx: TrainContext,
                                 f"({wall:.1f}s)", "green")
                 if rec.ok and cfg.checkpoint.save:
                     with timer.phase("checkpoint"), \
-                            tracer.span("checkpoint", round=r):
+                            tracer.span("checkpoint", round=r) as ck_span:
                         if ck_future is not None:
                             ck_future.result()  # surface errors; keep order
                         ck_future = ck_pool.submit(
-                            save_checkpoint, cfg.checkpoint.directory,
-                            cfg.model_key, params, stats, round_idx=r + 1)
+                            _write_checkpoint, tracer, ck_span.id, r,
+                            cfg.checkpoint.directory, cfg.model_key,
+                            params, stats)
                 history.append(rec)
                 logger.metric(kind="round", **dataclasses.asdict(rec),
                               phases=timer.summary(),
@@ -235,6 +248,7 @@ def run_training(cfg: Config, ctx: TrainContext,
             ck_future.result()  # the last checkpoint must be durable
         ck_pool.shutdown(wait=True)
         if own_tracer:
+            ctx.tracer = None
             tracer.close()
         else:
             tracer.flush()

@@ -20,6 +20,16 @@ summed.  This module is the attribution layer:
 * ``tools/sl_trace.py`` merges the journals into a Chrome/Perfetto
   ``trace.json`` and walks the span graph backward for a per-round
   critical-path report.
+* **Profiler captures** — a context-manager span also enters a
+  ``jax.profiler.TraceAnnotation`` named ``sl/<name>`` (with the
+  span's ``round``) on the thread that opened it, so any capture that
+  is running (``jax.profiler.start_trace``, the exporter's
+  ``POST /profile``) shows the host spans on the same clock as the
+  device's operations.  With no capture running that is a flag test.
+  ``start()``/``end()`` and ``record()`` spans may end on another
+  thread and enter none.
+* :class:`Laps` — back-to-back spans that share their clock readings
+  with the caller's own accounting (``MeshContext._drive_columns``).
 
 Costs are kept off the hot path: a disabled tracer returns a shared
 no-op span (no allocation beyond the call), sampling is a single RNG
@@ -32,6 +42,7 @@ ids, not timestamps — but RTTs absorb the skew).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import pathlib
@@ -41,6 +52,8 @@ import threading
 import time
 import uuid
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 from split_learning_tpu.runtime import blackbox
 
@@ -134,7 +147,10 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
-    def end(self, **attrs) -> None:
+    def end(self, t1=None, /, **attrs) -> None:
+        pass
+
+    def _close(self, t1=None) -> None:
         pass
 
 
@@ -149,35 +165,52 @@ class Span:
     tracer thread-state."""
 
     __slots__ = ("_tracer", "name", "id", "parent", "t0", "attrs",
-                 "_thread", "_done")
+                 "_thread", "_done", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, parent: str | None,
-                 attrs: dict):
+                 attrs: dict, t0: float | None = None):
         self._tracer = tracer
         self.name = name
         self.id = uuid.uuid4().hex[:16]
         self.parent = parent
-        self.t0 = time.time()
+        self.t0 = time.time() if t0 is None else t0
         self.attrs = attrs
         self._thread = threading.current_thread().name
         self._done = False
+        self._annotation = None
 
-    def end(self, **attrs) -> None:
+    def end(self, t1: float | None = None, /, **attrs) -> None:
+        """Write the record; ``t1`` is a clock reading the caller
+        already took (``time.time()``), else the clock is read here."""
         if self._done:
             return
         self._done = True
         if attrs:
             self.attrs.update(attrs)
-        self._tracer._emit(self, time.time())
+        self._tracer._emit(self, time.time() if t1 is None else t1)
 
     def __enter__(self):
         self._tracer._push(self.id)
+        # the same span on the profiler's clock: an event `sl/<name>` on
+        # this thread's line of any capture that is running
+        rnd = self.attrs.get("round")
+        self._annotation = (
+            TraceAnnotation("sl/" + self.name) if rnd is None
+            else TraceAnnotation("sl/" + self.name, round=rnd))
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._pop()
-        self.end()
+        self._close()
         return False
+
+    def _close(self, t1: float | None = None) -> None:
+        """Leave what ``__enter__`` entered, on the same thread."""
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        self._tracer._pop()
+        self.end(t1)
 
 
 class Tracer:
@@ -231,21 +264,24 @@ class Tracer:
         return random.random() < self.sample_rate
 
     def start(self, name: str, parent: str | None = None,
-              always: bool = True, **attrs: Any):
+              always: bool = True, t0: float | None = None,
+              **attrs: Any):
         """Open a span (ended explicitly via ``span.end()``).  With
         ``always=False`` the configured sample rate applies — use for
         per-frame/per-batch spans; structural spans (rounds, phases)
-        always record."""
+        always record.  ``t0`` is a ``time.time()`` reading the caller
+        already took."""
         if not self._sampled(always):
             return NULL_SPAN
         if parent is None:
             parent = self.current_id()
-        return Span(self, name, parent, attrs)
+        return Span(self, name, parent, attrs, t0)
 
     def span(self, name: str, parent: str | None = None,
              always: bool = True, **attrs: Any):
         """Context-manager span; children opened on this thread inside
-        the block inherit it as parent."""
+        the block inherit it as parent, and a running profiler capture
+        shows it as ``sl/<name>``."""
         s = self.start(name, parent=parent, always=always, **attrs)
         if s is NULL_SPAN:
             return contextlib.nullcontext(NULL_SPAN)
@@ -307,6 +343,61 @@ class Tracer:
     def close(self) -> None:
         if self._journal is not None:
             self._journal.close()
+
+
+class Laps:
+    """Back-to-back spans on one thread that share their clock
+    readings: ``lap(name)`` reads ``time.time()`` once, ends the open
+    span there and opens the next at the same instant; ``stop()``, or
+    leaving the block, ends the last.  ``totals`` holds the seconds by
+    name whether or not anything was journaled (no tracer, a disabled
+    one, a lap sampled out), so a caller's own accounting and the
+    journal are one measurement::
+
+        with Laps(tracer, round=r) as laps:
+            for batch in batches:
+                laps.lap("feed", always=False)
+                ...
+                laps.lap("dispatch", always=False)
+                ...
+            laps.lap("sync")
+            ...
+        host_s = laps.totals["feed"]
+    """
+
+    def __init__(self, tracer: "Tracer | None", **attrs: Any):
+        self._tracer = tracer
+        self._attrs = attrs
+        self._span: Any = NULL_SPAN
+        self._name: str | None = None
+        self._t = 0.0
+        self.totals: dict = collections.defaultdict(float)
+
+    def lap(self, name: str, always: bool = True) -> None:
+        t = time.time()
+        self._end(t)
+        self._name, self._t = name, t
+        if self._tracer is not None:
+            self._span = self._tracer.start(name, always=always, t0=t,
+                                            **self._attrs)
+            self._span.__enter__()
+
+    def stop(self) -> None:
+        """End the open lap, if any, now."""
+        self._end(time.time())
+
+    def _end(self, t: float) -> None:
+        if self._name is not None:
+            self.totals[self._name] += t - self._t
+            self._span._close(t)
+            self._span, self._name = NULL_SPAN, None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.stop()
+        return False
 
 
 def make_tracer(cfg, participant: str) -> Tracer:

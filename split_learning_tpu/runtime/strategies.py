@@ -17,6 +17,7 @@ Aggregation math is shared: per-cluster per-stage weighted FedAvg
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import time
@@ -223,11 +224,16 @@ class FedAvgStrategy(RoundStrategy):
         cluster_params, cluster_stats = [], []
         total, ok = 0, True
         agg_s = 0.0
+        detail: collections.Counter = collections.Counter()
         for plan in plans:
             ups = ctx.train_cluster(
                 plan, params, stats, round_idx=round_idx,
                 epochs=self._epochs(), lr=self._lr(round_idx),
                 sync_all_later_stages=self.sync_all_later_stages)
+            # the mesh context's clock readings for this cluster (the
+            # same seconds its spans journal); the protocol context
+            # keeps none
+            detail.update(getattr(ctx, "last_timings", None) or {})
             ok &= all(u.ok for u in ups)
             t0 = time.perf_counter()
             p, s, n = aggregate_cluster(ups)
@@ -259,6 +265,7 @@ class FedAvgStrategy(RoundStrategy):
                 out = RoundOutcome(merge_clusters(cluster_params),
                                    merge_clusters(cluster_stats),
                                    num_samples=total)
+        out.metrics = dict(detail)
         return out
 
 

@@ -1,4 +1,4 @@
-"""Runtime tracing: XLA profiler capture + per-phase step timing.
+"""Runtime tracing: per-phase step timing, counters and histograms.
 
 The reference's only observability is tqdm bars, per-batch loss prints,
 and a one-shot message-size probe (SURVEY.md §5.1); its in-message
@@ -7,10 +7,6 @@ and a one-shot message-size probe (SURVEY.md §5.1); its in-message
 * :class:`StepTimer` — named wall-clock phase accumulators with
   ``jax.block_until_ready`` fencing, dumped as a metrics dict (feeds the
   metrics.jsonl sidecar, ``runtime/log.py``);
-* :func:`trace` — context manager around ``jax.profiler`` writing a
-  TensorBoard-loadable XLA trace;
-* :func:`annotate` — ``TraceAnnotation`` wrapper so host-side round
-  phases (plan/train/aggregate/validate) show up on the trace timeline;
 * :class:`FaultCounters` — thread-safe failure/recovery counters
   (``drops``, ``timeouts``, ``redeliveries``, ``dedup_hits``,
   ``reconnects``, ...) shared by the transport stack
@@ -471,18 +467,3 @@ class StepTimer:
     def reset(self):
         self.totals.clear()
         self.counts.clear()
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture an XLA profiler trace (view with TensorBoard/XProf)."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Host-side phase marker visible on the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)

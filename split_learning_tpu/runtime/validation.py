@@ -73,14 +73,15 @@ class ValResult:
 
 
 def make_eval_step(model, has_stats: bool):
+    # the function's name is the program's name in a profiler trace
     @jax.jit
-    def step(variables, x, labels):
+    def sl_eval_step(variables, x, labels):
         logits = model.apply(variables, x, train=False)
         loss = optax.softmax_cross_entropy_with_integer_labels(
             logits, labels).sum()
         correct = jnp.sum(jnp.argmax(logits, axis=-1) == labels)
         return loss, correct
-    return step
+    return sl_eval_step
 
 
 def evaluate(model_key: str, variables: dict, batch_size: int = 200,

@@ -13,10 +13,11 @@ This tool merges them:
   from the server's ``round`` span end: follow the latest activity on
   the current participant, hop across participants along frame flow
   edges, and accrue every walked interval into one of ``compute`` /
-  ``compile`` / ``wire`` / ``queue_wait`` / ``aggregate`` /
-  ``control`` (``compile`` spans come from the perf plane's
-  CompileWatch, so a cold round's compile tax is separated from
-  device compute).
+  ``compile`` / ``wire`` / ``input`` / ``queue_wait`` /
+  ``aggregate`` / ``control`` (``compile`` spans come from the perf
+  plane's CompileWatch, so a cold round's compile tax is separated
+  from device compute; ``input`` is the mesh context building and
+  uploading a step's batch).
   The walk covers the round interval exactly, so the components sum to
   the round's wall time by construction; ``queue_wait`` absorbs the
   un-spanned intervals (queue residency, barrier waits, client-side
@@ -57,10 +58,24 @@ CATEGORY = {
     "checkpoint": "aggregate", "plan": "aggregate",
     "start_fanout": "control", "syn_fanout": "control",
     "pause_fanout": "control",
+    # the in-process mesh path (runtime/context.py): a step's batch is
+    # built and uploaded (`input`), the compiled step is called and the
+    # last loss awaited (`compute`: the device's queued work), columns
+    # are averaged on the mesh or pulled and folded on the host
+    "feed": "input", "upload": "input",
+    "dispatch": "compute", "sync": "compute",
+    "fedavg": "aggregate", "pull": "aggregate", "extract": "aggregate",
+    "checkpoint_write": "aggregate",
+    "round_setup": "control",
 }
 
-CATEGORIES = ("compute", "compile", "wire", "queue_wait", "aggregate",
-              "control")
+CATEGORIES = ("compute", "compile", "wire", "input", "queue_wait",
+              "aggregate", "control")
+
+#: categorized spans that run beside the round's thread: the loop only
+#: waits for one inside its ``checkpoint`` span, so the walk back from a
+#: ``train`` span's end never passes through them
+BACKGROUND_NAMES = frozenset({"checkpoint_write"})
 
 #: required keys of one spans.jsonl record (schema v1)
 SPAN_REQUIRED = frozenset({"v", "trace", "span", "name", "part", "ts",
@@ -223,7 +238,7 @@ def validate_trace(trace: dict) -> list[str]:
 def _leaves_by_part(spans: list[dict]) -> dict[str, list[dict]]:
     out: dict[str, list[dict]] = collections.defaultdict(list)
     for s in spans:
-        if s["name"] in CATEGORY:
+        if s["name"] in CATEGORY and s["name"] not in BACKGROUND_NAMES:
             out[s["part"]].append(s)
     return out
 
@@ -357,8 +372,8 @@ def render_report(rounds: list[dict]) -> str:
     if not rounds:
         return "no 'round' spans found — was tracing enabled?"
     lines = ["per-round critical path (compute | compile | wire | "
-             "queue-wait | aggregate | control; queue-wait includes "
-             "barrier/idle time):"]
+             "input | queue-wait | aggregate | control; queue-wait "
+             "includes barrier/idle time):"]
     for r in rounds:
         c = r["components_s"]
         pct = {k: (100.0 * v / r["wall_s"] if r["wall_s"] else 0.0)
