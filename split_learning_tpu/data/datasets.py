@@ -163,7 +163,13 @@ def _cifar(train: bool, synthetic_size, n_classes: int):
         mean, std = _CIFAR100_MEAN, _CIFAR100_STD
     if raw is not None:
         x, y = raw
-        x = (x.astype(np.float32) / 255.0 - mean) / std
+        # in place: one array of the set's size, not four (each fresh one
+        # is paged in anew); the files' memory order (planes, NHWC by
+        # strides) stays
+        x = x.astype(np.float32)
+        x /= 255.0
+        x -= mean
+        x /= std
         return ArrayDataset(x, y)
     n = synthetic_size or (10000 if train else 2000)
     return _synthetic_images(n, (32, 32, 3), n_classes,

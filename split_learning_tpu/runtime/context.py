@@ -26,6 +26,7 @@ interface.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 
@@ -500,6 +501,12 @@ class MeshContext(TrainContext):
 
     # -- the round ----------------------------------------------------------
 
+    # Steps that may be unfinished when ``_drive_columns`` uploads a batch.
+    # Three cover the host stalls seen on the chip (80 ms against a step of
+    # 50 ms, PERF.md section 6, PR 27); each costs one uploaded batch of
+    # device memory.
+    STEPS_AHEAD = 3
+
     def _drive_columns(self, step, loaders, c_phys, M, mb, epochs,
                        round_idx, params_c, opt_c, stats_c, laps: Laps, *,
                        frozen_c=None):
@@ -514,16 +521,39 @@ class MeshContext(TrainContext):
         so each column is capped at its loader's own epoch (and dataset)
         size.
 
+        The step's host batch is assembled in one pass: ``x_h``
+        ``(columns, M, mb, ...)`` and ``labels_h`` ``(columns, M, mb)``
+        int32 are allocated once a step and each column's loader fills
+        its ``M`` slots (``Epoch.fill``), so a sample is written once on
+        the host, from the data set into the array ``jnp.asarray`` takes.
+        Those arrays are NEVER written again: on the CPU backend
+        ``jnp.asarray`` may alias an aligned numpy array, and on the chip
+        the upload may still be reading it after the call returns —
+        hence a fresh allocation every step and no ring of buffers.
+        Nothing here runs beside the device on a thread: the step call
+        is asynchronous, so the host builds batch k+1 while the device
+        runs step k.  The host's lead is held to ``STEPS_AHEAD``
+        unfinished steps: a batch is built at once but uploaded only
+        when the step that many before it has finished, so the device
+        holds ``STEPS_AHEAD + 1`` batches at most however much faster
+        than the step the feed is (unbounded, a feed three times as fast
+        as the step queued thirteen steps and their uploaded batches by
+        a round's end), while a stall of the host shorter than the
+        queued steps' run time never reaches the device.
+
         The wall clock is read once at each boundary, by ``laps``: the
         same readings are the spans ``feed`` (batch build), ``upload``
         (host->device handoff), ``dispatch`` (the async step call's
-        return) a step and ``sync`` (final loss fetch — absorbs queued
-        device execution), and the caller's ``timings``.
+        return) a step, ``sync`` (the host waits for the device: before
+        an upload until ``STEPS_AHEAD`` steps are unfinished, and the
+        final loss fetch, which absorbs the queued execution), and the
+        caller's ``timings``.
         """
         steps_per_epoch = max(1, min(len(ld) for ld in loaders) // M)
         rngs = jax.vmap(jax.random.key)(jnp.arange(c_phys)
                                         + round_idx * 1000)
         loss = None
+        unfinished: collections.deque = collections.deque()
         consumed = np.zeros(c_phys, dtype=np.int64)
         for i, ld in enumerate(loaders):
             consumed[i] = epochs * min(steps_per_epoch * M * mb,
@@ -533,21 +563,21 @@ class MeshContext(TrainContext):
             iters = [iter(ld) for ld in loaders]
             for _ in range(steps_per_epoch):
                 laps.lap("feed", always=False)
-                xs, ys = [], []
+                x_h, labels_h = loaders[0].empty((len(loaders), M),
+                                                 label_dtype=np.int32)
                 for it_i, it in enumerate(iters):
-                    bx, by = [], []
-                    for _ in range(M):
+                    for m in range(M):
                         try:
-                            b = next(it)
+                            it.fill(x_h, labels_h, (it_i, m))
                         except StopIteration:
                             it = iters[it_i] = iter(loaders[it_i])
-                            b = next(it)
-                        bx.append(np.asarray(b[0]))
-                        by.append(np.asarray(b[1]))
-                    xs.append(np.stack(bx))
-                    ys.append(np.stack(by))
-                x_h = np.stack(xs)
-                labels_h = np.stack(ys).astype(np.int32)
+                            it.fill(x_h, labels_h, (it_i, m))
+                if len(unfinished) > self.STEPS_AHEAD:
+                    laps.lap("sync", always=False)
+                    # a wait for the step STEPS_AHEAD back: the queue
+                    # behind it keeps the device busy, nothing drains
+                    oldest = unfinished.popleft()
+                    oldest.block_until_ready()  # slcheck: sampled-gate
                 laps.lap("upload", always=False)
                 x = jnp.asarray(x_h)
                 labels = jnp.asarray(labels_h)
@@ -559,6 +589,7 @@ class MeshContext(TrainContext):
                 else:
                     params_c, opt_c, stats_c, loss = step(
                         params_c, opt_c, stats_c, x, labels, rngs)
+                unfinished.append(loss)
         laps.lap("sync")
         loss_h = (np.asarray(loss) if loss is not None
                   else np.zeros(c_phys))

@@ -54,6 +54,13 @@ def test_cifar10_pickle_batches(data_dir):
         ds.inputs[0], (raw0.astype(np.float32) / 255.0 - mean) / std,
         rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(ds.labels[:2], [1, 2])
+    # normalised in place, to the bit what the one expression gives, and
+    # still in the files' memory order (planes)
+    raw = np.concatenate([d for d, _ in train_parts]).reshape(
+        -1, 3, 32, 32).transpose(0, 2, 3, 1)
+    want = (raw.astype(np.float32) / 255.0 - mean) / std
+    assert ds.inputs.tobytes() == want.tobytes()
+    assert ds.inputs.strides == want.strides
 
     val = get_dataset("CIFAR10", train=False)
     assert len(val) == 3
