@@ -83,77 +83,148 @@ def moving_leaves(g_ref: dict) -> set:
 
 
 # -- the reference's three steps ------------------------------------------
+#
+# What the device holds at the peak of ``follow``: the parameters once, one
+# accumulating gradient, the gradient of the microbatch (or row block) in
+# hand and that block's activations: 12 bytes a parameter in float32.  The
+# starting parameters and the optimizer's state live on the host between
+# steps, the update goes leaf by leaf, and a sum takes the place of what it
+# sums (donation).  Every leaf sees the operations it always saw, in the
+# same order, so the numbers are the same.
 
 def _ce(logits, labels):
+    """Cross-entropy of every labelled position: logits ``(..., classes)``,
+    labels ``(...)``."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    return -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
+    return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
 
 
 @functools.lru_cache(maxsize=8)
 def _grad_fn(ref, cast, denom):
+    extra = getattr(ref, "extra_objective", None)
+
     def loss_sum(params, stats, x, labels, key):
         logits = ref.forward(params, stats, x, train=True, key=key,
                              cast=cast)
         return _ce(logits, labels).sum() / denom
-    return jax.jit(jax.value_and_grad(loss_sum))
+    if extra is None:
+        return jax.jit(jax.value_and_grad(loss_sum))
+
+    def objective(params, stats, x, labels, key):
+        # what the program differentiates: CE plus the terms its modules
+        # sow, weighted as it weights them; what it reports: CE alone
+        ce = loss_sum(params, stats, x, labels, key)
+        return ce + extra(params, stats, x, key, cast), ce
+    both = jax.jit(jax.value_and_grad(objective, has_aux=True))
+
+    def ce_and_grad(*args):
+        (_, ce), grads = both(*args)
+        return ce, grads
+    return ce_and_grad
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _sum_in_place(acc, g):
+    return jax.tree_util.tree_map(jnp.add, acc, g)
+
+
+def _add_into(acc, g):
+    """``acc + g``, in ``acc``'s place (the caller then drops ``g``).  An
+    ``acc`` that waits on the host is brought back first."""
+    if acc is None:
+        return g
+    return _sum_in_place(jax.tree_util.tree_map(jnp.asarray, acc), g)
+
+
+def _row_block(ref, rows: int) -> int:
+    """A module without batch statistics may take a microbatch in blocks
+    of ``ROW_BLOCK`` rows; one whose objective has a term of the whole
+    microbatch (``extra_objective``) takes it whole."""
+    block = getattr(ref, "ROW_BLOCK", None)
+    if not block or hasattr(ref, "extra_objective"):
+        return rows
+    return min(block, rows)
 
 
 def microbatch_grad(ref, params, stats, x, labels, key, cast=None):
-    """Mean loss and its gradient over one microbatch, in row blocks
-    where the module allows it (no batch statistics)."""
-    block = getattr(ref, "ROW_BLOCK", None) or x.shape[0]
-    fn = _grad_fn(ref, cast, x.shape[0])
+    """Mean loss over one microbatch's labels and its gradient, in row
+    blocks where the module allows it."""
+    block = _row_block(ref, x.shape[0])
+    fn = _grad_fn(ref, cast, labels.size)
     loss, grads = 0.0, None
     for lo in range(0, x.shape[0], block):
         l, g = fn(params, stats, x[lo:lo + block], labels[lo:lo + block],
                   key)
         loss = loss + l
-        grads = g if grads is None else jax.tree_util.tree_map(
-            jnp.add, grads, g)
+        # the sum is waited for and ``g`` dropped, or the next block's
+        # gradient would be allocated beside this one's
+        grads = jax.block_until_ready(_add_into(grads, g))
+        del g
     return loss, grads
 
 
 def optimizer_step(learning: dict, params, state, grads, t: int):
-    """SGD with momentum, or AdamW, as optax defines them."""
+    """SGD with momentum, or AdamW, as optax defines them, leaf by leaf:
+    ``state`` (``None`` before the first step) is a list of host arrays,
+    a leaf's in ``params``' flattening order, so the device holds one
+    leaf's state at a time beside the old and the new parameters and the
+    gradient."""
     lr = learning["learning-rate"]
-    tm = jax.tree_util.tree_map
-    if learning["optimizer"] == "sgd":
-        mom = learning.get("momentum", 0.9)
-        trace = grads if state is None else tm(
-            lambda g, s: g + mom * s, grads, state)
-        return tm(lambda p, s: p - lr * s, params, trace), trace
+    sgd = learning["optimizer"] == "sgd"
+    mom = learning.get("momentum", 0.9)
     wd = learning.get("weight-decay", 0.0)
-    mu, nu = state or (tm(jnp.zeros_like, grads), tm(jnp.zeros_like, grads))
-    mu = tm(lambda m, g: ADAM_B1 * m + (1 - ADAM_B1) * g, mu, grads)
-    nu = tm(lambda v, g: ADAM_B2 * v + (1 - ADAM_B2) * g * g, nu, grads)
     c1, c2 = 1 - ADAM_B1 ** t, 1 - ADAM_B2 ** t
-    new = tm(lambda p, m, v: p - lr * (
-        (m / c1) / (jnp.sqrt(v / c2) + ADAM_EPS) + wd * p), params, mu, nu)
-    return new, (mu, nu)
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    new, new_state = [], []
+    # each expression is waited for: buffers are allocated when an operation
+    # is enqueued, and a leaf's whole chain of temporaries enqueued at once
+    # stood beside one another (13 of the largest leaf's size on the chip)
+    done = jax.block_until_ready
+    for i, (p, g) in enumerate(zip(leaves, treedef.flatten_up_to(grads))):
+        if sgd:
+            trace = g if state is None else g + mom * jnp.asarray(state[i])
+            new.append(done(p - lr * trace))
+            new_state.append(jax.device_get(trace))
+        else:
+            m, v = (jnp.zeros_like(g), jnp.zeros_like(g)) if state is None \
+                else (jnp.asarray(state[i][0]), jnp.asarray(state[i][1]))
+            mu = done(ADAM_B1 * m + (1 - ADAM_B1) * g)
+            nu = done(ADAM_B2 * v + (1 - ADAM_B2) * g * g)
+            new.append(done(p - lr * (
+                (mu / c1) / (jnp.sqrt(nu / c2) + ADAM_EPS) + wd * p)))
+            new_state.append(jax.device_get((mu, nu)))
+    return jax.tree_util.tree_unflatten(treedef, new), new_state
 
 
 def follow(ref, learning: dict, params, stats, feed, cast=None,
            fault=None) -> dict:
     """Drive the reference through ``feed`` — a list of
-    ``(x[M, mb, ...], labels[M, mb], key_data)``, one per optimizer step —
-    and return its losses, first gradient's and final change's leaf
-    norms.  ``cast`` makes it the low-precision control; ``fault`` plants
-    ``half_batch`` (the second half of the microbatches left out, the
-    mean taken over the rest)."""
-    p0, state, losses, g1 = params, None, [], None
+    ``(x[M, mb, ...], labels[M, mb, ...], key_data)``, one per optimizer
+    step — from ``params`` (best a host tree: the device then holds the
+    one copy made here) and return its losses, first gradient's and final
+    change's leaf norms.  ``cast`` makes it the low-precision control;
+    ``fault`` plants ``half_batch`` (the second half of the microbatches
+    left out, the mean taken over the rest)."""
+    p0 = jax.device_get(params)
+    params = jax.tree_util.tree_map(jnp.asarray, p0)
+    state, losses, g1 = None, [], None
     for t, (x, labels, key_data) in enumerate(feed, start=1):
         key = jax.random.wrap_key_data(jnp.asarray(key_data))
         n_mb = x.shape[0]
         if fault == "half_batch":
             n_mb = max(1, n_mb // 2)
+        in_blocks = _row_block(ref, x.shape[1]) < x.shape[1]
         loss, grads = 0.0, None
         for m in range(n_mb):
+            if grads is not None and in_blocks:
+                # the microbatch's own sum takes its place on the device
+                grads = jax.device_get(grads)
             l, g = microbatch_grad(
                 ref, params, stats, jnp.asarray(x[m]),
                 jnp.asarray(labels[m]), jax.random.fold_in(key, m), cast)
             loss = loss + l / n_mb
-            grads = g if grads is None else jax.tree_util.tree_map(
-                jnp.add, grads, g)
+            grads = _add_into(grads, g)
+            del g
         grads = jax.tree_util.tree_map(lambda g: g / n_mb, grads)
         if g1 is None:
             g1 = leaf_norms(grads)
@@ -201,7 +272,7 @@ def val_loss(ref, params, stats, rows, labels, batch: int,
     for lo in range(0, len(labels), batch):
         total += float(fn(params, stats, jnp.asarray(rows[lo:lo + batch]),
                           jnp.asarray(labels[lo:lo + batch])))
-    return total / len(labels)
+    return total / labels.size
 
 
 def tree_mismatch(a, b) -> int:

@@ -343,7 +343,8 @@ class Job:
         self.val_rows, self.val_labels = traffic_gen.make_dataset(
             conf["dataset"]["kind"], env.work / "data", seed,
             conf["dataset"]["train"], conf["dataset"]["val"],
-            vocab=self.model_kwargs.get("vocab_size"))
+            vocab=self.model_kwargs.get("vocab_size"),
+            seq_len=conf["dataset"].get("seq-len"))
         self.base = from_dict(merge(self.program, {
             "seed": seed % (2 ** 31),
             "log-path": str(env.work / "logs"),
@@ -477,7 +478,8 @@ class Job:
 
     def _training_numbers(self, tap, p0, s0, cast, fault) -> dict:
         """The reference's three steps on the tapped feed (column 0: the
-        columns train apart) against the tap's readings."""
+        columns train apart) against the tap's readings.  ``p0`` is a
+        host tree: ``follow`` makes the device's one copy of it."""
         import jax
         import compare
         col = lambda tree: jax.tree_util.tree_map(  # noqa: E731
@@ -490,7 +492,7 @@ class Job:
                                  cast=cast, fault=fault)
         tapped = {"losses": [float(l[0]) for l in tap.losses],
                   "opt1": col(tap.opt1),
-                  "dparam": compare.diff_norms(col(tap.p3), to_host(p0))}
+                  "dparam": compare.diff_norms(col(tap.p3), p0)}
         return compare.numbers(self.ref, learning, tapped, ref_run)
 
     def compare(self, cast=None, fault=None) -> dict:
@@ -506,6 +508,7 @@ class Job:
         p0, s0 = self.weights()
         got, t1 = {}, t0
         if fault != "stale_val":
+            p0 = jax.device_get(p0)     # the device's copy goes with this
             got = self._training_numbers(tap, p0, s0, cast, fault)
             t1 = time.perf_counter()
             finals = [jax.tree_util.tree_map(lambda a, i=i: a[i], f)
@@ -551,6 +554,11 @@ def main(argv=None) -> int:
     t_ref = time.perf_counter()
     got = job.compare()
     reference_s = time.perf_counter() - t_ref
+    # a process's peak never falls: this is the reference's where it holds
+    # more than the program did, and the program's where it does not
+    peak_after_reference = max(
+        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for d in env.devices)
 
     # the configuration's ``limits`` names the numbers it holds; what the
     # comparison read besides goes under ``info``
@@ -633,6 +641,7 @@ def main(argv=None) -> int:
                        {k: v["total_s"] for k, v in rec["phases"].items()}
                        for rec in win["rounds"]],
                    "reference_s": reference_s,
+                   "peak_in_use_after_reference": peak_after_reference,
                    "reference_phases_s": job.reference_phases_s,
                    "setup_compile_s": job.setup_compile_s,
                    "steps_in_window": run["steps_in_window"],

@@ -244,5 +244,6 @@ def test_every_new_metric_is_declared_for_both_cells():
         assert declared[name]["moves"] == "round_throughput"
         assert declared[name]["workloads"] == ["bert_base_c7.round",
                                                "vgg16_c7.round"]
-    # appended after the eight that were there, which are as they were
-    assert [m["name"] for m in spec["per_layer"]][8:] == NEW_METRICS
+    # appended after the older ones (seven since PR 28 retired
+    # `host_data_share`), which are as they were
+    assert [m["name"] for m in spec["per_layer"]][7:] == NEW_METRICS
