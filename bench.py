@@ -432,12 +432,12 @@ def _measure_pipe_step(model_name: str, cuts, example_shape, example_dtype,
     flops = float(cost["flops"]) if cost and cost.get("flops") else None
 
     # warm-up step, then the timed loop
-    params_c, opt_c, stats_c, loss = step(params_c, opt_c, stats_c, x,
+    params_c, opt_c, stats_c, loss, _ = step(params_c, opt_c, stats_c, x,
                                           labels, rng)
     jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for _ in range(steps):
-        params_c, opt_c, stats_c, loss = step(params_c, opt_c, stats_c, x,
+        params_c, opt_c, stats_c, loss, _ = step(params_c, opt_c, stats_c, x,
                                               labels, rng)
     jax.block_until_ready(loss)
     dt = time.perf_counter() - t0

@@ -190,7 +190,7 @@ def _audit_hot_loops(root: pathlib.Path) -> list[Finding]:
 
 # -- donated-then-reused ----------------------------------------------------
 # Convention (parallel/pipeline.py make_*_train_step): a step called as
-#   params, opt, stats, loss = step(params, opt, stats, x, labels, rngs)
+#   params, opt, stats, loss, *_ = step(params, opt, stats, x, labels, rngs)
 # donates positions (0, 1, 2); the frozen/LoRA variant
 #   t, opt, stats, loss = step(frozen, t, opt, stats, x, labels, rngs)
 # donates (1, 2, 3).  Call sites must rebind every donated argument

@@ -61,6 +61,11 @@ _REL_KUPDATE = "split_learning_tpu/ops/kernels/update.py"
 #: (B, S, H, D) the flash kernels are held to: TinyLlama's 32 x 64
 #: heads and the 16 x 128 heads of ROADMAP R1, at sequence 2048
 FLASH_SHAPES = ((2, 2048, 32, 64), (2, 2048, 16, 128))
+#: (B, S, H, KV, D, window, block) of the benchmark's token cell
+#: (``mellum2_12b_c3``): 32 query heads over 4 key-value heads, blocks of
+#: 512, with the window of its sliding layers and without
+FLASH_GROUPED = ((2, 4096, 32, 4, 128, 1024, 512),
+                 (2, 4096, 32, 4, 128, None, 512))
 #: one microbatch of the VGG16 cut-7 boundary (configs/baseline1.yaml):
 #: the activation the codec quantizes, and its gradient
 CUT7_BOUNDARY = (32, 16, 16, 64)
@@ -139,6 +144,20 @@ def lowering_cases() -> list[tuple]:
         cases.append((
             f"flash_bwd{shape}", _REL_FLASH,
             jax.grad(lambda q, k, v: flash(q, k, v).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2)), qkv))
+    for b, s, h, kv, d, window, block in FLASH_GROUPED:
+        def grouped(q, k, v, window=window, block=block):
+            return flash_attention(q, k, v, causal=True, window=window,
+                                   block_q=block, block_k=block,
+                                   interpret=False)
+        qkv = (abstract((b, s, h, d), jnp.bfloat16),
+               abstract((b, s, kv, d), jnp.bfloat16),
+               abstract((b, s, kv, d), jnp.bfloat16))
+        name = f"({b}, {s}, {h}/{kv}, {d}) window {window}"
+        cases.append((f"flash_fwd{name}", _REL_FLASH, grouped, qkv))
+        cases.append((
+            f"flash_bwd{name}", _REL_FLASH,
+            jax.grad(lambda q, k, v, f=grouped: f(q, k, v).astype(
                 jnp.float32).sum(), argnums=(0, 1, 2)), qkv))
     n = int(np.prod(CUT7_BOUNDARY))
     for tile in QUANT_TILES:

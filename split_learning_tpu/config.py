@@ -84,6 +84,9 @@ class LearningConfig:
     # whose boundary exceeds the width threshold; "all" is the blanket
     # recompute; "none" stores every stage's activations
     remat: str = "wide"
+    # weight of the sown MoE load-balancing terms in the compiled
+    # pipeline's objective (parallel/pipeline.py ``moe_aux_weight``)
+    moe_aux_weight: float = 0.01
     # Asynchronous decoupled split learning (ROADMAP item 2; *Decoupled
     # Split Learning via Auxiliary Loss*, arxiv 2601.19261 + staleness-
     # tolerant pipelining, arxiv 2412.14374).  "sync" (default) is the

@@ -20,6 +20,7 @@ import split_learning_tpu.models.vit  # noqa: F401  (registers ViT_*)
 import split_learning_tpu.models.mobilenet  # noqa: F401  (MobileNetv1_*)
 import split_learning_tpu.models.resnet  # noqa: F401  (ResNet50_*)
 import split_learning_tpu.models.llama  # noqa: F401  (TinyLlama_*)
+import split_learning_tpu.models.mellum  # noqa: F401  (Mellum2_*)
 
 __all__ = [
     "LayerSpec", "SplitModel", "build_model", "model_registry",

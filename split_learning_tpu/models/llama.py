@@ -32,17 +32,30 @@ from split_learning_tpu.models.split import (
 )
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray,
-          base: float = 10000.0) -> jnp.ndarray:
-    """Rotary embedding over the last dim of (B, S, H, D)."""
-    d = x.shape[-1]
-    inv_freq = 1.0 / (base ** (np.arange(0, d, 2) / d))
+def rope_inv_freq(head_dim: int, base: float) -> np.ndarray:
+    """Plain rotary frequencies ``base^(-2i / head_dim)``, i = 0..D/2-1."""
+    return 1.0 / (base ** (np.arange(0, head_dim, 2) / head_dim))
+
+
+def _rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: np.ndarray,
+          interleaved: bool = True, factor: float = 1.0) -> jnp.ndarray:
+    """Rotary embedding over the last dim of (B, S, H, D).  The caller
+    says which frequencies (``inv_freq``, D/2 of them), which pairing —
+    ``interleaved`` turns the pairs (2i, 2i + 1), the half-split
+    (``rotate_half``) convention the pairs (i, i + D/2) — and a
+    ``factor`` on cos and sin (YaRN's attention factor)."""
     freqs = positions[:, None].astype(jnp.float32) * inv_freq[None, :]
-    cos = jnp.cos(freqs)[None, :, None, :]
-    sin = jnp.sin(freqs)[None, :, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    rot = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return rot.reshape(x.shape).astype(x.dtype)
+    cos = factor * jnp.cos(freqs)[None, :, None, :]
+    sin = factor * jnp.sin(freqs)[None, :, None, :]
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        rot = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = jnp.split(x, 2, axis=-1)
+        rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1)
+    return rot.astype(x.dtype)
 
 
 class LlamaAttention(nn.Module):
@@ -57,6 +70,7 @@ class LlamaAttention(nn.Module):
     num_kv_heads: int
     dtype: jnp.dtype = jnp.float32
     use_flash: bool = False
+    rope_base: float = 10000.0
     # sequence-parallel mode (parallel/sequence.py): when set, this
     # module runs inside shard_map with `seq_axis` defined, x is the
     # LOCAL token block, RoPE positions offset by the global block
@@ -81,9 +95,12 @@ class LlamaAttention(nn.Module):
             pos = jax.lax.axis_index(self.seq_axis) * s + jnp.arange(s)
         else:
             pos = jnp.arange(s)
-        q, k = _rope(q, pos), _rope(k, pos)
+        inv_freq = rope_inv_freq(hd, self.rope_base)
+        q, k = _rope(q, pos, inv_freq), _rope(k, pos, inv_freq)
         rep = self.num_heads // self.num_kv_heads
-        if rep > 1:
+        if rep > 1 and not (self.use_flash and self.seq_axis is None):
+            # the flash kernel picks a query head's key-value head by
+            # index; the ring and the einsum want one of each
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
 

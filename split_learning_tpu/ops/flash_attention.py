@@ -20,6 +20,16 @@ matrix never leaves the core: O(S) memory, MXU-shaped (block_q x D) @
   ``delta = rowsum(dO * O)`` is a cheap XLA-fused pre-pass.
   Causal runs skip fully-masked blocks in both kernels (~2x fewer MXU
   contractions at large S).
+* ``window`` (causal only): a query at ``p`` sees keys ``p - window + 1
+  .. p``.  The loop bounds of all three kernels skip the blocks wholly
+  outside that band, so a window of a quarter of the row does about a
+  quarter of a full layer's work; only the blocks the band's two edges
+  cross pay for the mask.
+* grouped-query heads: ``k``/``v`` may carry fewer heads than ``q``.
+  Query head ``h`` reads key-value head ``h // rep`` by BLOCK INDEX (no
+  repeated copy of K/V is made); the ``dKV`` kernel's grid gets a third,
+  innermost axis over the group's query heads and sums their
+  contributions in the resident float32 output tile.
 * ``interpret=None`` auto-selects the Pallas interpreter off-TPU, so the
   same code path runs in CPU tests and compiles natively on TPU.
 """
@@ -56,44 +66,110 @@ def _dot(a, b, dims, precision):
 
 
 # --------------------------------------------------------------------------
+# which blocks a kernel visits
+# --------------------------------------------------------------------------
+
+def _cdiv(a, b):
+    return (a + b - 1) // b
+
+
+def _key_blocks(qi, block_q: int, block_k: int, nk: int, causal: bool,
+                window):
+    """Key blocks ``[lo, hi)`` that query block ``qi`` may see, and inside
+    them ``[full_lo, full_hi)``: the blocks every one of its queries sees
+    whole, which need no mask."""
+    if not causal:
+        return 0, 0, nk, nk
+    q0 = qi * block_q
+    hi = jnp.minimum(nk, _cdiv(q0 + block_q, block_k))
+    full_hi = (q0 + 1) // block_k
+    if window is None:
+        lo = full_lo = 0
+    else:
+        lo = jnp.maximum(0, q0 - window + 1) // block_k
+        full_lo = _cdiv(jnp.maximum(0, q0 + block_q - window), block_k)
+    full_lo = jnp.clip(full_lo, lo, hi)
+    return lo, full_lo, jnp.clip(full_hi, full_lo, hi), hi
+
+
+def _query_blocks(kb, block_q: int, block_k: int, nq: int, causal: bool,
+                  window):
+    """The same for key block ``kb``: the query blocks that may see it."""
+    if not causal:
+        return 0, 0, nq, nq
+    k0 = kb * block_k
+    lo = k0 // block_q
+    full_lo = _cdiv(k0 + block_k - 1, block_q)
+    if window is None:
+        hi = full_hi = nq
+    else:
+        hi = jnp.minimum(nq, (k0 + block_k + window - 2) // block_q + 1)
+        full_hi = jnp.maximum(0, k0 + window) // block_q
+    full_lo = jnp.clip(full_lo, lo, hi)
+    return lo, full_lo, jnp.clip(full_hi, full_lo, hi), hi
+
+
+def _band(s, q0, k0, window, keys_first: bool = False):
+    """Scores outside the causal band (and the window) set to NEG_INF;
+    ``s`` is (queries, keys), or (keys, queries) with ``keys_first``."""
+    q_axis, k_axis = (1, 0) if keys_first else (0, 1)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_axis)
+    seen = k_pos <= q_pos
+    if window is not None:
+        seen &= k_pos > q_pos - window
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _sweep(bounds, body, carry, causal: bool):
+    """``body(i, carry, masked)`` over ``[lo, hi)``: the blocks the band's
+    edges cross with the mask, the blocks between them without."""
+    lo, full_lo, full_hi, hi = bounds
+    if not causal:
+        return jax.lax.fori_loop(
+            lo, hi, functools.partial(body, masked=False), carry)
+    edge = functools.partial(body, masked=True)
+    carry = jax.lax.fori_loop(lo, full_lo, edge, carry)
+    carry = jax.lax.fori_loop(
+        full_lo, full_hi, functools.partial(body, masked=False), carry)
+    return jax.lax.fori_loop(full_hi, hi, edge, carry)
+
+
+# --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                causal: bool, scale: float, block_q: int, precision):
+                causal: bool, scale: float, block_q: int, precision,
+                window):
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # (block_q, D)
-    s_total = k_ref.shape[1]
-    nk = s_total // block_k
+    q = q_ref[0]                                       # (block_q, D)
+    nk = k_ref.shape[1] // block_k
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros(q.shape, jnp.float32)
-    # causal: K/V blocks entirely in this query block's future contribute
-    # exactly zero — skip them (~2x fewer MXU contractions at large S)
-    nk_eff = jnp.minimum(
-        nk, ((qi + 1) * block_q + block_k - 1) // block_k) if causal \
-        else nk
 
-    def body(kb, carry):
+    def body(kb, carry, masked):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = _dot(q, k, ((1,), (1,)), precision)        # (block_q, block_k)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
+        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
+        s = _dot(q, k, ((1,), (1,)), precision) * scale  # (bq, bk)
+        if masked:
+            s = _band(s, qi * block_q, kb * block_k, window)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        acc_new = acc * corr + _dot(p, v, ((1,), (0,)), precision)
+        acc_new = acc * corr + _dot(p.astype(v.dtype), v, ((1,), (0,)),
+                                    precision)
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, nk_eff, body, (m0, l0, acc0))
+    # causal: K/V blocks entirely in this query block's future (or behind
+    # its window) contribute exactly zero — skip them
+    m, l, acc = _sweep(
+        _key_blocks(qi, block_q, block_k, nk, causal, window), body,
+        (m0, l0, acc0), causal)
     l_safe = jnp.where(l > 0, l, 1.0)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     # logsumexp of the SCALED scores: exp(s - lse) rebuilds softmax rows
@@ -102,8 +178,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
 
 def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
-                    block_q: int, block_k: int):
-    """(BH, S, D) flattened forward via pallas_call -> (o, lse).
+                    block_q: int, block_k: int, window, rep: int):
+    """(BH, S, D) flattened forward via pallas_call -> (o, lse); ``k``
+    and ``v`` are ``(BH / rep, S, D)``.
 
     ``lse`` (and the backward's ``delta``) are per-row values kept as
     ``(BH, S, 1)`` columns: a ``(block_q, 1)`` block is legal on TPU
@@ -115,7 +192,8 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
     precision = _pick_precision(q.dtype)
     kernel = functools.partial(_fwd_kernel, block_k=block_k,
                                causal=causal, scale=scale,
-                               block_q=block_q, precision=precision)
+                               block_q=block_q, precision=precision,
+                               window=window)
     return pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -123,8 +201,8 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, s, d), lambda b, i: (b // rep, 0, 0)),
+            pl.BlockSpec((1, s, d), lambda b, i: (b // rep, 0, 0)),
         ],
         out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))],
@@ -139,89 +217,96 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, block_q: int, block_k: int,
-                    causal: bool, scale: float, precision):
+                    causal: bool, scale: float, precision, window):
+    """Works on the TRANSPOSED scores (keys, queries): ``dV = P^T dO`` and
+    ``dK = dS^T Q`` are then plain products, and the queries' statistics
+    ``lse``/``delta`` come as lane-dense ``(1, S)`` rows that broadcast
+    down the keys (as ``(S, 1)`` columns they took 2 MB of VMEM each,
+    padded to 128 lanes)."""
     kb = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                   # (block_k, D)
-    v = v_ref[0].astype(jnp.float32)
-    s_total = q_ref.shape[1]
-    nq = s_total // block_q
+    k = k_ref[0]                                       # (block_k, D)
+    v = v_ref[0]
+    nq = q_ref.shape[1] // block_q
 
-    # causal: Q blocks entirely before this K block see none of it
-    qb_start = (kb * block_k) // block_q if causal else 0
-
-    def body(qb, carry):
+    def body(qb, carry, masked):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q), :]   # (bq, 1)
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q), :]
-        s = _dot(q, k, ((1,), (1,)), precision) * scale  # (bq, bk)
-        if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        q = q_ref[0, pl.ds(qb * block_q, block_q), :]
+        do = do_ref[0, pl.ds(qb * block_q, block_q), :]
+        lse = lse_ref[0, :, pl.ds(qb * block_q, block_q)]   # (1, bq)
+        delta = delta_ref[0, :, pl.ds(qb * block_q, block_q)]
+        s = _dot(k, q, ((1,), (1,)), precision) * scale  # (bk, bq)
+        if masked:
+            s = _band(s, qb * block_q, kb * block_k, window,
+                      keys_first=True)
         p = jnp.exp(s - lse)                           # exact softmax rows
-        dv_new = dv + _dot(p, do, ((0,), (0,)), precision)
-        dp = _dot(do, v, ((1,), (1,)), precision)      # (bq, bk)
+        dv_new = dv + _dot(p.astype(do.dtype), do, ((1,), (0,)), precision)
+        dp = _dot(v, do, ((1,), (1,)), precision)      # (bk, bq)
         ds = p * (dp - delta) * scale
-        dk_new = dk + _dot(ds, q, ((0,), (0,)), precision)
+        dk_new = dk + _dot(ds.astype(q.dtype), q, ((1,), (0,)), precision)
         return dk_new, dv_new
 
-    dk0 = jnp.zeros(k.shape, jnp.float32)
-    dv0 = jnp.zeros(v.shape, jnp.float32)
-    dk, dv = jax.lax.fori_loop(qb_start, nq, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    # causal: Q blocks entirely before this K block (or past its window)
+    # see none of it
+    dk, dv = _sweep(
+        _query_blocks(kb, block_q, block_k, nq, causal, window), body,
+        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)),
+        causal)
+
+    # the grid's innermost axis walks the query heads that share this
+    # key-value head: their contributions add up in the resident tile
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dk_ref[0] = dk
+        dv_ref[0] = dv
+
+    @pl.when(pl.program_id(2) != 0)
+    def _():
+        dk_ref[0] += dk
+        dv_ref[0] += dv
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, *, block_q: int, block_k: int, causal: bool,
-                   scale: float, precision):
+                   scale: float, precision, window):
     qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)                   # (block_q, D)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]                                       # (block_q, D)
+    do = do_ref[0]
     lse = lse_ref[0]                                   # (block_q, 1)
     delta = delta_ref[0]
-    s_total = k_ref.shape[1]
-    nk = s_total // block_k
-    nk_eff = jnp.minimum(
-        nk, ((qi + 1) * block_q + block_k - 1) // block_k) if causal \
-        else nk
+    nk = k_ref.shape[1] // block_k
 
-    def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
+    def body(kb, dq, masked):
+        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
+        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
         s = _dot(q, k, ((1,), (1,)), precision) * scale  # (bq, bk)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        if masked:
+            s = _band(s, qi * block_q, kb * block_k, window)
         p = jnp.exp(s - lse)
         dp = _dot(do, v, ((1,), (1,)), precision)
         ds = p * (dp - delta) * scale
-        return dq + _dot(ds, k, ((1,), (0,)), precision)
+        return dq + _dot(ds.astype(k.dtype), k, ((1,), (0,)), precision)
 
-    dq = jax.lax.fori_loop(0, nk_eff, body,
-                           jnp.zeros(q.shape, jnp.float32))
+    dq = _sweep(_key_blocks(qi, block_q, block_k, nk, causal, window),
+                body, jnp.zeros(q.shape, jnp.float32), causal)
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, interpret, block_q, block_k):
-    o, _ = _flash_fwd_bhsd(q, k, v, causal, interpret, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, interpret, block_q, block_k, window, rep):
+    o, _ = _flash_fwd_bhsd(q, k, v, causal, interpret, block_q, block_k,
+                           window, rep)
     return o
 
 
-def _flash_fwd_rule(q, k, v, causal, interpret, block_q, block_k):
-    o, lse = _flash_fwd_bhsd(q, k, v, causal, interpret, block_q, block_k)
+def _flash_fwd_rule(q, k, v, causal, interpret, block_q, block_k, window,
+                    rep):
+    o, lse = _flash_fwd_bhsd(q, k, v, causal, interpret, block_q, block_k,
+                             window, rep)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, interpret, block_q, block_k, res, do):
+def _flash_bwd_rule(causal, interpret, block_q, block_k, window, rep, res,
+                    do):
     q, k, v, o, lse = res
     bh, s, d = q.shape
     scale = 1.0 / np.sqrt(d)
@@ -229,44 +314,42 @@ def _flash_bwd_rule(causal, interpret, block_q, block_k, res, do):
     # delta = rowsum(dO * O): cheap elementwise pre-pass, XLA fuses it
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
         axis=-1, keepdims=True)
+    static = dict(block_q=block_q, block_k=block_k, causal=causal,
+                  scale=scale, precision=precision, window=window)
 
-    full = pl.BlockSpec((1, s, d), lambda b, j: (b, 0, 0))
-    row_full = pl.BlockSpec((1, s, 1), lambda b, j: (b, 0, 0))
-    row_q = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
-
+    # dKV: one instance a (key-value head, key block, query head of the
+    # group); the float32 tiles stay resident over the last axis
+    head = lambda b, j, r: (b * rep + r, 0, 0)         # noqa: E731
+    kv_block = pl.BlockSpec((1, block_k, d), lambda b, j, r: (b, j, 0))
+    as_row = lambda t: t.reshape(bh, 1, s)             # noqa: E731
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          precision=precision),
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        grid=(bh, s // block_k),
-        in_specs=[full,
-                  pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-                  pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-                  full, row_full, row_full],
-        out_specs=[pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-                   pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0))],
+        functools.partial(_bwd_dkv_kernel, **static),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(v.shape, jnp.float32)],
+        grid=(bh // rep, s // block_k, rep),
+        in_specs=[pl.BlockSpec((1, s, d), head), kv_block, kv_block,
+                  pl.BlockSpec((1, s, d), head),
+                  pl.BlockSpec((1, 1, s), head),
+                  pl.BlockSpec((1, 1, s), head)],
+        out_specs=[kv_block, kv_block],
         interpret=interpret,
         name="slt_flash_bwd_dkv",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, as_row(lse), as_row(delta))
 
+    full = pl.BlockSpec((1, s, d), lambda b, i: (b // rep, 0, 0))
+    q_block = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    row_q = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          precision=precision),
+        functools.partial(_bwd_dq_kernel, **static),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(bh, s // block_q),
-        in_specs=[pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-                  full, full,
-                  pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-                  row_q, row_q],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+        in_specs=[q_block, full, full, q_block, row_q, row_q],
+        out_specs=q_block,
         interpret=interpret,
         name="slt_flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
-    return dq, dk, dv
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -274,17 +357,31 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def flash_attention(q, k, v, causal: bool = False,
                     interpret: bool | None = None,
-                    block_q: int = 128, block_k: int = 128) -> jnp.ndarray:
-    """Fused attention over (B, S, H, D) tensors.
+                    block_q: int = 128, block_k: int = 128,
+                    window: int | None = None) -> jnp.ndarray:
+    """Fused attention over ``q`` (B, S, H, D) and ``k``, ``v``
+    (B, S, KV, D) with ``H`` a multiple of ``KV``: query head ``h`` reads
+    key-value head ``h // (H / KV)``.
 
-    ``interpret=None`` runs the Pallas interpreter unless on real TPU.
-    S must be divisible by the (auto-shrunk) block sizes.
+    ``window`` (with ``causal``): a query at ``p`` sees keys
+    ``p - window + 1 .. p``.  ``interpret=None`` runs the Pallas
+    interpreter unless on real TPU.  S must be divisible by the
+    (auto-shrunk) block sizes.
     """
     interpret = resolve_interpret(interpret)
     b, s, h, d = q.shape
+    kv = k.shape[2]
+    if h % kv or v.shape != k.shape:
+        raise ValueError(f"{h} query heads over key/value {k.shape}, "
+                         f"{v.shape}")
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window needs causal=True and window >= 1")
+    if window is not None and window >= s:
+        window = None                   # the band is the causal triangle
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(s, block_k)
-    to_bhsd = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, s, d)  # noqa
+    to_bhsd = lambda t: t.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+        b * t.shape[2], s, d)
     out = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v), causal, interpret,
-                 block_q, block_k)
+                 block_q, block_k, window, h // kv)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -22,6 +22,10 @@ weight is zero, so they pass through the residual unchanged — standard
 Switch semantics).  The load-balance auxiliary loss is sown into the
 ``intermediates`` collection; :func:`moe_aux_loss` or the bundled
 train step adds it to the objective.
+
+:class:`HeldMoEMLP` is the other layer: no capacity and no drops (pairs
+sorted by expert, grouped matrix products), for one chip's share of the
+experts of a router that picks among all of them.
 """
 
 from __future__ import annotations
@@ -116,10 +120,9 @@ class MoEMLP(nn.Module):
 
     Memory note: the dense dispatch/combine tensors are (T, E, C) with
     C ≈ k·T/E·factor, i.e. O(k·T²·factor) per MoE layer regardless of
-    E.  At T = B·S ≈ 16k tokens that is ~GB-scale in fp32; keep
-    T ≲ 8k per call (shard the batch/sequence first), or route within
-    fixed-size groups (reshape to (G, T/G) and vmap this module over G)
-    before scaling further.
+    E.  Past a few thousand tokens a call, or where a dropped token is a
+    different result, use :class:`HeldMoEMLP`: sorted dispatch, grouped
+    products, no capacity and no drops.
     """
     hidden_size: int
     intermediate_size: int
@@ -160,6 +163,197 @@ class MoEMLP(nn.Module):
         out = jnp.einsum("tec,ech->th", combine.astype(self.dtype),
                          expert_out)
         return out.reshape(b, s, h)
+
+
+# --------------------------------------------------------------------------
+# drop-free held-share layer: sorted dispatch, grouped products
+# --------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def take_rows(src, idx, back_idx, back_ok, fan: int):
+    """``src[idx]`` whose backward pass is a gather too.  ``idx`` picks
+    every row of ``src`` at most ``fan`` times, and ``back_idx[r * fan +
+    j]`` (valid where ``back_ok``) is where row ``r``'s ``j``-th copy
+    went: so ``d_src[r] = sum_j d_out[back_idx[r, j]]``, with no
+    scatter-add."""
+    return src[idx]
+
+
+def _take_rows_fwd(src, idx, back_idx, back_ok, fan):
+    return src[idx], (back_idx, back_ok)
+
+
+def _take_rows_bwd(fan, res, g):
+    back_idx, back_ok = res
+    back = jnp.where(back_ok[:, None], g[back_idx], 0)
+    d_src = back.reshape(-1, fan, g.shape[-1]).sum(axis=1) if fan > 1 \
+        else back
+    return d_src.astype(g.dtype), None, None, None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def route_held(probs: jnp.ndarray, k: int, first: int, n_held: int):
+    """Top-``k`` routing over ALL experts, and the plan for the experts
+    ``first .. first + n_held - 1`` that live here.
+
+    Returns ``(weights, plan, aux)``: ``weights`` (T, k) are the chosen
+    experts' probabilities renormalized over the k chosen; ``aux`` is the
+    load-balancing term ``E * sum_e f_e P_e`` over all experts (``f_e``
+    the pairs routed to ``e`` over T, ``P_e`` the mean probability);
+    ``plan`` holds the (token, choice) pairs sorted by held expert, the
+    pairs of absent experts last:
+
+    * ``row_pair`` (R,): the pair ``t * k + j`` in buffer row ``r``, for
+      ``R = min(k, n_held) * T`` rows — a token picks distinct experts,
+      so no more of its pairs can be held: nothing is ever dropped;
+    * ``pair_row`` (T * k,), ``pair_ok``: the row a pair sits in, and
+      whether it is a held pair (absent experts' pairs are not);
+    * ``group_sizes`` (n_held,): pairs of each held expert, in order.
+    """
+    t, e = probs.shape
+    top_p, top_i = jax.lax.top_k(probs, k)
+    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    counts = jnp.zeros((e,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
+    aux = e * jnp.sum(counts / t * jnp.mean(probs, axis=0))
+
+    local = top_i.reshape(-1) - first
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    rows = min(k, n_held) * t
+    pair_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    plan = {"row_pair": order[:rows],
+            "pair_row": jnp.minimum(pair_row, rows - 1),
+            "pair_ok": held,
+            "group_sizes": counts[first:first + n_held].astype(jnp.int32)}
+    return weights, plan, aux
+
+
+class _ExpertKernel(nn.Module):
+    """``(n, d_in, d_out)``: one matrix an expert, the experts leading."""
+    shape: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param(
+            "kernel", nn.initializers.lecun_normal(batch_axis=(0,)),
+            self.shape)
+
+
+class _ExpertBank(nn.Module):
+    """The held experts' SwiGLU over rows sorted by expert: three grouped
+    matrix products (``jax.lax.ragged_dot``) over ``group_sizes``; rows
+    past their sum belong to no expert and come out as they may (the
+    caller masks them)."""
+    n: int
+    hidden_size: int
+    intermediate_size: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, rows, group_sizes):
+        n, d, f = self.n, self.hidden_size, self.intermediate_size
+        gate, up, down = (
+            _ExpertKernel(shape, name=name)().astype(self.dtype)
+            for name, shape in (("gate_proj", (n, d, f)),
+                                ("up_proj", (n, d, f)),
+                                ("down_proj", (n, f, d))))
+        precision = (jax.lax.Precision.HIGHEST
+                     if self.dtype == jnp.float32 else None)
+        dot = functools.partial(jax.lax.ragged_dot,
+                                group_sizes=group_sizes,
+                                precision=precision)
+        return dot(nn.silu(dot(rows, gate)) * dot(rows, up), down)
+
+
+class HeldMoEMLP(nn.Module):
+    """Top-k mixture-of-experts FFN that computes the part of the result
+    its OWN experts give, and drops nothing.
+
+    The router is ``num_experts`` wide and picks ``k`` of ALL experts,
+    their weights renormalized over the k chosen.  Of the (token, expert)
+    pairs, those whose expert is one of ``held`` (consecutive ids; ``None``:
+    all) are sorted by expert, their rows gathered, run through the
+    experts' SwiGLU as grouped matrix products, scaled by their weights
+    and summed back into their tokens.  What the absent experts would add
+    is left out: on a chip of an expert-parallel job that partial sum is
+    what the exchange would complete; the shares of all chips add up to
+    the whole layer (``tests/test_moe_held.py``).
+
+    There is no capacity.  The row buffer has the proven worst case,
+    ``min(k, len(held)) * T`` rows (every token picking every held
+    expert), and the grouped products do the work of the pairs there are;
+    the gathers into and out of the buffer move all of it.  Backward
+    passes are gathers as well (:func:`take_rows`).
+
+    Sown: ``aux_loss`` under ``intermediates`` (load balance over all
+    experts, from the router alone), and two counters that a step of
+    ``parallel/pipeline.py`` folds and hands back (its ``COUNTER_FOLDS``):
+    ``moe_pairs_held`` under ``counters_sum`` (the pairs computed here: an
+    operator reads from it what share of the routed work this chip holds,
+    and whether a live router drifts towards the held experts) and
+    ``moe_load_max_over_mean`` under ``counters_max`` (the fullest held
+    expert's pairs over the mean).  Nothing can be dropped, so there is no
+    counter of dropped pairs.
+    Scopes: ``moe_route`` (router, top-k, sort, gathers, weighted sum) and
+    ``moe_experts`` (the grouped products).
+    """
+    hidden_size: int
+    intermediate_size: int
+    num_experts: int = 8
+    k: int = 2
+    held: tuple | None = None
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, h = x.shape
+        t, k = b * s, self.k
+        held = tuple(self.held) if self.held is not None \
+            else tuple(range(self.num_experts))
+        first, n_held = held[0], len(held)
+        if held != tuple(range(first, first + n_held)) \
+                or first + n_held > self.num_experts:
+            raise ValueError(f"held experts must be consecutive ids "
+                             f"under {self.num_experts}: {held}")
+        if k > self.num_experts:
+            raise ValueError(f"top-k k={k} exceeds num_experts="
+                             f"{self.num_experts}")
+        xt = x.reshape(t, h)
+        with jax.named_scope("moe_route"):
+            logits = nn.Dense(self.num_experts, use_bias=False,
+                              dtype=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST,
+                              name="router")(xt.astype(jnp.float32))
+            weights, plan, aux = route_held(
+                jax.nn.softmax(logits, axis=-1), k, first, n_held)
+            row_pair, pair_row = plan["row_pair"], plan["pair_row"]
+            pair_ok, sizes = plan["pair_ok"], plan["group_sizes"]
+            rows = take_rows(xt, row_pair // k, pair_row, pair_ok, k)
+        self.sow("intermediates", "aux_loss", aux)
+        pairs = jnp.sum(sizes).astype(jnp.float32)
+        self.sow("counters_sum", "moe_pairs_held", pairs)
+        self.sow("counters_max", "moe_load_max_over_mean",
+                 jnp.max(sizes) * n_held / jnp.maximum(pairs, 1.0))
+        with jax.named_scope("moe_experts"):
+            y = _ExpertBank(n_held, h, self.intermediate_size, self.dtype,
+                            name="experts")(rows, sizes)
+        with jax.named_scope("moe_route"):
+            back = take_rows(y, pair_row, row_pair,
+                             jnp.ones(row_pair.shape, bool), 1)
+            # BOTH masks are needed: an absent expert's pair reads a
+            # buffer row past the groups, which the grouped product
+            # leaves as it may (NaN on the chip).  The outer mask keeps
+            # that out of the result; the mask on the weight keeps
+            # ``0 * NaN`` out of the router's gradient.
+            scale = jnp.where(pair_ok, weights.reshape(-1), 0.0)
+            out = jnp.where(pair_ok[:, None],
+                            back * scale[:, None].astype(back.dtype), 0)
+            out = out.reshape(t, k, h).sum(axis=1)
+        return out.reshape(b, s, h).astype(x.dtype)
 
 
 # --------------------------------------------------------------------------

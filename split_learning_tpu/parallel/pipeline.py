@@ -88,6 +88,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from split_learning_tpu.models import build_model, shard_params
 from split_learning_tpu.models.split import SplitModel
 from split_learning_tpu.ops.fedavg import fedavg_psum
+from split_learning_tpu.parallel.expert import moe_aux_loss
 from split_learning_tpu.parallel.mesh import stage_ranges
 
 
@@ -99,6 +100,43 @@ def _tree_flat_size(struct_tree) -> int:
     """Total per-sample wire width of a (possibly pytree) boundary."""
     return sum(_flat_size(leaf.shape)
                for leaf in jax.tree_util.tree_leaves(struct_tree))
+
+
+#: Collections a layer may sow scalar counters into (``self.sow(collection,
+#: name, value)``: what it did, never a term of the objective), and how a
+#: step folds each: over one leaf, over same-named leaves (the layers of a
+#: stage, the stages and microbatches of a step), and over the devices of
+#: the ``stage`` axis.  The layer owns its counters' names and, by the
+#: collection it picks, their rule; the pipeline knows neither
+COUNTER_FOLDS = {
+    "counters_sum": (jnp.sum, jnp.add, jax.lax.psum),
+    "counters_max": (jnp.max, jnp.maximum, jax.lax.pmax),
+}
+
+
+def sown_counters(mut: dict) -> dict:
+    """``{collection: {name: scalar}}`` of what one apply sowed into the
+    counter collections, same-named leaves folded by their collection's
+    rule; ``{}`` for a model that sows none."""
+    out: dict = {}
+    for col, (over_leaf, fold, _) in COUNTER_FOLDS.items():
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+                mut.get(col, {})):
+            name = next(p.key for p in reversed(path)
+                        if isinstance(getattr(p, "key", None), str))
+            value = over_leaf(leaf).astype(jnp.float32)
+            seen = out.setdefault(col, {})
+            seen[name] = fold(seen[name], value) if name in seen else value
+    return out
+
+
+def fold_counters(acc: dict, new: dict) -> dict:
+    """``acc`` with ``new``'s counters folded in, each by its rule; a name
+    ``new`` lacks stays as it is."""
+    return {col: {name: (COUNTER_FOLDS[col][1](v, new[col][name])
+                         if name in new.get(col, {}) else v)
+                  for name, v in names.items()}
+            for col, names in acc.items()}
 
 
 class PipelineModel:
@@ -194,15 +232,26 @@ class PipelineModel:
         var_shapes = jax.eval_shape(
             lambda: self.full_model.init(jax.random.key(0), jnp.zeros(
                 x.shape, x.dtype), train=False))
+        # the counters the model's layers sow (COUNTER_FOLDS), as a tree
+        # of zeros: what a step starts its count from.  ``{}`` for a
+        # model that sows none, whose step then carries nothing more
+        self.counters0: dict = {}
+
+        def shapes(m, sub, x):
+            out, mut = m.apply(sub, x, train=False,
+                               mutable=list(COUNTER_FOLDS))
+            return out, sown_counters(mut)
         for m, (a, b) in zip(shape_models, self.ranges):
             sub = {
                 col: shard_params(tree, self.specs, a, b)
                 for col, tree in var_shapes.items()
             }
-            out = jax.eval_shape(
-                functools.partial(m.apply, train=False), sub,
-                self.boundary[-1])
+            out, counted = jax.eval_shape(
+                functools.partial(shapes, m), sub, self.boundary[-1])
             self.boundary.append(out)
+            for col, names in counted.items():
+                self.counters0.setdefault(col, {}).update(
+                    dict.fromkeys(names, jnp.zeros(())))
         out_leaves = jax.tree_util.tree_leaves(self.boundary[-1])
         if len(out_leaves) != 1:
             raise ValueError(
@@ -329,7 +378,7 @@ class PipelineModel:
 
         Every branch has identical signature and output shapes
         (lax.switch requirement): ``(params, stats, wire_in, rng_data,
-        labels_mb) -> (wire, slot, stats, aux)``.  Under streamed loss
+        labels_mb) -> (wire, slot, stats, aux, counters)``.  Under streamed loss
         (default) ``slot`` is a scalar: the ``last`` branch fuses the
         final stage's apply WITH the microbatch's loss in one
         (optionally rematerialized) block — the logits are consumed
@@ -342,6 +391,9 @@ class PipelineModel:
         stage slice out of the replicated full tree; a
         :class:`StageParamLayout` unpacks it from this device's flat
         stage-sliced segment.
+
+        ``counters`` are the sown counters of its stages, folded into the
+        model's tree of them (``counters0``; ``{}`` where none is sown).
         """
         lo, hi = d * k, (d + 1) * k
         in_struct = self.boundary[lo]
@@ -356,6 +408,7 @@ class PipelineModel:
             x = self._from_wire(wire_in, in_struct)
             new_stats = dict(stats)
             aux = jnp.zeros(())
+            count = self.counters0
             loss_mb = jnp.zeros(())
             for s in range(lo, hi):
                 model = self.stage_models[s]
@@ -368,9 +421,6 @@ class PipelineModel:
                 # verifier failure, jax 0.9)
                 def apply_one(sp, st_in, x, rng_data, labels,
                               model=model, a=a, b=b, fuse=fuse_loss):
-                    from split_learning_tpu.parallel.expert import (
-                        moe_aux_loss,
-                    )
                     rng = jax.random.wrap_key_data(rng_data)
                     variables: dict = {"params": sp}
                     st = shard_params(st_in, self.specs, a, b)
@@ -378,7 +428,8 @@ class PipelineModel:
                         variables["batch_stats"] = st
                     out, mut = model.apply(
                         variables, x, train=train,
-                        mutable=["batch_stats", "intermediates"],
+                        mutable=["batch_stats", "intermediates",
+                                 *COUNTER_FOLDS],
                         rngs={"dropout": rng} if train else None)
                     if fuse:
                         # streamed loss: reduce the final output to the
@@ -394,18 +445,20 @@ class PipelineModel:
                     # sown MoE load-balance losses (zero for dense
                     # stages) join the objective on THIS device
                     return (out, mut.get("batch_stats", {}),
-                            moe_aux_loss(mut.get("intermediates", {})))
+                            moe_aux_loss(mut.get("intermediates", {})),
+                            sown_counters(mut))
 
                 # the stage's name, entered INSIDE what jax.checkpoint
                 # wraps, so that the recomputed copy carries it too
                 apply_one = jax.named_scope(f"stage{s + 1}")(apply_one)
                 if self.stage_remat[s]:
                     apply_one = jax.checkpoint(apply_one)
-                out, mut_stats, stage_aux = apply_one(
+                out, mut_stats, stage_aux, stage_count = apply_one(
                     stage_params_of(params, s), new_stats, x, rng_data,
                     labels_mb)
                 new_stats.update(mut_stats)
                 aux = aux + stage_aux
+                count = fold_counters(count, stage_count)
                 if fuse_loss:
                     loss_mb = out
                 else:
@@ -415,17 +468,18 @@ class PipelineModel:
                 if last:
                     return (jnp.zeros((mb, self.max_flat),
                                       self.wire_dtype),
-                            loss_mb, new_stats, aux)
-                return (self._to_wire(x), jnp.zeros(()), new_stats, aux)
+                            loss_mb, new_stats, aux, count)
+                return (self._to_wire(x), jnp.zeros(()), new_stats, aux,
+                        count)
             if last:
                 tail = jnp.concatenate(
                     [v.reshape(mb, -1).astype(self.wire_dtype)
                      for v in jax.tree_util.tree_leaves(x)], axis=1)
                 return (jnp.zeros((mb, self.max_flat), self.wire_dtype),
-                        tail, new_stats, aux)
+                        tail, new_stats, aux, count)
             return (self._to_wire(x),
                     jnp.zeros((mb, self.n_out), self.wire_dtype),
-                    new_stats, aux)
+                    new_stats, aux, count)
 
         return apply_device
 
@@ -452,10 +506,13 @@ class PipelineModel:
         device's flat stage-sliced segment instead of the replicated
         full tree (:func:`make_sliced_train_step`).
 
-        Returns ``(local_loss, (loss, new_stats))``: ``local_loss`` is this
-        device's (unsummed) contribution — the value to differentiate;
-        ``loss`` is the stage-psum'd scalar for reporting, and ``new_stats``
-        the stage-merged batch stats.
+        Returns ``(local_loss, (loss, new_stats, counters))``:
+        ``local_loss`` is this device's (unsummed) contribution — the
+        value to differentiate; ``loss`` is the stage-psum'd scalar for
+        reporting, ``new_stats`` the stage-merged batch stats, and
+        ``counters`` what the model's layers counted over the step's
+        stages and microbatches (``COUNTER_FOLDS``; ``{}`` for a model
+        that sows none).
         """
         S, M = self.n_stages, self.num_microbatches
         A = S if stage_axis_size is None else stage_axis_size
@@ -471,7 +528,7 @@ class PipelineModel:
         stats0 = stats
 
         def tick(carry, t):
-            act_wire, stats, acc, aux_acc = carry
+            act_wire, stats, acc, aux_acc, count_acc = carry
             inj_idx = jnp.clip(t, 0, M - 1)
             with jax.named_scope("hop"):
                 x_inj = self._to_wire(
@@ -491,7 +548,7 @@ class PipelineModel:
             c_idx = jnp.clip(t - (A - 1), 0, M - 1)
             labels_t = jax.lax.dynamic_index_in_dim(labels, c_idx, 0,
                                                     keepdims=False)
-            out_wire, out_slot, new_stats, aux = jax.lax.switch(
+            out_wire, out_slot, new_stats, aux, count = jax.lax.switch(
                 dev, branches, params, stats, act_in,
                 jax.random.key_data(rng_t), labels_t)
 
@@ -500,6 +557,8 @@ class PipelineModel:
             new_stats = jax.tree_util.tree_map(
                 lambda n, o: jnp.where(valid, n, o), new_stats, stats)
             aux_acc = aux_acc + jnp.where(valid, aux, 0.0)
+            count_acc = fold_counters(count_acc, jax.tree_util.tree_map(
+                lambda c: jnp.where(valid, c, 0.0), count))
 
             collect = (dev == A - 1) & (t >= A - 1)
             if self.stream_loss:
@@ -519,7 +578,7 @@ class PipelineModel:
             with jax.named_scope("hop"):
                 act_next = (jax.lax.ppermute(out_wire, "stage", perm)
                             if perm else out_wire)
-            return (act_next, new_stats, acc, aux_acc), None
+            return (act_next, new_stats, acc, aux_acc, count_acc), None
 
         del mesh_axes  # only relevant under check_vma, which we disable
         act0 = jnp.zeros((self.mb_size, self.max_flat), self.wire_dtype)
@@ -539,8 +598,8 @@ class PipelineModel:
         # reading them back, the carry's copies, the microbatches'
         # gradient accumulation) carries it without a stage's
         with jax.named_scope("pipeline"):
-            (_, stats_f, acc, aux_acc), _ = jax.lax.scan(
-                tick, (act0, stats0, acc0, jnp.zeros(())),
+            (_, stats_f, acc, aux_acc, count_acc), _ = jax.lax.scan(
+                tick, (act0, stats0, acc0, jnp.zeros(()), self.counters0),
                 jnp.arange(ticks), unroll=unroll)
 
         if self.stream_loss:
@@ -589,7 +648,10 @@ class PipelineModel:
                 lambda d: jax.lax.pmean(d, self.seq_axis), delta)
         stats_out = jax.tree_util.tree_map(
             lambda i, d: i + jax.lax.psum(d, "stage"), stats0, delta)
-        return local, (loss, stats_out)
+        counters = {col: {name: COUNTER_FOLDS[col][2](
+            jax.lax.stop_gradient(v), "stage") for name, v in names.items()}
+            for col, names in count_acc.items()}
+        return local, (loss, stats_out, counters)
 
 
 class StageParamLayout:
@@ -777,7 +839,10 @@ def make_train_step(pipe: PipelineModel, optimizer: optax.GradientTransformation
     device with microbatch gradient accumulation (no collective hops),
     preserving cut semantics on a single chip.
 
-    Returns (params, opt_state, stats, loss[C]).
+    Returns (params, opt_state, stats, loss[C], counters): ``counters``
+    is ``{collection: {name: value[C]}}``, what the model's layers sowed
+    into the counter collections (``COUNTER_FOLDS``) over the step, and
+    ``{}`` for a model that sows none.
     """
     grad_sync = _make_grad_sync(client_sync, mesh)
     stage_axis = int(mesh.shape["stage"])
@@ -799,7 +864,7 @@ def make_train_step(pipe: PipelineModel, optimizer: optax.GradientTransformation
                                           scan_unroll=unroll)
             return local, aux
 
-        (_, (loss, new_stats)), grads = jax.value_and_grad(
+        (_, (loss, new_stats, counters)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
         # each device produced grads for its own stage only; sync replicas
         with jax.named_scope("grad_sync"):
@@ -809,7 +874,8 @@ def make_train_step(pipe: PipelineModel, optimizer: optax.GradientTransformation
             grads = grad_sync(grads, jax.lax.axis_index("client"))
         new_params, new_opt = _apply_optimizer(optimizer, grads, opt_state,
                                                params)
-        return new_params, new_opt, _restore(new_stats), loss[None]
+        return (new_params, new_opt, _restore(new_stats), loss[None],
+                jax.tree_util.tree_map(lambda c: c[None], counters))
 
     spec_c = P("client")
     # x/labels carry the sequence on their last dim (token models):
@@ -823,7 +889,7 @@ def make_train_step(pipe: PipelineModel, optimizer: optax.GradientTransformation
     mapped = jax.shard_map(
         sl_train_step, mesh=mesh,
         in_specs=(spec_c, spec_c, spec_c, spec_x, spec_x, spec_c),
-        out_specs=(spec_c,) * 4,
+        out_specs=(spec_c,) * 5,
         check_vma=False,
         **_shmap_kwargs(mesh),
     )
@@ -879,7 +945,7 @@ def make_sliced_train_step(pipe: PipelineModel,
                                           scan_unroll=unroll)
             return local, aux
 
-        (_, (loss, new_stats)), grads = jax.value_and_grad(
+        (_, (loss, new_stats, _)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(p)
         # grads are purely LOCAL (this device's slice): no stage psum.
         # Seq-sharded pipelines still fold token-block partial sums.
@@ -972,7 +1038,7 @@ def make_lora_train_step(pipe: PipelineModel,
                                           scan_unroll=unroll)
             return local, aux
 
-        (_, (loss, new_stats)), grads = jax.value_and_grad(
+        (_, (loss, new_stats, _)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(t)
         with jax.named_scope("grad_sync"):
             grads = jax.tree_util.tree_map(

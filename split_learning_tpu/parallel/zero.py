@@ -235,7 +235,7 @@ def make_zero1_train_step(pipe: PipelineModel, mesh: Mesh,
                                           scan_unroll=unroll)
             return local, aux
 
-        (_, (loss, new_stats)), grads = jax.value_and_grad(
+        (_, (loss, new_stats, _)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
         with jax.named_scope("grad_sync"):
             grads = jax.tree_util.tree_map(

@@ -135,6 +135,11 @@ class MeshContext(TrainContext):
     #: the last ``train_cluster`` call's wall-clock attribution (the
     #: resident path returns its own with the outcome)
     last_timings: dict | None = None
+    #: what the model's layers counted in the last round (the counters a
+    #: step of ``pipeline.make_train_step`` hands back), each the mean
+    #: over the round's steps and columns; ``{}`` for a model that sows
+    #: none.  The round record's ``counters``
+    last_counters: dict = {}
 
     def __init__(self, cfg: Config, devices=None):
         self.cfg = cfg
@@ -392,7 +397,7 @@ class MeshContext(TrainContext):
                 self.cfg.model_key, cuts=cuts_phys,
                 example_input=example,
                 num_microbatches=lrn.control_count,
-                remat=lrn.remat,
+                remat=lrn.remat, moe_aux_weight=lrn.moe_aux_weight,
                 model_kwargs=self.model_kwargs, seq_axis=seq_axis)
 
         if seq_axis is not None:
@@ -553,6 +558,7 @@ class MeshContext(TrainContext):
         rngs = jax.vmap(jax.random.key)(jnp.arange(c_phys)
                                         + round_idx * 1000)
         loss = None
+        counts: list = []      # each step's sown counters, where any
         unfinished: collections.deque = collections.deque()
         consumed = np.zeros(c_phys, dtype=np.int64)
         for i, ld in enumerate(loaders):
@@ -587,12 +593,19 @@ class MeshContext(TrainContext):
                         frozen_c, params_c, opt_c, stats_c, x,
                         labels, rngs)
                 else:
-                    params_c, opt_c, stats_c, loss = step(
+                    # pipeline.make_train_step hands back what the
+                    # model's layers counted as a fifth member
+                    params_c, opt_c, stats_c, loss, *count = step(
                         params_c, opt_c, stats_c, x, labels, rngs)
+                    counts.extend(c for c in count if c)
                 unfinished.append(loss)
         laps.lap("sync")
         loss_h = (np.asarray(loss) if loss is not None
                   else np.zeros(c_phys))
+        self.last_counters = {
+            name: float(np.mean([np.asarray(c[col][name]) for c in counts]))
+            for col, names in (counts[0] if counts else {}).items()
+            for name in names}
         return params_c, opt_c, stats_c, loss_h, consumed
 
     @staticmethod
@@ -754,7 +767,8 @@ class MeshContext(TrainContext):
         return types.SimpleNamespace(params=ret_params, stats=ret_stats,
                                      num_samples=int(consumed.sum()),
                                      ok=True,
-                                     timings=self._timings(laps))
+                                     timings=self._timings(laps),
+                                     counters=self.last_counters)
 
     def train_cluster(self, plan: ClusterPlan, params, stats, *,
                       round_idx: int = 0, epochs: int = 1,

@@ -214,7 +214,9 @@ def run_training(cfg: Config, ctx: TrainContext,
                 logger.metric(kind="round", **dataclasses.asdict(rec),
                               phases=timer.summary(),
                               **({"train_detail": outcome.metrics}
-                                 if outcome.metrics else {}))
+                                 if outcome.metrics else {}),
+                              **({"counters": outcome.counters}
+                                 if outcome.counters else {}))
                 timer.reset()
             if capture is not None:
                 capture.stop()

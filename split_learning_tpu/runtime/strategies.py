@@ -49,6 +49,9 @@ class RoundOutcome:
     num_samples: int = 0
     validate: bool = True           # run full-model validation this round?
     metrics: dict = dataclasses.field(default_factory=dict)
+    # what the model's layers counted this round (the mesh context's
+    # ``last_counters``); the round record's ``counters``
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 # --------------------------------------------------------------------------
@@ -220,7 +223,8 @@ class FedAvgStrategy(RoundStrategy):
                     return RoundOutcome(
                         res.params, res.stats,
                         num_samples=res.num_samples,
-                        metrics=getattr(res, "timings", {}) or {})
+                        metrics=getattr(res, "timings", {}) or {},
+                        counters=getattr(res, "counters", {}) or {})
         cluster_params, cluster_stats = [], []
         total, ok = 0, True
         agg_s = 0.0
@@ -266,6 +270,8 @@ class FedAvgStrategy(RoundStrategy):
                                    merge_clusters(cluster_stats),
                                    num_samples=total)
         out.metrics = dict(detail)
+        # of the last cluster trained (a count is no sum over clusters)
+        out.counters = dict(getattr(ctx, "last_counters", None) or {})
         return out
 
 

@@ -77,7 +77,7 @@ def main() -> None:
         jax.random.wrap_key_data, rng)
 
     step = make_train_step(pipe, optimizer, mesh)
-    params_c, opt_c, stats_c, loss = step(params_c, opt_c, stats_c, x,
+    params_c, opt_c, stats_c, loss, _ = step(params_c, opt_c, stats_c, x,
                                           labels, rng)
     loss_h = np.asarray(jax.device_get(
         jax.jit(lambda l: l.mean(),

@@ -61,3 +61,15 @@ def qkv_batch(key, b=2, s=32, h=8, d=8):
     import jax
     ks = jax.random.split(key, 3)
     return tuple(jax.random.normal(k, (b, s, h, d)) for k in ks)
+
+
+def bench_reference(name: str):
+    """A configuration's plain reference (``benchmarks/configs/<name>.py``)
+    as a module: the oracle of the tests that tie the program to it."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "configs", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"ref_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -199,9 +199,9 @@ class TestMoEThroughPipeline:
 
     def test_aux_weight_changes_router_update(self, eight_devices):
         step0, args0 = self._setup(moe_aux_weight=0.0)
-        p0, _, _, loss0 = step0(*args0)
+        p0, _, _, loss0, _ = step0(*args0)
         step1, args1 = self._setup(moe_aux_weight=10.0)
-        p1, _, _, loss1 = step1(*args1)
+        p1, _, _, loss1, _ = step1(*args1)
         # reported loss is CE only: identical regardless of aux weight
         np.testing.assert_allclose(np.asarray(loss0), np.asarray(loss1),
                                    rtol=1e-5)
@@ -259,7 +259,7 @@ def test_pp_ep_pipeline_matches_pp_only(eight_devices):
         oc = shard_to_mesh(stack_for_clients(opt_state, 2), mesh)
         sc = shard_to_mesh(stack_for_clients(stats, 2), mesh)
         step = make_train_step(pipe, opt, mesh)
-        return step(pc, oc, sc, x, y, rngs)
+        return step(pc, oc, sc, x, y, rngs)[:4]
 
     mesh_pp = Mesh(np.array(eight_devices[:4]).reshape(2, 2),
                    ("client", "stage"))

@@ -58,7 +58,7 @@ def test_flash_lowers_for_tpu(name):
     from split_learning_tpu.analysis.pallas_check import (
         check_tpu_lowering,
     )
-    assert len(_FLASH_CASES) == 4
+    assert len(_FLASH_CASES) == 8   # two shapes, two grouped ones; fwd, bwd
     assert check_tpu_lowering(*_FLASH_CASES[name]) == []
 
 
@@ -129,3 +129,74 @@ def test_llama_use_flash_matches_einsum_path():
     out = m_flash.apply(variables, x, train=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+def _band_attention(q, k, v, window):
+    """Masked einsum with grouped key-value heads: query head ``h`` reads
+    key-value head ``h // rep``; a query at ``p`` sees keys
+    ``p - window + 1 .. p`` (``window=None``: every key up to ``p``)."""
+    import jax.numpy as jnp
+    s, rep = q.shape[1], q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
+    pos = jnp.arange(s)
+    seen = pos[None, :] <= pos[:, None]
+    if window is not None:
+        seen &= pos[None, :] > pos[:, None] - window
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+# window < block, = block, between blocks, > S, and none; 4 query heads
+# over 2 key-value heads
+@pytest.mark.parametrize("window", [5, 8, 20, 100, None])
+def test_window_and_grouped_heads_match_masked_einsum(window):
+    """Forward and all three gradients of the windowed kernel with
+    grouped key-value heads against the masked einsum."""
+    b, s, h, kv, d = 2, 32, 4, 2, 16
+    kq, kk, kv_, kw = jax.random.split(jax.random.key(7), 4)
+    q = jax.random.normal(kq, (b, s, h, d))
+    k = jax.random.normal(kk, (b, s, kv, d))
+    v = jax.random.normal(kv_, (b, s, kv, d))
+    w = jax.random.normal(kw, (b, s, h, d))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=8, block_k=8,
+                               window=window)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)),
+        np.asarray(_band_attention(q, k, v, window)), rtol=2e-5, atol=2e-5)
+    g1 = jax.grad(lambda *a: (flash(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: (_band_attention(*a, window) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for name, a, b_ in zip("qkv", g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-4, atol=5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", [(8, 16), (16, 8)])
+def test_window_with_unequal_blocks(blocks):
+    """The loop bounds hold when query and key blocks differ."""
+    bq, bk = blocks
+    q, k, v = qkv_batch(jax.random.key(3), b=1, s=64, h=2, d=16)
+    out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                          window=12)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_band_attention(q, k, v, 12)),
+        rtol=2e-5, atol=2e-5)
+    g1 = jax.grad(lambda *a: (flash_attention(
+        *a, causal=True, block_q=bq, block_k=bk, window=12) ** 2).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(lambda *a: (_band_attention(*a, 12) ** 2).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-4, atol=5e-4)
+
+
+def test_window_needs_causal():
+    q, k, v = _qkv(jax.random.key(0), s=16)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=4)

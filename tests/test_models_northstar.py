@@ -98,7 +98,7 @@ def test_tinyllama_4stage_pipeline_mesh(eight_devices):
     rngs = jax.vmap(jax.random.key)(jnp.arange(2))
     losses = []
     for _ in range(4):
-        params_c, opt_c, stats_c, loss = step(params_c, opt_c, stats_c,
+        params_c, opt_c, stats_c, loss, _ = step(params_c, opt_c, stats_c,
                                               x, labels, rngs)
         losses.append(float(np.asarray(loss).mean()))
     assert np.isfinite(losses).all()

@@ -66,7 +66,7 @@ def test_kwt_pipeline_matches_sequential(eight_devices, cuts, M):
     o_stack = shard_to_mesh(stack_for_clients(opt.init(params), C), mesh)
     s_stack = shard_to_mesh(stack_for_clients({}, C), mesh)
     rngs = jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(C))
-    new_p, _, _, loss = step(p_stack, o_stack, s_stack, x, labels, rngs)
+    new_p, _, _, loss, _ = step(p_stack, o_stack, s_stack, x, labels, rngs)
 
     # reference: per-client sequential full model + manual SGD
     model = build_model("KWT_SPEECHCOMMANDS")
@@ -118,7 +118,7 @@ def test_vgg_pipeline_train_mode_with_batchnorm(eight_devices, stage_devs):
     o_stack = shard_to_mesh(stack_for_clients(opt.init(params), C), mesh)
     s_stack = shard_to_mesh(stack_for_clients(stats, C), mesh)
     rngs = jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(C))
-    _, _, new_stats, loss = step(p_stack, o_stack, s_stack, x, labels, rngs)
+    _, _, new_stats, loss, _ = step(p_stack, o_stack, s_stack, x, labels, rngs)
 
     model = build_model("VGG16_CIFAR10")
     ref_loss, ref_stats = _ref_loss(model, params, stats, x[0], labels[0],
@@ -215,7 +215,7 @@ def test_virtual_stages_match_full_mesh(eight_devices, n_stage_devs):
         x = jax.random.randint(jax.random.key(1), (C, M, mb, 16), 0, 64)
         labels = jax.random.randint(jax.random.key(2), (C, M, mb), 0, 4)
         step = make_train_step(pipe, opt, mesh, train=False, donate=False)
-        new_p, _, _, loss = step(
+        new_p, _, _, loss, _ = step(
             shard_to_mesh(stack_for_clients(params, C), mesh),
             shard_to_mesh(stack_for_clients(opt.init(params), C), mesh),
             shard_to_mesh(stack_for_clients({}, C), mesh),

@@ -62,7 +62,7 @@ def _run_step(cuts, M, C, A, devices, *, stream_loss, remat,
     step = make_train_step(pipe, opt, mesh, train=train, donate=False)
     p_c = shard_to_mesh(stack_for_clients(params, C), mesh)
     o_c = shard_to_mesh(stack_for_clients(opt.init(params), C), mesh)
-    new_p, _, _, loss = step(p_c, o_c, stats_c, x, labels, rngs)
+    new_p, _, _, loss, _ = step(p_c, o_c, stats_c, x, labels, rngs)
     tree = jax.tree_util.tree_map(lambda a: np.asarray(a)[0], new_p)
     return np.asarray(loss), tree
 

@@ -119,7 +119,7 @@ def test_pp_sp_pipeline_matches_pp_only(eight_devices):
         oc = shard_to_mesh(stack_for_clients(opt_state, 2), mesh)
         sc = shard_to_mesh(stack_for_clients(stats, 2), mesh)
         step = make_train_step(pipe, opt, mesh)
-        return step(pc, oc, sc, x, y, rngs)
+        return step(pc, oc, sc, x, y, rngs)[:4]
 
     mesh_pp = Mesh(np.array(eight_devices[:4]).reshape(2, 2),
                    ("client", "stage"))

@@ -79,7 +79,7 @@ def test_zero1_step_matches_dense_adamw(eight_devices):
     opt = optax.adamw(lr, weight_decay=wd)
     dense = make_train_step(pipe, opt, mesh, train=False, donate=False)
     p0 = shard_to_mesh(stack_for_clients(params, C), mesh)
-    dp, _, _, dense_loss = dense(
+    dp, _, _, dense_loss, _ = dense(
         p0, shard_to_mesh(stack_for_clients(opt.init(params), C), mesh),
         shard_to_mesh(stack_for_clients({}, C), mesh), x, labels, rngs)
 
