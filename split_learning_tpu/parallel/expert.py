@@ -169,29 +169,23 @@ class MoEMLP(nn.Module):
 # drop-free held-share layer: sorted dispatch, grouped products
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def take_rows(src, idx, back_idx, back_ok, fan: int):
-    """``src[idx]`` whose backward pass is a gather too.  ``idx`` picks
-    every row of ``src`` at most ``fan`` times, and ``back_idx[r * fan +
-    j]`` (valid where ``back_ok``) is where row ``r``'s ``j``-th copy
-    went: so ``d_src[r] = sum_j d_out[back_idx[r, j]]``, with no
-    scatter-add."""
-    return src[idx]
+#: How many times an even router's share of the pairs the common pass has
+#: rows for.  The held pairs of the token cell are 1.000-1.004 of that
+#: share, a router drawn whole gives 0.92-1.07, a router drifting towards
+#: the held experts at 3e-4 reached 1.44 (PERF.md section 6): twice covers
+#: all of them with room, and costs a quarter of the worst case where a
+#: token picks 8 of 64.  What lies beyond it is computed all the same, by
+#: the overflow pass.
+COMMON_SHARE = 2
 
 
-def _take_rows_fwd(src, idx, back_idx, back_ok, fan):
-    return src[idx], (back_idx, back_ok)
-
-
-def _take_rows_bwd(fan, res, g):
-    back_idx, back_ok = res
-    back = jnp.where(back_ok[:, None], g[back_idx], 0)
-    d_src = back.reshape(-1, fan, g.shape[-1]).sum(axis=1) if fan > 1 \
-        else back
-    return d_src.astype(g.dtype), None, None, None
-
-
-take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def common_rows(t: int, k: int, n_held: int, e: int) -> int:
+    """Rows ``C`` of the common pass for ``t`` tokens picking ``k`` of ``e``
+    experts, ``n_held`` of them here: ``COMMON_SHARE`` times what an even
+    router sends, at most the worst case ``min(k, n_held) * t`` (every
+    token picking every held expert: a token picks distinct experts, so
+    no more of its pairs can be held).  From shapes alone."""
+    return min(min(k, n_held) * t, -(-COMMON_SHARE * t * k * n_held // e))
 
 
 def route_held(probs: jnp.ndarray, k: int, first: int, n_held: int):
@@ -203,13 +197,14 @@ def route_held(probs: jnp.ndarray, k: int, first: int, n_held: int):
     load-balancing term ``E * sum_e f_e P_e`` over all experts (``f_e``
     the pairs routed to ``e`` over T, ``P_e`` the mean probability);
     ``plan`` holds the (token, choice) pairs sorted by held expert, the
-    pairs of absent experts last:
+    pairs of absent experts last.  Sorted row ``r`` is where a pair's
+    token is multiplied; no buffer has all the rows: a pass
+    (:func:`pass_plan`) takes the range of them it was given.
 
-    * ``row_pair`` (R,): the pair ``t * k + j`` in buffer row ``r``, for
-      ``R = min(k, n_held) * T`` rows — a token picks distinct experts,
-      so no more of its pairs can be held: nothing is ever dropped;
-    * ``pair_row`` (T * k,), ``pair_ok``: the row a pair sits in, and
-      whether it is a held pair (absent experts' pairs are not);
+    * ``order`` (T * k,): the pair ``t * k + j`` of sorted row ``r``;
+    * ``pair_row`` (T * k,), ``held``: the sorted row of a pair, and
+      whether its expert is held (then its row is under the sum of
+      ``group_sizes``, itself at most ``min(k, n_held) * T``);
     * ``group_sizes`` (n_held,): pairs of each held expert, in order.
     """
     t, e = probs.shape
@@ -222,14 +217,192 @@ def route_held(probs: jnp.ndarray, k: int, first: int, n_held: int):
     held = (local >= 0) & (local < n_held)
     key = jnp.where(held, local, n_held).astype(jnp.int32)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    rows = min(k, n_held) * t
     pair_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
         jnp.arange(t * k, dtype=jnp.int32))
-    plan = {"row_pair": order[:rows],
-            "pair_row": jnp.minimum(pair_row, rows - 1),
-            "pair_ok": held,
+    plan = {"order": order, "pair_row": pair_row, "held": held,
             "group_sizes": counts[first:first + n_held].astype(jnp.int32)}
     return weights, plan, aux
+
+
+def pass_plan(plan: dict, lo: int, hi: int, t: int, k: int) -> dict:
+    """What one pass over the sorted rows ``[lo, hi)`` needs, all of it
+    integers of ``hi - lo`` rows or of ``t * k`` pairs:
+
+    * ``sizes``: the experts' group sizes clipped to the range (a group
+      that straddles ``lo`` or ``hi`` gives each side its part);
+    * ``token``, ``pair``, ``live``: of each row, the token and the pair
+      it holds, and whether it lies under the groups' sum;
+    * ``by_token``: the rows in (token, choice) order, the live ones
+      first (so ``live`` says which are live in that order too);
+      ``joins[i]``: whether the row ``2 ** i`` places earlier in that
+      order belongs to the same token;
+    * ``last``, ``has`` (t,): where in that order a token's run of rows
+      ends, and whether it has one;
+    * ``pair_at``, ``inside`` (t * k,): the row of a pair, counted from
+      ``lo``, and whether the pair is one of this pass's.
+    """
+    n = hi - lo
+    sizes, pair_row = plan["group_sizes"], plan["pair_row"]
+    ends = jnp.cumsum(sizes)
+    pair = plan["order"][lo:hi]
+    live = jnp.arange(lo, hi) < ends[-1]
+    # a token's held pairs are neighbours once the live rows are sorted by
+    # pair: at most min(k, n_held) of them, so that many rows back at most
+    key, by_token = jax.lax.sort_key_val(
+        jnp.where(live, pair, t * k), jnp.arange(n, dtype=jnp.int32))
+    token_sorted = key // k
+    joins, step = [], 1
+    while step < min(k, sizes.shape[0], n):
+        joins.append(jnp.concatenate([
+            jnp.zeros((step,), bool),
+            token_sorted[step:] == token_sorted[:-step]]))
+        step *= 2
+    inside = plan["held"] & (pair_row >= lo) & (pair_row < hi)
+    count = jnp.sum(inside.reshape(t, k), axis=1, dtype=jnp.int32)
+    return {"sizes": jnp.clip(ends, lo, hi) - jnp.clip(ends - sizes, lo, hi),
+            "token": pair // k, "pair": pair, "live": live,
+            "by_token": by_token, "joins": tuple(joins),
+            "last": jnp.maximum(jnp.cumsum(count) - 1, 0), "has": count > 0,
+            "pair_at": jnp.clip(pair_row - lo, 0, n - 1), "inside": inside}
+
+
+def _fold(vals, scale, p):
+    """Rows to tokens: ``out[t] = sum of scale[r] * vals[r]`` over the live
+    rows of token ``t``, at the cost of the rows there are and of ``T``:
+    the rows are gathered into token order, each run of a token's rows is
+    summed by ``len(joins)`` masked shifted adds, and one gather of ``T``
+    rows reads the runs' ends.  The adds are made in the rows' type: in
+    bfloat16 a token of ``m`` rows takes up to ``ceil(log2 m)`` roundings
+    more than one sum would (``tests/test_moe_held.py`` holds the fold and
+    the layer to that; the sums in float32 cost more than they bought,
+    PERF.md section 6).  Rows that are not live may hold anything (NaN on
+    the chip): they are masked, value and scale alike."""
+    z = vals[p["by_token"]]
+    if scale is not None:
+        z = z * scale[p["by_token"]][:, None].astype(z.dtype)
+    z = jnp.where(p["live"][:, None], z, 0)
+    for i, same in enumerate(p["joins"]):
+        earlier = jnp.concatenate(
+            [jnp.zeros((2 ** i, z.shape[1]), z.dtype), z[:-2 ** i]])
+        z = z + jnp.where(same[:, None], earlier, 0)
+    return jnp.where(p["has"][:, None], z[p["last"]], 0)
+
+
+@jax.custom_vjp
+def spread_rows(src, p):
+    """Tokens to rows, ``src[p["token"]]``; its backward pass is the fold
+    of the rows' cotangents into their tokens (:func:`_fold`): a gather of
+    as many rows again, no scatter-add."""
+    return src[p["token"]]
+
+
+def _spread_rows_fwd(src, p):
+    return src[p["token"]], p
+
+
+def _spread_rows_bwd(p, g):
+    return _fold(g, None, p).astype(g.dtype), None
+
+
+spread_rows.defvjp(_spread_rows_fwd, _spread_rows_bwd)
+
+
+@jax.custom_vjp
+def fold_rows(vals, weights, p):
+    """Rows to tokens: every live row scaled by its pair's weight
+    (``weights`` (T * k,)) and summed into its token (:func:`_fold`).  The
+    backward pass hands a row ``weight * d_out[token]`` and a pair the
+    product of its row with ``d_out[token]``: gathers of rows and of
+    scalars."""
+    return _fold_rows_fwd(vals, weights, p)[0]
+
+
+def _fold_rows_fwd(vals, weights, p):
+    scale = jnp.where(p["live"], weights[p["pair"]], 0.0)
+    return _fold(vals, scale, p), (vals, scale, p)
+
+
+def _fold_rows_bwd(res, g):
+    vals, scale, p = res
+    live = p["live"]
+    back = g[p["token"]]
+    # BOTH masks are needed: a row past the groups holds what the grouped
+    # product left there (NaN on the chip), and ``0 * NaN`` would reach
+    # the router's gradient through the weight
+    d_vals = jnp.where(live[:, None],
+                       back * scale[:, None].astype(back.dtype), 0)
+    d_scale = jnp.where(live, jnp.sum(
+        vals.astype(jnp.float32) * back.astype(jnp.float32), axis=1), 0.0)
+    d_weights = jnp.where(p["inside"], d_scale[p["pair_at"]], 0.0)
+    return d_vals.astype(vals.dtype), d_weights.astype(scale.dtype), None
+
+
+fold_rows.defvjp(_fold_rows_fwd, _fold_rows_bwd)
+
+
+def _pass(x, weights, kernels, plan, lo: int, hi: int, precision):
+    """The held experts' part of the result from the sorted rows
+    ``[lo, hi)``: gather the rows' tokens, the SwiGLU as three grouped
+    products (``jax.lax.ragged_dot``) over the group sizes clipped to the
+    range, fold the weighted results into their tokens."""
+    t = x.shape[0]
+    with jax.named_scope("moe_route"):
+        p = pass_plan(plan, lo, hi, t, weights.shape[0] // t)
+        rows = spread_rows(x, p)
+    with jax.named_scope("moe_experts"):
+        gate, up, down = kernels
+        dot = functools.partial(jax.lax.ragged_dot, group_sizes=p["sizes"],
+                                precision=precision)
+        y = dot(nn.silu(dot(rows, gate)) * dot(rows, up), down)
+    with jax.named_scope("moe_route"):
+        return fold_rows(y, weights, p)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def both_passes(x, weights, kernels, plan, c: int, worst: int, precision):
+    """The common pass over the sorted rows ``[0, c)`` and, where the held
+    pairs number more, the overflow pass over ``[c, worst)`` added to it,
+    under one ``cond``: every pair is computed whatever the load.
+
+    A pass that is skipped costs nothing of its rows' size, forward or
+    backward: what the backward pass keeps of the overflow pass are its
+    INPUTS (it is rare, so it is recomputed under the backward pass's own
+    ``cond``), and the skipped branch hands the common pass's cotangents
+    through as they are, where a differentiated ``cond`` would zero-fill
+    every residual of the branch it did not take."""
+    out = _pass(x, weights, kernels, plan, 0, c, precision)
+    return _add_overflow(out, x, weights, kernels, plan, c, worst, precision)
+
+
+def _add_overflow(out, x, weights, kernels, plan, c, worst, precision):
+    return jax.lax.cond(
+        jnp.sum(plan["group_sizes"]) > c,
+        lambda o: o + _pass(x, weights, kernels, plan, c, worst, precision),
+        lambda o: o, out)
+
+
+def _both_passes_fwd(x, weights, kernels, plan, c, worst, precision):
+    out, pull = jax.vjp(
+        lambda *a: _pass(*a, plan, 0, c, precision), x, weights, kernels)
+    out = _add_overflow(out, x, weights, kernels, plan, c, worst, precision)
+    return out, (pull, x, weights, kernels, plan)
+
+
+def _both_passes_bwd(c, worst, precision, res, g):
+    pull, x, weights, kernels, plan = res
+
+    def and_overflow(grads):
+        _, pull_over = jax.vjp(
+            lambda *a: _pass(*a, plan, c, worst, precision),
+            x, weights, kernels)
+        return jax.tree_util.tree_map(jnp.add, grads, pull_over(g))
+
+    grads = jax.lax.cond(jnp.sum(plan["group_sizes"]) > c, and_overflow,
+                         lambda grads: grads, pull(g))
+    return (*grads, None)
+
+
+both_passes.defvjp(_both_passes_fwd, _both_passes_bwd)
 
 
 class _ExpertKernel(nn.Module):
@@ -244,29 +417,22 @@ class _ExpertKernel(nn.Module):
 
 
 class _ExpertBank(nn.Module):
-    """The held experts' SwiGLU over rows sorted by expert: three grouped
-    matrix products (``jax.lax.ragged_dot``) over ``group_sizes``; rows
-    past their sum belong to no expert and come out as they may (the
-    caller masks them)."""
+    """The held experts' SwiGLU kernels ``(gate, up, down)`` in the compute
+    type, the experts leading: what the grouped products of a pass
+    (:func:`_pass`) multiply the rows by."""
     n: int
     hidden_size: int
     intermediate_size: int
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, rows, group_sizes):
+    def __call__(self):
         n, d, f = self.n, self.hidden_size, self.intermediate_size
-        gate, up, down = (
+        return tuple(
             _ExpertKernel(shape, name=name)().astype(self.dtype)
             for name, shape in (("gate_proj", (n, d, f)),
                                 ("up_proj", (n, d, f)),
                                 ("down_proj", (n, f, d))))
-        precision = (jax.lax.Precision.HIGHEST
-                     if self.dtype == jnp.float32 else None)
-        dot = functools.partial(jax.lax.ragged_dot,
-                                group_sizes=group_sizes,
-                                precision=precision)
-        return dot(nn.silu(dot(rows, gate)) * dot(rows, up), down)
 
 
 class HeldMoEMLP(nn.Module):
@@ -283,23 +449,31 @@ class HeldMoEMLP(nn.Module):
     what the exchange would complete; the shares of all chips add up to
     the whole layer (``tests/test_moe_held.py``).
 
-    There is no capacity.  The row buffer has the proven worst case,
-    ``min(k, len(held)) * T`` rows (every token picking every held
-    expert), and the grouped products do the work of the pairs there are;
-    the gathers into and out of the buffer move all of it.  Backward
-    passes are gathers as well (:func:`take_rows`).
+    There is no capacity.  The row buffer, and every pass over rows, has
+    ``C`` rows (:func:`common_rows`: twice what an even router sends here,
+    from shapes alone), not the worst case ``min(k, len(held)) * T``: the
+    common pass moves and multiplies the sorted rows ``[0, C)``.  Where a
+    call's held pairs number more than ``C``, an overflow pass does the
+    same for the rows ``[C, worst)`` and adds its part
+    (:func:`both_passes`: one ``cond``, nothing of the rows' size where it
+    is skipped); where ``C`` is the worst case (a share of half the
+    experts or more) none is built.  The ways in and out are gathers in
+    both directions (:func:`spread_rows`, :func:`fold_rows`).
 
     Sown: ``aux_loss`` under ``intermediates`` (load balance over all
-    experts, from the router alone), and two counters that a step of
+    experts, from the router alone), and three counters that a step of
     ``parallel/pipeline.py`` folds and hands back (its ``COUNTER_FOLDS``):
     ``moe_pairs_held`` under ``counters_sum`` (the pairs computed here: an
     operator reads from it what share of the routed work this chip holds,
-    and whether a live router drifts towards the held experts) and
-    ``moe_load_max_over_mean`` under ``counters_max`` (the fullest held
-    expert's pairs over the mean).  Nothing can be dropped, so there is no
-    counter of dropped pairs.
-    Scopes: ``moe_route`` (router, top-k, sort, gathers, weighted sum) and
-    ``moe_experts`` (the grouped products).
+    and whether a live router drifts towards the held experts),
+    ``moe_overflow_passes`` under ``counters_sum`` (1 where this call's
+    pairs exceeded ``C`` and the overflow pass ran: a share whose calls
+    mostly overflow pays for two passes) and ``moe_load_max_over_mean``
+    under ``counters_max`` (the fullest held expert's pairs over the
+    mean).  Nothing can be dropped, so there is no counter of dropped
+    pairs.
+    Scopes: ``moe_route`` (router, top-k, sorts, gathers, folds) and
+    ``moe_experts`` (the grouped products), in both passes.
     """
     hidden_size: int
     intermediate_size: int
@@ -330,29 +504,29 @@ class HeldMoEMLP(nn.Module):
                               name="router")(xt.astype(jnp.float32))
             weights, plan, aux = route_held(
                 jax.nn.softmax(logits, axis=-1), k, first, n_held)
-            row_pair, pair_row = plan["row_pair"], plan["pair_row"]
-            pair_ok, sizes = plan["pair_ok"], plan["group_sizes"]
-            rows = take_rows(xt, row_pair // k, pair_row, pair_ok, k)
+        sizes = plan["group_sizes"]
+        worst = min(k, n_held) * t
+        c = common_rows(t, k, n_held, self.num_experts)
         self.sow("intermediates", "aux_loss", aux)
         pairs = jnp.sum(sizes).astype(jnp.float32)
         self.sow("counters_sum", "moe_pairs_held", pairs)
+        self.sow("counters_sum", "moe_overflow_passes",
+                 (pairs > c).astype(jnp.float32))
         self.sow("counters_max", "moe_load_max_over_mean",
                  jnp.max(sizes) * n_held / jnp.maximum(pairs, 1.0))
+        # the kernels' casts to the compute type, and their transposes (the
+        # kernels' gradients back to float32), are the experts' work
         with jax.named_scope("moe_experts"):
-            y = _ExpertBank(n_held, h, self.intermediate_size, self.dtype,
-                            name="experts")(rows, sizes)
-        with jax.named_scope("moe_route"):
-            back = take_rows(y, pair_row, row_pair,
-                             jnp.ones(row_pair.shape, bool), 1)
-            # BOTH masks are needed: an absent expert's pair reads a
-            # buffer row past the groups, which the grouped product
-            # leaves as it may (NaN on the chip).  The outer mask keeps
-            # that out of the result; the mask on the weight keeps
-            # ``0 * NaN`` out of the router's gradient.
-            scale = jnp.where(pair_ok, weights.reshape(-1), 0.0)
-            out = jnp.where(pair_ok[:, None],
-                            back * scale[:, None].astype(back.dtype), 0)
-            out = out.reshape(t, k, h).sum(axis=1)
+            kernels = _ExpertBank(n_held, h, self.intermediate_size,
+                                  self.dtype, name="experts")()
+        precision = (jax.lax.Precision.HIGHEST
+                     if self.dtype == jnp.float32 else None)
+        if c < worst:
+            out = both_passes(xt, weights.reshape(-1), kernels, plan,
+                              c, worst, precision)
+        else:
+            out = _pass(xt, weights.reshape(-1), kernels, plan, 0, worst,
+                        precision)
         return out.reshape(b, s, h).astype(x.dtype)
 
 

@@ -238,7 +238,7 @@ def test_the_step_hands_back_what_the_expert_layers_counted():
     pipe, params, ids, out = _step_outputs("Mellum2_TINYSTORIES", TINY)
     got = out[4]
     assert {col: sorted(names) for col, names in got.items()} == {
-        "counters_sum": ["moe_pairs_held"],
+        "counters_sum": ["moe_overflow_passes", "moe_pairs_held"],
         "counters_max": ["moe_load_max_over_mean"]}
     pairs, load = 0.0, 0.0
     for mb in range(ids.shape[1]):
@@ -250,6 +250,9 @@ def test_the_step_hands_back_what_the_expert_layers_counted():
         load = max(load, float(
             count["counters_max"]["moe_load_max_over_mean"]))
     assert float(got["counters_sum"]["moe_pairs_held"][0]) == pairs > 0
+    # 4 of 8 experts held: the common pass has the worst case's rows, and
+    # no call can overflow it
+    assert float(got["counters_sum"]["moe_overflow_passes"][0]) == 0.0
     np.testing.assert_allclose(
         np.asarray(got["counters_max"]["moe_load_max_over_mean"])[0], load,
         rtol=1e-6)

@@ -1,7 +1,9 @@
 """The drop-free held-share expert layer (``parallel/expert.py
 HeldMoEMLP``): the shares of an expert-parallel job add up to the uncut
-reference's layer, nothing is dropped however uneven the load, and the
-backward pass (gathers, no scatter-add) gives the dense oracle's gradients.
+reference's layer, nothing is dropped however uneven the load (the rows
+past the common pass's go through the overflow pass), no pass over rows
+has the worst case's size, and the backward pass (gathers, no scatter-add)
+gives the dense oracle's gradients.
 The oracle is the benchmark's plain reference (``benchmarks/configs/
 mellum2_12b_c3.py moe_layer``): every held expert on every token, weighted
 by what the router gave it."""
@@ -15,7 +17,8 @@ import pytest
 from tests.conftest import bench_reference
 
 from split_learning_tpu.parallel.expert import (
-    HeldMoEMLP, MoEMLP, ep_spec, moe_aux_loss, route_held,
+    HeldMoEMLP, MoEMLP, _fold, common_rows, ep_spec, moe_aux_loss,
+    pass_plan, route_held,
 )
 from split_learning_tpu.parallel.pipeline import COUNTER_FOLDS, sown_counters
 
@@ -30,12 +33,12 @@ def _mm(eq, a, b):
     return jnp.einsum(eq, a, b, precision=HI)
 
 
-def _ref_layer(params, m, held):
-    """The reference's expert layer holding ``held`` of ``params``' E."""
+def _ref_layer(params, m, held, e=E, k=K):
+    """The reference's expert layer holding ``held`` of ``params``' e."""
     share = {"router": params["router"],
              "experts": jax.tree_util.tree_map(
                  lambda a: a[np.asarray(held)], params["experts"])}
-    s = {"num_experts": E, "num_experts_per_tok": K, "experts_held": held}
+    s = {"num_experts": e, "num_experts_per_tok": k, "experts_held": held}
     return REF.moe_layer(share, m, s, _mm)
 
 
@@ -93,17 +96,28 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(layer):
     assert pairs == T * K
 
 
-def _one_expert_for_all(params):
-    """A router that sends every token to expert 3 first."""
+def _one_expert_for_all(params, then=None):
+    """A router that sends every token to expert 3 first (and to expert
+    ``then`` second; left to itself the second choice is expert 0)."""
     router = np.zeros((H, E), np.float32)
     router[:, 3] = 1.0
+    if then is not None:
+        router[:, then] = 0.5
     return {**params, "router": {"kernel": jnp.asarray(router)}}
 
 
-def test_no_pair_is_dropped_when_every_token_picks_the_same_expert(layer):
+@pytest.mark.parametrize("then, pairs, overflow", [(None, T, 0.0),
+                                                   (2, 2 * T, 1.0)])
+def test_no_pair_is_dropped_when_every_token_picks_the_same_expert(
+        layer, then, pairs, overflow):
+    """Held (2, 3) of 8, top-2: the common pass has ``C = T`` rows.  Every
+    token on expert 3 fills it to the last row; every token on 3 AND 2 is
+    the worst case, ``2 T`` rows, and its second half goes through the
+    overflow pass."""
     x, params = layer
     x = jnp.abs(x) + 0.1                 # so that expert 3's logit wins
-    params = _one_expert_for_all(params)
+    params = _one_expert_for_all(params, then)
+    assert common_rows(T, K, 2, E) == T
     y, mut = HeldMoEMLP(H, F, num_experts=E, k=K, held=(2, 3)).apply(
         {"params": _share(params, (2, 3))}, x,
         mutable=list(COUNTER_FOLDS))
@@ -114,10 +128,15 @@ def test_no_pair_is_dropped_when_every_token_picks_the_same_expert(layer):
                                params["router"]["kernel"]))
     _, plan, _ = route_held(probs, K, 2, 2)
     assert int(plan["group_sizes"][1]) == T      # every token, none lost
+    assert int(plan["held"].sum()) == pairs
+    # the held pairs' sorted rows are the first ``pairs``, each once
+    rows = np.asarray(plan["pair_row"])[np.asarray(plan["held"])]
+    assert sorted(rows) == list(range(pairs))
     counters = {name: float(v) for names in sown_counters(mut).values()
                 for name, v in names.items()}
-    assert counters["moe_pairs_held"] >= T
-    assert counters["moe_load_max_over_mean"] > 1.0
+    assert counters["moe_pairs_held"] == pairs
+    assert counters["moe_overflow_passes"] == overflow
+    assert counters["moe_load_max_over_mean"] > 1.0 or then is not None
     assert not any("dropped" in name for name in counters)
 
 
@@ -136,7 +155,8 @@ def test_the_capacity_layer_drops_there(layer):
 @pytest.mark.parametrize("held", [None, (4, 5, 6, 7)])
 def test_gradients_match_the_reference(layer, held):
     """Router, experts and input: the layer's backward pass is gathers
-    (``take_rows``), the reference's plain autodiff of dense products."""
+    (``spread_rows``, ``fold_rows``), the reference's plain autodiff of
+    dense products."""
     x, params = layer
     ids = tuple(range(E)) if held is None else held
     w = jax.random.normal(jax.random.key(5), (T, H))
@@ -157,34 +177,261 @@ def test_gradients_match_the_reference(layer, held):
                                    rtol=1e-4, atol=1e-5)
 
 
-def test_rows_past_the_groups_may_hold_anything(layer, monkeypatch):
+# -- the two passes under load -------------------------------------------------
+
+# 256 tokens picking 8 of 64 experts, experts 8-15 held: an even router
+# sends 256 pairs here, the common pass has C = 512 rows, the worst case
+# (every token picking all eight) 2,048
+BIG = dict(h=32, f=16, e=64, k=8, t=256, held=tuple(range(8, 16)))
+LOADS = {"even": 0.0, "exactly_c": 0.0, "c_plus_1": 1.0, "worst": 1.0}
+
+
+@pytest.fixture(scope="module")
+def big():
+    """For each load ``(tokens, parameters, held pairs)``: the router drawn
+    WHOLE, the tokens picked from a pool by how many of its eight choices
+    a token makes among the held experts."""
+    h, e, k, t, held = (BIG[n] for n in ("h", "e", "k", "t", "held"))
+    c = common_rows(t, k, len(held), e)
+    assert (c, min(k, len(held)) * t) == (512, 2048)
+    layer = HeldMoEMLP(h, BIG["f"], num_experts=e, k=k)
+    pool = jax.random.normal(jax.random.key(10), (16 * t, h))
+    params = layer.init(jax.random.key(11), pool[None, :t])["params"]
+    params = {**params, "router": {"kernel": 2.0 * jax.random.normal(
+        jax.random.key(12), (h, e))}}
+    top = np.asarray(jax.lax.top_k(
+        _mm("td,de->te", pool, params["router"]["kernel"]), k)[1])
+    count = ((top >= held[0]) & (top <= held[-1])).sum(axis=1)
+    twos, threes = np.flatnonzero(count == 2), np.flatnonzero(count == 3)
+    assert len(twos) >= t and len(threes) >= 1
+    # every token picks every held expert: their columns lifted clear
+    lifted = np.asarray(params["router"]["kernel"]).copy()
+    lifted[:, held[0]:held[-1] + 1] = 0.0
+    even = int(count[:t].sum())
+    assert 0 < even < c // 2 + c // 4
+    return {"even": (pool[:t], params, even),
+            "exactly_c": (pool[twos[:t]], params, c),
+            "c_plus_1": (pool[np.append(twos[:t - 1], threes[0])], params,
+                         c + 1),
+            "worst": (jnp.abs(pool[:t]) + 0.1, {**params, "router": {
+                "kernel": jnp.asarray(lifted - 3.0 * (lifted != 0))}}, 2048)}
+
+
+def _big_share(params):
+    return _share(params, BIG["held"])
+
+
+def _big_layer():
+    return HeldMoEMLP(BIG["h"], BIG["f"], num_experts=BIG["e"], k=BIG["k"],
+                      held=BIG["held"])
+
+
+@pytest.mark.parametrize("load", list(LOADS))
+def test_both_passes_match_the_reference_whatever_the_load(big, load):
+    """Result and gradients (input, router, expert kernels) against the
+    benchmark reference's ``moe_layer``, with the router drawn whole: the
+    common pass alone (well under ``C``, and full to its last row), and
+    with the overflow pass (one row in it, and all of them)."""
+    x, params, pairs = big[load]
+    t, h = BIG["t"], BIG["h"]
+    w = jax.random.normal(jax.random.key(13), (t, h))
+
+    def prog(p, x):
+        y, mut = _big_layer().apply({"params": p}, x[None],
+                                    mutable=list(COUNTER_FOLDS))
+        return (y.reshape(t, h) * w).sum(), (y, sown_counters(mut))
+
+    def ref(p, x):
+        y, _ = _ref_layer(p, x, BIG["held"], BIG["e"], BIG["k"])
+        return (y * w).sum(), y
+
+    (_, (y, count)), got = jax.value_and_grad(
+        prog, argnums=(0, 1), has_aux=True)(_big_share(params), x)
+    (_, want_y), (want_p, want_x) = jax.value_and_grad(
+        ref, argnums=(0, 1), has_aux=True)(params, x)
+    assert float(count["counters_sum"]["moe_pairs_held"]) == pairs
+    assert float(count["counters_sum"]["moe_overflow_passes"]) == LOADS[load]
+    np.testing.assert_allclose(np.asarray(y).reshape(t, h),
+                               np.asarray(want_y), rtol=1e-4, atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(
+                        (_big_share(want_p), want_x))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=2e-5)
+
+
+def _gap(a, b):
+    """Norm of the difference over the norm of ``b``, in float64."""
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("load", list(LOADS))
+def test_in_bfloat16_both_passes_stay_near_the_float32_reference(big, load):
+    """The chip's cell has tiled routers (one held pair a token) and the
+    tests above run in float32, so this is where the fold's bfloat16 sums
+    of SEVERAL pairs a token are held to something: result and gradients
+    of the bfloat16 layer, routers drawn whole, within 0.008 of the
+    float32 reference's norm (read: 0.0053-0.0063 for the result, 0.0040-
+    0.0068 for the gradients; the worst-case buffer's single sum over
+    ``k`` read 0.0053-0.0063 and 0.0046-0.0104, and a fold that sums in
+    float32 0.0050-0.0062: the grouped products' rounded results carry
+    the gap, not the order of a token's sums)."""
+    x, params, _ = big[load]
+    t, h = BIG["t"], BIG["h"]
+    x = x.astype(jnp.bfloat16)
+    w = jax.random.normal(jax.random.key(13), (t, h))
+    model = HeldMoEMLP(h, BIG["f"], num_experts=BIG["e"], k=BIG["k"],
+                       held=BIG["held"], dtype=jnp.bfloat16)
+
+    def prog(p, x):
+        y = model.apply({"params": p}, x[None]).reshape(t, h)
+        return (y.astype(jnp.float32) * w).sum(), y
+
+    def ref(p, x):
+        y, _ = _ref_layer(p, x, BIG["held"], BIG["e"], BIG["k"])
+        return (y * w).sum(), y
+
+    (_, y), got = jax.value_and_grad(prog, argnums=(0, 1), has_aux=True)(
+        _big_share(params), x)
+    (_, want_y), (want_p, want_x) = jax.value_and_grad(
+        ref, argnums=(0, 1), has_aux=True)(params, x.astype(jnp.float32))
+    assert y.dtype == jnp.bfloat16
+    assert _gap(y, want_y) < 0.008
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(
+                        (_big_share(want_p), want_x))):
+        assert _gap(a, b) < 0.008
+
+
+@pytest.mark.parametrize("load", ["even", "worst"])
+def test_the_fold_in_bfloat16_is_within_one_more_rounding_of_the_sum(
+        big, load):
+    """The fold alone, rows and result in bfloat16, against the float64
+    sum of each token's weighted rows: a router drawn whole gives a token
+    up to three held pairs here, the worst case all eight (three shifted
+    adds, each rounded).  The exact sum rounded ONCE to bfloat16 stands
+    0.0016 of its norm off; the fold has to stay under 0.004 (2 ** -8;
+    read: 0.0021 and 0.0031)."""
+    x, params, _ = big[load]
+    t, h, k, held = (BIG[n] for n in ("t", "h", "k", "held"))
+    probs = jax.nn.softmax(_mm("td,de->te", x, params["router"]["kernel"]))
+    weights, plan, _ = route_held(probs, k, held[0], len(held))
+    rows = min(k, len(held)) * t
+    p = pass_plan(plan, 0, rows, t, k)
+    live = np.asarray(p["live"])
+    a_token = np.bincount(np.asarray(p["token"])[live], minlength=t)
+    assert a_token.max() == (8 if load == "worst" else 3)
+    vals = jax.random.normal(jax.random.key(3), (rows, h)).astype(
+        jnp.bfloat16)
+    scale = jnp.where(p["live"], weights.reshape(-1)[p["pair"]], 0.0)
+    want = np.zeros((t, h))
+    np.add.at(want, np.asarray(p["token"])[live],
+              np.asarray(vals, np.float64)[live]
+              * np.asarray(scale, np.float64)[live, None])
+    got = _fold(vals, scale, p)
+    assert got.dtype == jnp.bfloat16
+    once = jnp.asarray(want, jnp.float32).astype(jnp.bfloat16)
+    assert _gap(once, want) < _gap(got, want) < 0.004
+
+
+@pytest.mark.parametrize("where", ["common", "overflow"])
+def test_rows_past_the_groups_may_hold_anything(layer, big, monkeypatch,
+                                                where):
     """On the chip a grouped product leaves the buffer rows past its
     groups as they may (the absent experts' pairs sit there): with NaN in
-    them, as the chip has, result and gradients are what they were."""
-    x, params = layer
-    held = (2, 3)
-    share = _share(params, held)
+    them, as the chip has, result and gradients are what they were.  The
+    common pass's buffer has such rows under an even load; at ``C + 1``
+    pairs it is full and all rows but one of the overflow pass's are."""
+    if where == "common":
+        x, params = layer
+        model = HeldMoEMLP(H, F, num_experts=E, k=K, held=(2, 3))
+        share = _share(params, (2, 3))
+    else:
+        x, params, _ = big["c_plus_1"]
+        x, model, share = x[None], _big_layer(), _big_share(params)
     w = jax.random.normal(jax.random.key(6), x.shape)
 
     def loss(p, x):
-        return (HeldMoEMLP(H, F, num_experts=E, k=K, held=held).apply(
-            {"params": p}, x) * w).sum()
+        return (model.apply({"params": p}, x) * w).sum()
 
     want = jax.value_and_grad(loss, argnums=(0, 1))(share, x)
     real = jax.lax.ragged_dot
+    planted = []
 
     def garbage_past_the_groups(lhs, rhs, group_sizes, **kw):
         out = real(lhs, rhs, group_sizes, **kw)
         live = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        planted.append(out.shape[0])
         return jnp.where(live[:, None], out, jnp.nan)
 
     monkeypatch.setattr(jax.lax, "ragged_dot", garbage_past_the_groups)
     got = jax.value_and_grad(loss, argnums=(0, 1))(share, x)
+    # a product of the pass the case names was among them: T rows for
+    # some 50 held pairs; 1,536 for the one pair past C
+    assert {"common": T, "overflow": 2048 - 512}[where] in planted
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
+
+
+def _rows_by_columns(jaxpr, skip_branch, found):
+    """Every (equation, shape) of ``jaxpr`` and of what it calls, but for
+    branch ``skip_branch`` of its ``cond``s."""
+    for eqn in jaxpr.eqns:
+        for v in (*eqn.invars, *eqn.outvars):
+            found.append((eqn.primitive.name,
+                          tuple(getattr(v.aval, "shape", ()))))
+        for name, param in eqn.params.items():
+            subs = param if isinstance(param, (tuple, list)) else (param,)
+            for i, sub in enumerate(subs):
+                inner = getattr(sub, "jaxpr", sub)
+                if not hasattr(inner, "eqns"):
+                    continue
+                if eqn.primitive.name == "cond" and name == "branches" \
+                        and i == skip_branch:
+                    continue
+                _rows_by_columns(inner, skip_branch, found)
+    return found
+
+
+@pytest.mark.parametrize("router", ["tiled", "drawn_whole"])
+def test_no_pass_over_rows_has_the_worst_case_outside_the_overflow_branch(
+        big, router):
+    """Counts, not times (this host has no chip): in the layer's value
+    and gradient at T, k, held, E = 256, 8, 8, 64, outside the ``cond``'s
+    overflow branch nothing has ``T * k`` rows of ``H`` columns, nor more
+    than ``C``: not the gathers in and out, not the folds, not the
+    grouped products.
+    With the cell's tiled routers (every token one held pair) and with a
+    router drawn whole (some token five or six), so that a fold which is
+    cheap only at one pair a token fails here."""
+    x, params, _ = big["even"]
+    t, h, k, e = BIG["t"], BIG["h"], BIG["k"], BIG["e"]
+    if router == "tiled":
+        kernel = np.asarray(params["router"]["kernel"])
+        params = {**params, "router": {"kernel": jnp.asarray(
+            np.tile(kernel[:, :k], (1, e // k)))}}
+    share = _big_share(params)
+
+    def loss(p, x):
+        return _big_layer().apply({"params": p}, x[None]).sum()
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(
+        share, x).jaxpr
+    c, worst = common_rows(t, k, len(BIG["held"]), e), 2048
+    # the walk sees into branches: the overflow pass's rows are there
+    assert any(p == "gather" and s == (worst - c, h)
+               for p, s in _rows_by_columns(jaxpr, None, []))
+    found = _rows_by_columns(jaxpr, 1, [])
+    assert any(p.startswith("ragged_dot") for p, _ in found)
+    assert any(p == "gather" and s == (c, h) for p, s in found)
+    # nothing of T * k rows, and nothing of more rows than C either
+    for prim, shape in found:
+        assert not (len(shape) >= 2 and shape[-1] == h
+                    and int(np.prod(shape[:-1])) > c), (prim, shape)
 
 
 def test_expert_parameters_keep_the_leading_axis_ep_spec_shards(layer):
