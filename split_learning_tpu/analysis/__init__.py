@@ -19,9 +19,9 @@ enforced only by runtime tests:
   loops, quantizer kernels actually staged on device;
 * :mod:`~split_learning_tpu.analysis.pallas_check` — the Pallas
   kernel plane (PK001): every enableable kernel (fused quantize/
-  dequantize, fused stage_update, llama flash attention) traced with
-  the kernel on must show its ``pallas_call`` in the hot-path jaxpr —
-  kernels cannot silently fall back to XLA.
+  dequantize, fused stage_update, the decoders' flash attention) traced
+  with the kernel on must show its ``pallas_call`` in the hot-path
+  jaxpr — kernels cannot silently fall back to XLA.
 
 CLI: ``python -m split_learning_tpu.analysis`` (wrapper:
 ``tools/slcheck.py``).  This package is import-light on purpose —

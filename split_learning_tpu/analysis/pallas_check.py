@@ -20,10 +20,11 @@ it):
 * stage_update — a real :class:`MeshFoldBackend` built with
   ``stage_update`` enabled, driven through ``stage_update`` exactly
   like the JX007 jaxpr audit, then every cached fused program traced;
-* flash attention — the llama decoder path (``use_flash=True``): a
-  tiny TinyLlama forward traced end to end, proving the model-level
-  flag still routes through the Pallas kernel in the compiled step
-  (before this gate, nothing asserted that).
+* flash attention — the decoders' one ``Attention``
+  (``models/decoder.py``, ``use_flash=True``): a tiny TinyLlama forward
+  traced end to end, proving the builders' keyword still routes
+  through the Pallas kernel in the compiled step (before this gate,
+  nothing asserted that).
 
 :func:`check_lowering` is a pure jaxpr->findings helper so the
 negative test can prove the gate actually fires on a pallas-free
@@ -279,7 +280,7 @@ def _check_stage_update_kernel() -> list[Finding]:
 
 
 def _check_flash_lowering() -> list[Finding]:
-    """The llama attention path: a tiny TinyLlama with
+    """``models/decoder.py Attention``: a tiny TinyLlama with
     ``use_flash=True`` traced end to end must keep ``flash_attention``
     as a pallas_call in the compiled step."""
     import jax

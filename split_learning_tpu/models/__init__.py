@@ -7,6 +7,12 @@ contiguous slice of it — the TPU-native counterpart of the reference's
 per-model ``Klass(start_layer, end_layer)`` pattern
 (``/root/reference/src/model/VGG16_CIFAR10.py:4-9``) without one class per
 model/shard combination.
+
+A model family is one file that registers its builders.  The causal
+language models share one (``decoder.py``): an architecture of that family
+is a registered builder of some 25 lines that translates its
+configuration's keys, plus, where its mixer or feed-forward is new, one
+module and one entry of ``decoder.MIXERS`` / ``decoder.FEED_FORWARDS``.
 """
 
 from split_learning_tpu.models.split import (
@@ -19,8 +25,7 @@ import split_learning_tpu.models.kwt  # noqa: F401  (registers KWT_*)
 import split_learning_tpu.models.vit  # noqa: F401  (registers ViT_*)
 import split_learning_tpu.models.mobilenet  # noqa: F401  (MobileNetv1_*)
 import split_learning_tpu.models.resnet  # noqa: F401  (ResNet50_*)
-import split_learning_tpu.models.llama  # noqa: F401  (TinyLlama_*)
-import split_learning_tpu.models.mellum  # noqa: F401  (Mellum2_*)
+import split_learning_tpu.models.decoder  # noqa: F401  (TinyLlama*, Mellum2_*)
 
 __all__ = [
     "LayerSpec", "SplitModel", "build_model", "model_registry",

@@ -1,0 +1,370 @@
+"""Causal language models as indexed layers: ONE pre-RMSNorm decoder block,
+whose mixer and feed-forward each layer picks from a table.
+
+A block is ``x + mixer(norm(x))``; ``x + feed_forward(norm(x))``
+(:class:`DecoderBlock`).  Which mixer and which feed-forward layer ``i``
+has is decided here and nowhere else: :data:`MIXERS` and
+:data:`FEED_FORWARDS` map a configuration's kind names (the published
+``layer_types`` / ``mlp_layer_types`` values) to code, and
+:func:`decoder_specs` looks each layer's pair up.  An architecture of this
+family is a registered builder that translates its configuration's own key
+names; a new mixer or feed-forward is one module and one table entry.
+
+The mixers there are: grouped-query attention, no bias, rotary embedding
+(:class:`Attention`), ``full_attention`` or ``sliding_attention`` (a query
+at ``p`` sees keys ``p - window + 1 .. p``), each kind with its own RoPE.
+The feed-forwards: a ``dense`` SwiGLU, ``sparse`` experts of which this
+program may hold a share
+(:class:`~split_learning_tpu.parallel.expert.HeldMoEMLP`), and ``capacity``
+experts that drop what overflows
+(:class:`~split_learning_tpu.parallel.expert.MoEMLP`).
+
+Split-layer contract: 1 = token embedding, 2..n+1 = decoder blocks,
+n+2 = final RMSNorm, n+3 = untied LM head.  The streaming activation
+between any two stages is the (B, S, H) hidden state, exactly what
+``ppermute``/the wire carries.  Causality needs no mask plumbing across
+stages: each block rebuilds its own mask from the sequence length.
+
+Loss: next-token CE.  The labels tensor is the input shifted by the data
+pipeline (``data/datasets.py`` TINYSTORIES provider), so the pipeline's
+``softmax_cross_entropy`` path broadcasts over (B, S) unchanged.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Callable
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from split_learning_tpu.models.split import (
+    LayerSpec, register_model, module_plain_fn as _plain_fn,
+)
+# parallel/pipeline.py imports this package for ``build_model``, which
+# models/__init__.py binds before it imports this module
+from split_learning_tpu.parallel.expert import HeldMoEMLP, MoEMLP, swiglu
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+#: Mellum-2's published ``rope_parameters`` (both kinds at theta 500,000)
+ROPE_PARAMETERS = {
+    FULL: {"rope_type": "yarn", "rope_theta": 500000.0, "factor": 16.0,
+           "original_max_position_embeddings": 8192, "beta_fast": 32.0,
+           "beta_slow": 1.0, "attention_factor": 1.2772588722239782},
+    SLIDING: {"rope_type": "default", "rope_theta": 500000.0},
+}
+
+
+# --------------------------------------------------------------------------
+# rotary embedding
+# --------------------------------------------------------------------------
+
+def rope_inv_freq(head_dim: int, base: float) -> np.ndarray:
+    """Plain rotary frequencies ``base^(-2i / head_dim)``, i = 0..D/2-1."""
+    return 1.0 / (base ** (np.arange(0, head_dim, 2) / head_dim))
+
+
+def yarn_inv_freq(head_dim: int, rope_theta: float, factor: float,
+                  original_max_position_embeddings: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0,
+                  **_) -> np.ndarray:
+    """YaRN's frequencies, computed once (not per length): each of the
+    ``head_dim / 2`` plain frequencies is kept where it turns more than
+    ``beta_fast`` times over the original context, divided by ``factor``
+    where it turns fewer than ``beta_slow`` times, and blended linearly
+    by index between the two."""
+    extrap = rope_inv_freq(head_dim, rope_theta)
+    interp = extrap / factor
+
+    def correction_dim(rotations):
+        return head_dim * math.log(original_max_position_embeddings
+                                   / (rotations * 2 * math.pi)) \
+            / (2 * math.log(rope_theta))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low)
+                   / max(high - low, 1e-3), 0, 1)
+    return interp * ramp + extrap * (1 - ramp)
+
+
+def rope_of(kind: str, head_dim: int, rope_parameters: dict) -> tuple:
+    """``(inv_freq, factor on cos and sin)`` of one kind of layer."""
+    p = rope_parameters[kind]
+    if p.get("rope_type", "default") == "yarn":
+        return yarn_inv_freq(head_dim, **{k: v for k, v in p.items()
+                                          if k != "rope_type"}), \
+            float(p.get("attention_factor", 1.0))
+    return rope_inv_freq(head_dim, p["rope_theta"]), 1.0
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: np.ndarray,
+         interleaved: bool = True, factor: float = 1.0) -> jnp.ndarray:
+    """Rotary embedding over the last dim of (B, S, H, D).  The caller
+    says which frequencies (``inv_freq``, D/2 of them), which pairing —
+    ``interleaved`` turns the pairs (2i, 2i + 1), the half-split
+    (``rotate_half``) convention the pairs (i, i + D/2) — and a
+    ``factor`` on cos and sin (YaRN's attention factor)."""
+    freqs = positions[:, None].astype(jnp.float32) * inv_freq[None, :]
+    cos = factor * jnp.cos(freqs)[None, :, None, :]
+    sin = factor * jnp.sin(freqs)[None, :, None, :]
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        rot = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                        axis=-1).reshape(x.shape)
+    else:
+        x1, x2 = jnp.split(x, 2, axis=-1)
+        rot = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1)
+    return rot.astype(x.dtype)
+
+
+# --------------------------------------------------------------------------
+# the mixer and the block
+# --------------------------------------------------------------------------
+
+class Attention(nn.Module):
+    """Causal grouped-query attention with the RoPE of its ``kind`` of
+    layer, over all earlier keys or the last ``window`` of them.
+
+    Three back ends, same math.  With ``seq_axis`` set the module runs
+    inside ``shard_map`` (``parallel/sequence.py``): ``x`` is the LOCAL
+    token block, positions are offset by the block's index on that axis
+    and the blocks' keys go round the ring.  ``use_flash`` takes the Pallas
+    kernel (``ops/flash_attention.py``: O(S) memory; it picks a query
+    head's key-value head by index and skips the key blocks outside the
+    window).  Else the scores are an einsum and the mask is applied to
+    them (small sizes only; the kernel's parity oracle).
+    """
+    hidden_size: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_parameters: dict
+    kind: str = FULL
+    interleaved: bool = False
+    window: int | None = None
+    use_flash: bool = False
+    flash_block: int = 512
+    seq_axis: str | None = None
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, _ = x.shape
+        hd, window = self.head_dim, self.window
+        if window is not None and self.seq_axis is not None:
+            raise ValueError("the ring (seq_axis) has no window")
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        q = dense(self.num_heads * hd, name="q_proj")(x)
+        k = dense(self.num_kv_heads * hd, name="k_proj")(x)
+        v = dense(self.num_kv_heads * hd, name="v_proj")(x)
+        q = q.reshape(b, s, self.num_heads, hd)
+        k = k.reshape(b, s, self.num_kv_heads, hd)
+        v = v.reshape(b, s, self.num_kv_heads, hd)
+        inv_freq, factor = rope_of(self.kind, hd, self.rope_parameters)
+        pos = jnp.arange(s)
+        if self.seq_axis is not None:
+            pos = jax.lax.axis_index(self.seq_axis) * s + pos
+        q = rope(q, pos, inv_freq, self.interleaved, factor)
+        k = rope(k, pos, inv_freq, self.interleaved, factor)
+        rep = self.num_heads // self.num_kv_heads
+        with jax.named_scope(
+                "attn_window" if window is not None else "attn_full"):
+            if self.seq_axis is not None:
+                from split_learning_tpu.parallel.sequence import (
+                    ring_attention,
+                )
+                # the ring wants a key-value head a query head
+                out = ring_attention(
+                    q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+                    axis_name=self.seq_axis, causal=True)
+            elif self.use_flash:
+                from split_learning_tpu.ops.flash_attention import (
+                    flash_attention,
+                )
+                out = flash_attention(
+                    q, k, v, causal=True, window=window,
+                    block_q=self.flash_block, block_k=self.flash_block)
+            else:
+                qg = q.reshape(b, s, self.num_kv_heads, rep, hd)
+                scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k) \
+                    / np.sqrt(hd)
+                seen = pos[None, :] <= pos[:, None]
+                if window is not None:
+                    seen &= pos[None, :] > pos[:, None] - window
+                probs = nn.softmax(jnp.where(
+                    seen, scores.astype(jnp.float32), -1e30)).astype(
+                        self.dtype)
+                out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
+        return dense(self.hidden_size, name="o_proj")(
+            out.reshape(b, s, self.num_heads * hd))
+
+
+class DecoderBlock(nn.Module):
+    """``h = x + mixer(norm(x))``; ``h + feed_forward(norm(h))``.
+
+    ``mixer`` makes the mixer's module, given its name; ``feed_forward``
+    is applied to the normed state and creates what it needs in this
+    block's scope (a dense SwiGLU its three kernels, an expert layer its
+    submodule ``moe``): both are the builder's partials over an entry of
+    :data:`MIXERS` / :data:`FEED_FORWARDS`, and the block knows neither
+    attention nor experts.
+    """
+    mixer: Callable[..., nn.Module]
+    feed_forward: Callable[[jnp.ndarray], jnp.ndarray]
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        norm = functools.partial(nn.RMSNorm, epsilon=self.eps,
+                                 dtype=self.dtype)
+        x = x + self.mixer(name="attention")(norm(name="input_norm")(x))
+        return x + self.feed_forward(norm(name="post_norm")(x))
+
+
+def _experts(layer) -> Callable:
+    """An expert layer of ``parallel/expert.py`` as a feed-forward: the
+    caller's submodule ``moe``, as wide as the state it is given."""
+    def apply(x, **kw):
+        return layer(hidden_size=x.shape[-1], name="moe", **kw)(x)
+    return apply
+
+
+# The one place where a layer's kind becomes code.  A mixer is a module
+# class (the block names it ``attention``); a feed-forward a function of the
+# normed state that runs in the block's scope.  Both take that kind's
+# keywords from the builder.
+MIXERS = {FULL: functools.partial(Attention, kind=FULL),
+          SLIDING: functools.partial(Attention, kind=SLIDING)}
+FEED_FORWARDS = {"dense": swiglu, "sparse": _experts(HeldMoEMLP),
+                 "capacity": _experts(MoEMLP)}
+
+
+def _entry(table: dict, what: str, kind: str, keywords: dict):
+    if kind not in table:
+        raise ValueError(f"{what} {kind!r}: the known ones are "
+                         f"{sorted(table)}")
+    return functools.partial(table[kind], **keywords[kind])
+
+
+def decoder_specs(layers, mixers: dict, feed_forwards: dict, *,
+                  vocab_size: int, hidden_size: int, eps: float,
+                  dtype=jnp.float32) -> tuple:
+    """The split layers of a decoder: the embedding, one
+    :class:`DecoderBlock` for each ``(mixer kind, feed-forward kind)`` of
+    ``layers``, the final RMSNorm, the untied head.  ``mixers`` and
+    ``feed_forwards`` hold, by kind, the keywords of the table's entry."""
+    specs = [LayerSpec("layer1", make=functools.partial(
+        nn.Embed, num_embeddings=vocab_size, features=hidden_size,
+        dtype=dtype), fn=_plain_fn)]
+    for mixer, feed_forward in layers:
+        specs.append(LayerSpec(
+            f"layer{1 + len(specs)}", make=functools.partial(
+                DecoderBlock,
+                mixer=_entry(MIXERS, "layer type", mixer, mixers),
+                feed_forward=_entry(FEED_FORWARDS, "feed-forward",
+                                    feed_forward, feed_forwards),
+                eps=eps, dtype=dtype), fn=_plain_fn))
+    specs.append(LayerSpec(f"layer{1 + len(specs)}", make=functools.partial(
+        nn.RMSNorm, epsilon=eps, dtype=dtype), fn=_plain_fn))
+    specs.append(LayerSpec(f"layer{1 + len(specs)}", make=functools.partial(
+        nn.Dense, features=vocab_size, use_bias=False, dtype=dtype),
+        fn=_plain_fn))
+    return tuple(specs)
+
+
+# --------------------------------------------------------------------------
+# the registered architectures
+# --------------------------------------------------------------------------
+
+def _llama_specs(vocab_size: int = 32000, hidden_size: int = 2048,
+                 num_heads: int = 32, num_kv_heads: int = 4,
+                 intermediate_size: int = 5632, n_block: int = 22,
+                 use_flash: bool = False, dtype=jnp.float32,
+                 num_experts: int = 0, k: int = 2,
+                 seq_axis: str | None = None) -> tuple:
+    """LLaMA geometry (TinyLlama-1.1B's sizes): full attention in every
+    block, heads of ``hidden_size / num_heads``, interleaved RoPE at base
+    10,000, RMSNorm 1e-5; capacity experts where ``num_experts`` > 0."""
+    attention = dict(
+        hidden_size=hidden_size, num_heads=num_heads,
+        num_kv_heads=num_kv_heads, head_dim=hidden_size // num_heads,
+        rope_parameters={FULL: {"rope_theta": 10000.0}}, interleaved=True,
+        use_flash=use_flash, flash_block=128, seq_axis=seq_axis, dtype=dtype)
+    dense = dict(intermediate_size=intermediate_size, dtype=dtype)
+    return decoder_specs(
+        [(FULL, "capacity" if num_experts > 0 else "dense")] * n_block,
+        {FULL: attention},
+        {"dense": dense,
+         "capacity": dict(dense, num_experts=num_experts, k=k)},
+        vocab_size=vocab_size, hidden_size=hidden_size, eps=1e-5,
+        dtype=dtype)
+
+
+@register_model("TinyLlama_TINYSTORIES")
+def tinyllama_tinystories(dtype=jnp.float32, **kw) -> tuple:
+    """TinyLlama-1.1B geometry (2048 hidden, 22 blocks, 32 Q / 4 KV heads,
+    5632 intermediate, 32000 vocab); input (B, S) int32 token ids, output
+    (B, S, vocab) next-token logits.  25 layers at full size."""
+    return _llama_specs(dtype=dtype, **kw)
+
+
+@register_model("TinyLlamaMoE_TINYSTORIES")
+def tinyllama_moe_tinystories(dtype=jnp.float32, num_experts: int = 8,
+                              **kw) -> tuple:
+    """Sparse-MoE variant: every decoder block's MLP is a top-k
+    mixture of ``num_experts`` SwiGLU experts, shardable over an
+    ``expert`` mesh axis (``parallel/expert.py``; no reference
+    counterpart, SURVEY.md §2.2 EP row).  Same split-layer contract as
+    the dense model."""
+    return _llama_specs(dtype=dtype, num_experts=num_experts, **kw)
+
+
+@register_model("Mellum2_TINYSTORIES")
+def mellum2_tinystories(
+        vocab_size: int = 98304, hidden_size: int = 2304,
+        num_attention_heads: int = 32, num_key_value_heads: int = 4,
+        head_dim: int = 128, num_hidden_layers: int = 28,
+        layer_types: tuple | None = None, sliding_window: int = 1024,
+        rope_parameters: dict | None = None, rms_norm_eps: float = 1e-6,
+        num_experts: int = 64, num_experts_per_tok: int = 8,
+        moe_intermediate_size: int = 896,
+        experts_held: int | tuple | None = None, use_flash: bool = False,
+        flash_block: int = 512, dtype=jnp.float32) -> tuple:
+    """Mellum2-12B-A2.5B geometry under the keys of the published
+    configuration
+    (https://huggingface.co/JetBrains/Mellum2-12B-A2.5B-Instruct/blob/main/config.json);
+    input (B, S) int32 token ids, output (B, S, vocab) next-token logits.
+    ``layer_types`` names each block ``sliding_attention`` (plain RoPE) or
+    ``full_attention`` (YaRN frequencies, cos and sin multiplied by its
+    attention factor), by default the published period (three sliding, one
+    full); RoPE is half-split (``rotate_half``); after attention a top-k
+    router over ``num_experts`` experts with renormalized weights and
+    SwiGLU experts of width ``moe_intermediate_size``, no shared expert.
+    ``experts_held`` says which experts live here, a count (experts
+    ``0 .. n - 1``) or the ids: the others' part of each block's result
+    is left out."""
+    kinds = tuple(layer_types) if layer_types is not None else tuple(
+        FULL if i % 4 == 3 else SLIDING for i in range(num_hidden_layers))
+    if len(kinds) != num_hidden_layers:
+        raise ValueError(f"{num_hidden_layers} layers, layer_types {kinds}")
+    held = tuple(range(experts_held)) if isinstance(experts_held, int) \
+        else (tuple(experts_held) if experts_held is not None else None)
+    attention = dict(
+        hidden_size=hidden_size, num_heads=num_attention_heads,
+        num_kv_heads=num_key_value_heads, head_dim=head_dim,
+        rope_parameters=dict(rope_parameters or ROPE_PARAMETERS),
+        use_flash=use_flash, flash_block=flash_block, dtype=dtype)
+    return decoder_specs(
+        [(kind, "sparse") for kind in kinds],
+        {FULL: attention, SLIDING: dict(attention, window=sliding_window)},
+        {"sparse": dict(intermediate_size=moe_intermediate_size,
+                        num_experts=num_experts, k=num_experts_per_tok,
+                        held=held, dtype=dtype)},
+        vocab_size=vocab_size, hidden_size=hidden_size, eps=rms_norm_eps,
+        dtype=dtype)

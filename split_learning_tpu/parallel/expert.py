@@ -94,19 +94,26 @@ def topk_dispatch(probs: jnp.ndarray, k: int, capacity: int):
     return combine, dispatch, aux
 
 
+def swiglu(x, intermediate_size: int, dtype=jnp.float32):
+    """The SwiGLU feed-forward (LLaMA geometry) over the last axis of ``x``.
+    Its kernels ``gate_proj``, ``up_proj``, ``down_proj`` are created in the
+    CALLER's scope: a dense decoder block's own (``models/decoder.py``), or
+    one expert's (:class:`ExpertFFN`)."""
+    dense = functools.partial(nn.Dense, use_bias=False, dtype=dtype)
+    gate = nn.silu(dense(intermediate_size, name="gate_proj")(x))
+    up = dense(intermediate_size, name="up_proj")(x)
+    return dense(x.shape[-1], name="down_proj")(gate * up)
+
+
 class ExpertFFN(nn.Module):
-    """One expert: SwiGLU FFN (LLaMA geometry)."""
-    hidden_size: int
+    """One expert of :class:`MoEMLP`: :func:`swiglu` as the module that
+    ``nn.vmap`` needs."""
     intermediate_size: int
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        dense = functools.partial(nn.Dense, use_bias=False,
-                                  dtype=self.dtype)
-        gate = nn.silu(dense(self.intermediate_size, name="gate_proj")(x))
-        up = dense(self.intermediate_size, name="up_proj")(x)
-        return dense(self.hidden_size, name="down_proj")(gate * up)
+        return swiglu(x, self.intermediate_size, self.dtype)
 
 
 class MoEMLP(nn.Module):
@@ -155,9 +162,8 @@ class MoEMLP(nn.Module):
             split_rngs={"params": True},
             axis_size=self.num_experts,
             metadata_params={nn.PARTITION_NAME: "expert"},
-        )(hidden_size=self.hidden_size,
-          intermediate_size=self.intermediate_size,
-          dtype=self.dtype, name="experts")
+        )(intermediate_size=self.intermediate_size, dtype=self.dtype,
+          name="experts")
         expert_out = experts(expert_in)            # (E, C, H)
         # (T,E,C),(E,C,H) -> (T,H): the gather all-to-all
         out = jnp.einsum("tec,ech->th", combine.astype(self.dtype),

@@ -1,4 +1,4 @@
-"""The program's Mellum-2 decoder (``models/mellum.py``: windowed and full
+"""The program's Mellum-2 decoder (``models/decoder.py``: windowed and full
 attention, YaRN, a held share of experts) against the benchmark's plain
 reference (``benchmarks/configs/mellum2_12b_c3.py``) on seeded weights at a
 small size: logits, loss and first gradient; YaRN's frequencies against
@@ -15,7 +15,7 @@ import pytest
 from tests.conftest import bench_reference
 
 from split_learning_tpu.models import build_model
-from split_learning_tpu.models import mellum
+from split_learning_tpu.models import decoder
 from split_learning_tpu.parallel.expert import moe_aux_loss
 
 # 4 layers (one period), hidden 64, 4 query / 2 key-value heads of 16,
@@ -104,6 +104,56 @@ def test_the_tree_is_the_references_tree(seeded):
     assert len(model.specs) == TINY["num_hidden_layers"] + 3
 
 
+LLAMA_TINY = dict(vocab_size=128, hidden_size=32, num_heads=2,
+                  num_kv_heads=1, intermediate_size=64, n_block=1)
+ATTENTION = [f"attention/{p}_proj/kernel" for p in "qkvo"]
+NORMS = ["input_norm/scale", "post_norm/scale"]
+SWIGLU = ["gate_proj/kernel", "up_proj/kernel", "down_proj/kernel"]
+# what the benchmark's reference writes (``configs/mellum2_12b_c3.py
+# init``), ``tp_spec``/``ep_spec`` and ``learning.lora-targets`` match by
+BLOCK_PATHS = {
+    "TinyLlama_TINYSTORIES": (LLAMA_TINY, ATTENTION + NORMS + SWIGLU),
+    "TinyLlamaMoE_TINYSTORIES": (
+        {**LLAMA_TINY, "num_experts": 4},
+        ATTENTION + NORMS + ["moe/router/kernel"]
+        + [f"moe/experts/{p}" for p in SWIGLU]),
+    "Mellum2_TINYSTORIES": (
+        {**TINY, "num_hidden_layers": 1, "layer_types": ("full_attention",)},
+        ATTENTION + NORMS + ["moe/router/kernel"]
+        + [f"moe/experts/{p}" for p in SWIGLU]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BLOCK_PATHS))
+def test_a_decoders_tree_has_these_paths(name):
+    """Every registered decoder, one block: embedding, the block's leaves
+    by their literal names, final norm, head."""
+    kwargs, block = BLOCK_PATHS[name]
+    tree = jax.eval_shape(
+        build_model(name, **kwargs).init, jax.random.key(0),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32))["params"]
+    paths = {"/".join(k.key for k in path)
+             for path, _ in jax.tree_util.tree_leaves_with_path(tree)}
+    assert paths == {"layer1/embedding", *(f"layer2/{p}" for p in block),
+                     "layer3/scale", "layer4/kernel"}
+
+
+@pytest.mark.parametrize("what, kind, call", [
+    ("layer type", "linear_attention", lambda kind: build_model(
+        "Mellum2_TINYSTORIES", **{**TINY, "layer_types": (kind,) * 4})),
+    ("feed-forward", "shared", lambda kind: decoder.decoder_specs(
+        [(decoder.FULL, kind)], {decoder.FULL: {}}, {kind: {}},
+        vocab_size=8, hidden_size=8, eps=1e-5)),
+])
+def test_a_kind_outside_the_tables_is_refused_with_the_known_ones(
+        what, kind, call):
+    table = decoder.MIXERS if what == "layer type" else decoder.FEED_FORWARDS
+    with pytest.raises(ValueError) as e:
+        call(kind)
+    assert what in str(e.value) and kind in str(e.value)
+    assert str(sorted(table)) in str(e.value)
+
+
 def test_the_window_and_the_held_share_change_the_result(seeded):
     """The two mechanisms are not no-ops at this size: a model without
     the window, or holding all experts, gives other logits."""
@@ -148,7 +198,7 @@ def test_the_references_routers_give_every_chip_one_choice_a_token(seeded):
 
 # -- YaRN for this configuration's numbers ----------------------------------
 
-PUBLISHED = mellum.ROPE_PARAMETERS[mellum.FULL]
+PUBLISHED = decoder.ROPE_PARAMETERS[decoder.FULL]
 
 
 def _closed_form(i):
@@ -166,7 +216,7 @@ def _closed_form(i):
 
 @pytest.mark.parametrize("where", ["program", "reference"])
 def test_yarn_frequencies_match_the_closed_form(where):
-    fn = mellum.yarn_inv_freq if where == "program" else REF.yarn_inv_freq
+    fn = decoder.yarn_inv_freq if where == "program" else REF.yarn_inv_freq
     got = fn(128, **{k: v for k, v in PUBLISHED.items()
                      if k != "rope_type"})
     want = np.array([_closed_form(i) for i in range(64)])
@@ -183,24 +233,23 @@ def test_attention_factor_scales_cos_and_sin_of_full_layers_only():
     # 0.1 ln(16) + 1: YaRN's own rule for factor 16
     np.testing.assert_allclose(PUBLISHED["attention_factor"],
                                0.1 * math.log(16) + 1, rtol=1e-15)
-    inv_full, f_full = mellum.rope_of(mellum.FULL, 128,
-                                      mellum.ROPE_PARAMETERS)
-    inv_win, f_win = mellum.rope_of(mellum.SLIDING, 128,
-                                    mellum.ROPE_PARAMETERS)
+    inv_full, f_full = decoder.rope_of(decoder.FULL, 128,
+                                       decoder.ROPE_PARAMETERS)
+    inv_win, f_win = decoder.rope_of(decoder.SLIDING, 128,
+                                     decoder.ROPE_PARAMETERS)
     assert f_full == PUBLISHED["attention_factor"] and f_win == 1.0
     np.testing.assert_allclose(
         inv_win, 500000.0 ** (-2 * np.arange(64) / 128), rtol=1e-12)
     assert not np.allclose(inv_full, inv_win)
     x = jax.random.normal(jax.random.key(0), (1, 4, 2, 128))
-    from split_learning_tpu.models.llama import _rope
-    turned = _rope(x, jnp.arange(4), inv_full, interleaved=False,
-                   factor=f_full)
+    turned = decoder.rope(x, jnp.arange(4), inv_full, interleaved=False,
+                          factor=f_full)
     # position 0 is not turned, only scaled by the factor
     np.testing.assert_allclose(np.asarray(turned[:, 0]),
                                np.asarray(x[:, 0]) * f_full, rtol=1e-6)
     np.testing.assert_allclose(
         np.asarray(turned), np.asarray(REF._rope(
-            x, mellum.FULL, mellum.ROPE_PARAMETERS)), rtol=1e-5, atol=1e-6)
+            x, decoder.FULL, decoder.ROPE_PARAMETERS)), rtol=1e-5, atol=1e-6)
 
 
 # -- through the compiled pipeline step -----------------------------------------
