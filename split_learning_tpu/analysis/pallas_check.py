@@ -24,7 +24,10 @@ it):
   (``models/decoder.py``, ``use_flash=True``): a tiny TinyLlama forward
   traced end to end, proving the builders' keyword still routes
   through the Pallas kernel in the compiled step (before this gate,
-  nothing asserted that).
+  nothing asserted that);
+* grouped products — ``parallel/expert.py HeldMoEMLP`` traced through
+  value and gradient: the ``pallas_call``s of ``ops/grouped_matmul.py``
+  are there and no ``ragged_dot`` is.
 
 :func:`check_lowering` is a pure jaxpr->findings helper so the
 negative test can prove the gate actually fires on a pallas-free
@@ -56,6 +59,7 @@ from split_learning_tpu.analysis.findings import Finding
 _REL_QUANT = "split_learning_tpu/runtime/codec/quant.py"
 _REL_AGG = "split_learning_tpu/runtime/aggregate.py"
 _REL_FLASH = "split_learning_tpu/ops/flash_attention.py"
+_REL_GMM = "split_learning_tpu/ops/grouped_matmul.py"
 _REL_KQUANT = "split_learning_tpu/ops/kernels/quant.py"
 _REL_KUPDATE = "split_learning_tpu/ops/kernels/update.py"
 
@@ -67,6 +71,11 @@ FLASH_SHAPES = ((2, 2048, 32, 64), (2, 2048, 16, 128))
 #: 512, with the window of its sliding layers and without
 FLASH_GROUPED = ((2, 4096, 32, 4, 128, 1024, 512),
                  (2, 4096, 32, 4, 128, None, 512))
+#: (rows, K, N, groups) of the token cell's grouped products: a common
+#: pass's ``C`` rows against the 8 held experts' ``gate``/``up`` and
+#: ``down`` matrices, and the overflow pass's ``worst - C`` rows
+GROUPED_SHAPES = ((16384, 2304, 896, 8), (16384, 896, 2304, 8),
+                  (49152, 2304, 896, 8), (49152, 896, 2304, 8))
 #: one microbatch of the VGG16 cut-7 boundary (configs/baseline1.yaml):
 #: the activation the codec quantizes, and its gradient
 CUT7_BOUNDARY = (32, 16, 16, 64)
@@ -75,33 +84,25 @@ CUT7_BOUNDARY = (32, 16, 16, 64)
 QUANT_TILES = (64, 256)
 
 
+def primitives(jaxpr, found=None) -> set:
+    """Names of every primitive of the (closed) jaxpr and of what it
+    calls: custom_vjp/jit carry ClosedJaxprs, ``pallas_call`` its kernel
+    as a plain Jaxpr, a ``cond`` its branches as a list."""
+    found = set() if found is None else found
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        found.add(eqn.primitive.name)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) \
+                    else (param,):
+                if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                    primitives(sub, found)
+    return found
+
+
 def contains_pallas_call(jaxpr) -> bool:
     """True iff a ``pallas_call`` primitive appears anywhere in the
     (closed) jaxpr, including nested sub-jaxprs."""
-    seen: set = set()
-
-    def walk(jx) -> bool:
-        if id(jx) in seen:
-            return False
-        seen.add(id(jx))
-        for eqn in jx.eqns:
-            if eqn.primitive.name == "pallas_call":
-                return True
-            for sub in eqn.params.values():
-                inner = getattr(sub, "jaxpr", None)
-                if inner is not None and walk(inner):
-                    return True
-                # pallas_call itself carries the kernel as a plain
-                # Jaxpr param; custom_vjp/jit carry ClosedJaxprs —
-                # both expose .jaxpr, lists carry several
-                if isinstance(sub, (list, tuple)):
-                    for s in sub:
-                        inner = getattr(s, "jaxpr", None)
-                        if inner is not None and walk(inner):
-                            return True
-        return False
-
-    return walk(jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr)
+    return "pallas_call" in primitives(jaxpr)
 
 
 def check_lowering(jaxpr, rel: str, where: str) -> list[Finding]:
@@ -117,12 +118,42 @@ def check_lowering(jaxpr, rel: str, where: str) -> list[Finding]:
         "fell back to the XLA chain")]
 
 
+def grouped_lowering_cases() -> list[tuple]:
+    """The three grouped-product kernels (``slt_gmm``, ``slt_gmm_t``,
+    ``slt_gmm_drhs``) in bfloat16 at :data:`GROUPED_SHAPES`, with the
+    tiles the operation picks itself."""
+    import jax
+    import jax.numpy as jnp
+
+    from split_learning_tpu.ops import grouped_matmul as gm
+
+    def abstract(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    cases = []
+    for rows, k, n, groups in GROUPED_SHAPES:
+        sizes = abstract(groups, dtype=jnp.int32)
+        shape = f"({rows}, {k}, {n}, {groups})"
+        cases += [
+            (f"gmm{shape}", _REL_GMM,
+             functools.partial(gm.gmm, interpret=False),
+             (abstract(rows, k), abstract(groups, k, n), sizes)),
+            (f"gmm_t{shape}", _REL_GMM,
+             functools.partial(gm.gmm, transposed=True, interpret=False),
+             (abstract(rows, n), abstract(groups, k, n), sizes)),
+            (f"gmm_drhs{shape}", _REL_GMM,
+             functools.partial(gm.gmm_drhs, interpret=False),
+             (abstract(rows, k), abstract(rows, n), sizes))]
+    return cases
+
+
 def lowering_cases() -> list[tuple]:
     """``(name, rel, fn, abstract_args)`` for every Pallas kernel at
     the shapes the chip smoke runs: flash forward and backward, the
-    quantize/dequantize passes over the cut-7 boundary, and both stage
-    updates over every distinct leaf shape of the VGG16 tree (conv
-    kernels and 1-D biases included)."""
+    grouped products of the token cell, the quantize/dequantize passes
+    over the cut-7 boundary, and both stage updates over every distinct
+    leaf shape of the VGG16 tree (conv kernels and 1-D biases
+    included)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -160,6 +191,7 @@ def lowering_cases() -> list[tuple]:
             f"flash_bwd{name}", _REL_FLASH,
             jax.grad(lambda q, k, v, f=grouped: f(q, k, v).astype(
                 jnp.float32).sum(), argnums=(0, 1, 2)), qkv))
+    cases += grouped_lowering_cases()
     n = int(np.prod(CUT7_BOUNDARY))
     for tile in QUANT_TILES:
         t = n // tile
@@ -300,12 +332,40 @@ def _check_flash_lowering() -> list[Finding]:
     return check_lowering(jaxpr, _REL_FLASH, "llama-flash-attention")
 
 
+def _check_grouped_dispatch() -> list[Finding]:
+    """``parallel/expert.py HeldMoEMLP`` with the token cell's structure
+    (a share of 8 of 64 experts, top-8: common pass and overflow pass
+    under a ``cond``), value and gradient: every grouped product is a
+    ``pallas_call`` and none a ``ragged_dot``."""
+    import jax
+    import jax.numpy as jnp
+
+    from split_learning_tpu.parallel.expert import HeldMoEMLP
+
+    layer = HeldMoEMLP(32, 16, num_experts=64, k=8, held=tuple(range(8)))
+    x = jnp.zeros((1, 64, 32))
+    params = jax.eval_shape(
+        lambda k: layer.init(k, x), jax.random.key(0))["params"]
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda p, xx: layer.apply({"params": p}, xx).sum(),
+        argnums=(0, 1)))(params, x)
+    findings = check_lowering(jaxpr, _REL_GMM, "held-moe-grouped-dot")
+    left = sorted(p for p in primitives(jaxpr) if p.startswith("ragged_dot"))
+    if left:
+        findings.append(Finding(
+            "PK001", _REL_GMM, 0, "held-moe-grouped-dot",
+            f"HeldMoEMLP still multiplies through {left}: the grouped "
+            "products are ops/grouped_matmul.py's kernels"))
+    return findings
+
+
 def run(root: pathlib.Path, trace: bool = True) -> list[Finding]:
     if not trace:
         return []
     findings = _check_codec_kernels()
     findings += _check_stage_update_kernel()
     findings += _check_flash_lowering()
+    findings += _check_grouped_dispatch()
     for case in lowering_cases():
         findings += check_tpu_lowering(*case)
     return findings

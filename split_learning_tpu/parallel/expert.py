@@ -24,8 +24,9 @@ Switch semantics).  The load-balance auxiliary loss is sown into the
 train step adds it to the objective.
 
 :class:`HeldMoEMLP` is the other layer: no capacity and no drops (pairs
-sorted by expert, grouped matrix products), for one chip's share of the
-experts of a router that picks among all of them.
+sorted by expert, grouped matrix products: the Pallas kernels of
+``ops/grouped_matmul.py``, :func:`grouped_dot`), for one chip's share of
+the experts of a router that picks among all of them.
 """
 
 from __future__ import annotations
@@ -37,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from split_learning_tpu.ops.grouped_matmul import grouped_dot, live_rows
 
 
 def topk_dispatch(probs: jnp.ndarray, k: int, capacity: int):
@@ -230,12 +233,19 @@ def route_held(probs: jnp.ndarray, k: int, first: int, n_held: int):
     return weights, plan, aux
 
 
+def _clipped_sizes(sizes, lo: int, hi: int):
+    """The groups' sizes clipped to the sorted rows ``[lo, hi)``: a group
+    that straddles ``lo`` or ``hi`` gives each side its part."""
+    ends = jnp.cumsum(sizes)
+    return jnp.clip(ends, lo, hi) - jnp.clip(ends - sizes, lo, hi)
+
+
 def pass_plan(plan: dict, lo: int, hi: int, t: int, k: int) -> dict:
     """What one pass over the sorted rows ``[lo, hi)`` needs, all of it
     integers of ``hi - lo`` rows or of ``t * k`` pairs:
 
-    * ``sizes``: the experts' group sizes clipped to the range (a group
-      that straddles ``lo`` or ``hi`` gives each side its part);
+    * ``sizes``: the experts' group sizes clipped to the range
+      (:func:`_clipped_sizes`);
     * ``token``, ``pair``, ``live``: of each row, the token and the pair
       it holds, and whether it lies under the groups' sum;
     * ``by_token``: the rows in (token, choice) order, the live ones
@@ -265,7 +275,7 @@ def pass_plan(plan: dict, lo: int, hi: int, t: int, k: int) -> dict:
         step *= 2
     inside = plan["held"] & (pair_row >= lo) & (pair_row < hi)
     count = jnp.sum(inside.reshape(t, k), axis=1, dtype=jnp.int32)
-    return {"sizes": jnp.clip(ends, lo, hi) - jnp.clip(ends - sizes, lo, hi),
+    return {"sizes": _clipped_sizes(sizes, lo, hi),
             "token": pair // k, "pair": pair, "live": live,
             "by_token": by_token, "joins": tuple(joins),
             "last": jnp.maximum(jnp.cumsum(count) - 1, 0), "has": count > 0,
@@ -346,26 +356,28 @@ def _fold_rows_bwd(res, g):
 fold_rows.defvjp(_fold_rows_fwd, _fold_rows_bwd)
 
 
-def _pass(x, weights, kernels, plan, lo: int, hi: int, precision):
+def _pass(x, weights, kernels, plan, lo: int, hi: int):
     """The held experts' part of the result from the sorted rows
     ``[lo, hi)``: gather the rows' tokens, the SwiGLU as three grouped
-    products (``jax.lax.ragged_dot``) over the group sizes clipped to the
-    range, fold the weighted results into their tokens."""
+    products (:func:`grouped_dot`: float32 operands at
+    ``Precision.HIGHEST``) over the group sizes clipped to the range,
+    fold the weighted results into their tokens."""
     t = x.shape[0]
     with jax.named_scope("moe_route"):
         p = pass_plan(plan, lo, hi, t, weights.shape[0] // t)
         rows = spread_rows(x, p)
     with jax.named_scope("moe_experts"):
         gate, up, down = kernels
-        dot = functools.partial(jax.lax.ragged_dot, group_sizes=p["sizes"],
-                                precision=precision)
+        def dot(lhs, rhs):
+            return grouped_dot(lhs, rhs, p["sizes"])
+
         y = dot(nn.silu(dot(rows, gate)) * dot(rows, up), down)
     with jax.named_scope("moe_route"):
         return fold_rows(y, weights, p)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def both_passes(x, weights, kernels, plan, c: int, worst: int, precision):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def both_passes(x, weights, kernels, plan, c: int, worst: int):
     """The common pass over the sorted rows ``[0, c)`` and, where the held
     pairs number more, the overflow pass over ``[c, worst)`` added to it,
     under one ``cond``: every pair is computed whatever the load.
@@ -376,31 +388,30 @@ def both_passes(x, weights, kernels, plan, c: int, worst: int, precision):
     ``cond``), and the skipped branch hands the common pass's cotangents
     through as they are, where a differentiated ``cond`` would zero-fill
     every residual of the branch it did not take."""
-    out = _pass(x, weights, kernels, plan, 0, c, precision)
-    return _add_overflow(out, x, weights, kernels, plan, c, worst, precision)
+    out = _pass(x, weights, kernels, plan, 0, c)
+    return _add_overflow(out, x, weights, kernels, plan, c, worst)
 
 
-def _add_overflow(out, x, weights, kernels, plan, c, worst, precision):
+def _add_overflow(out, x, weights, kernels, plan, c, worst):
     return jax.lax.cond(
         jnp.sum(plan["group_sizes"]) > c,
-        lambda o: o + _pass(x, weights, kernels, plan, c, worst, precision),
+        lambda o: o + _pass(x, weights, kernels, plan, c, worst),
         lambda o: o, out)
 
 
-def _both_passes_fwd(x, weights, kernels, plan, c, worst, precision):
+def _both_passes_fwd(x, weights, kernels, plan, c, worst):
     out, pull = jax.vjp(
-        lambda *a: _pass(*a, plan, 0, c, precision), x, weights, kernels)
-    out = _add_overflow(out, x, weights, kernels, plan, c, worst, precision)
+        lambda *a: _pass(*a, plan, 0, c), x, weights, kernels)
+    out = _add_overflow(out, x, weights, kernels, plan, c, worst)
     return out, (pull, x, weights, kernels, plan)
 
 
-def _both_passes_bwd(c, worst, precision, res, g):
+def _both_passes_bwd(c, worst, res, g):
     pull, x, weights, kernels, plan = res
 
     def and_overflow(grads):
         _, pull_over = jax.vjp(
-            lambda *a: _pass(*a, plan, c, worst, precision),
-            x, weights, kernels)
+            lambda *a: _pass(*a, plan, c, worst), x, weights, kernels)
         return jax.tree_util.tree_map(jnp.add, grads, pull_over(g))
 
     grads = jax.lax.cond(jnp.sum(plan["group_sizes"]) > c, and_overflow,
@@ -467,19 +478,24 @@ class HeldMoEMLP(nn.Module):
     both directions (:func:`spread_rows`, :func:`fold_rows`).
 
     Sown: ``aux_loss`` under ``intermediates`` (load balance over all
-    experts, from the router alone), and three counters that a step of
+    experts, from the router alone), and four counters that a step of
     ``parallel/pipeline.py`` folds and hands back (its ``COUNTER_FOLDS``):
     ``moe_pairs_held`` under ``counters_sum`` (the pairs computed here: an
     operator reads from it what share of the routed work this chip holds,
     and whether a live router drifts towards the held experts),
     ``moe_overflow_passes`` under ``counters_sum`` (1 where this call's
     pairs exceeded ``C`` and the overflow pass ran: a share whose calls
-    mostly overflow pays for two passes) and ``moe_load_max_over_mean``
-    under ``counters_max`` (the fullest held expert's pairs over the
-    mean).  Nothing can be dropped, so there is no counter of dropped
-    pairs.
+    mostly overflow pays for two passes), ``moe_gmm_rows`` under
+    ``counters_sum`` (the rows the grouped products' tiles cover, both
+    passes: over ``moe_pairs_held`` it says what the tiling adds at the
+    groups' ends; 2 would mean a kernel walks the buffer) and
+    ``moe_load_max_over_mean`` under ``counters_max`` (the fullest held
+    expert's pairs over the mean).  Nothing can be dropped, so there is
+    no counter of dropped pairs.
     Scopes: ``moe_route`` (router, top-k, sorts, gathers, folds) and
-    ``moe_experts`` (the grouped products), in both passes.
+    ``moe_experts`` (the grouped products, :func:`grouped_dot`'s kernels
+    ``slt_gmm*``, and the element-wise work between them), in both
+    passes.
     """
     hidden_size: int
     intermediate_size: int
@@ -518,6 +534,10 @@ class HeldMoEMLP(nn.Module):
         self.sow("counters_sum", "moe_pairs_held", pairs)
         self.sow("counters_sum", "moe_overflow_passes",
                  (pairs > c).astype(jnp.float32))
+        self.sow("counters_sum", "moe_gmm_rows", sum(
+            live_rows(_clipped_sizes(sizes, lo, hi), hi - lo)
+            for lo, hi in ((0, c), (c, worst)) if lo < hi
+        ).astype(jnp.float32))
         self.sow("counters_max", "moe_load_max_over_mean",
                  jnp.max(sizes) * n_held / jnp.maximum(pairs, 1.0))
         # the kernels' casts to the compute type, and their transposes (the
@@ -525,14 +545,11 @@ class HeldMoEMLP(nn.Module):
         with jax.named_scope("moe_experts"):
             kernels = _ExpertBank(n_held, h, self.intermediate_size,
                                   self.dtype, name="experts")()
-        precision = (jax.lax.Precision.HIGHEST
-                     if self.dtype == jnp.float32 else None)
         if c < worst:
             out = both_passes(xt, weights.reshape(-1), kernels, plan,
-                              c, worst, precision)
+                              c, worst)
         else:
-            out = _pass(xt, weights.reshape(-1), kernels, plan, 0, worst,
-                        precision)
+            out = _pass(xt, weights.reshape(-1), kernels, plan, 0, worst)
         return out.reshape(b, s, h).astype(x.dtype)
 
 

@@ -287,18 +287,21 @@ def test_the_step_hands_back_what_the_expert_layers_counted():
     pipe, params, ids, out = _step_outputs("Mellum2_TINYSTORIES", TINY)
     got = out[4]
     assert {col: sorted(names) for col, names in got.items()} == {
-        "counters_sum": ["moe_overflow_passes", "moe_pairs_held"],
+        "counters_sum": ["moe_gmm_rows", "moe_overflow_passes",
+                         "moe_pairs_held"],
         "counters_max": ["moe_load_max_over_mean"]}
-    pairs, load = 0.0, 0.0
+    pairs, load, gmm_rows = 0.0, 0.0, 0.0
     for mb in range(ids.shape[1]):
         _, mut = pipe.full_model.apply({"params": params},
                                        ids[0, mb, :, :-1],
                                        mutable=list(COUNTER_FOLDS))
         count = sown_counters(mut)
         pairs += float(count["counters_sum"]["moe_pairs_held"])
+        gmm_rows += float(count["counters_sum"]["moe_gmm_rows"])
         load = max(load, float(
             count["counters_max"]["moe_load_max_over_mean"]))
     assert float(got["counters_sum"]["moe_pairs_held"][0]) == pairs > 0
+    assert float(got["counters_sum"]["moe_gmm_rows"][0]) == gmm_rows >= pairs
     # 4 of 8 experts held: the common pass has the worst case's rows, and
     # no call can overflow it
     assert float(got["counters_sum"]["moe_overflow_passes"][0]) == 0.0

@@ -16,6 +16,8 @@ import pytest
 
 from tests.conftest import bench_reference
 
+from split_learning_tpu.ops.grouped_matmul import row_tile
+from split_learning_tpu.parallel import expert
 from split_learning_tpu.parallel.expert import (
     HeldMoEMLP, MoEMLP, _fold, common_rows, ep_spec, moe_aux_loss,
     pass_plan, route_held,
@@ -260,6 +262,26 @@ def test_both_passes_match_the_reference_whatever_the_load(big, load):
                                    rtol=1e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("load", list(LOADS))
+def test_the_products_tiles_cover_the_pairs_and_a_tile_a_group_at_most(
+        big, load):
+    """``moe_gmm_rows``: visits times the row tile, both passes.  Every
+    held pair lies in a visited tile, and a group's end adds less than a
+    tile, so the counter stands between the pairs and the pairs plus a
+    tile a group and pass; a pass that is skipped adds nothing."""
+    x, params, pairs = big[load]
+    _, mut = _big_layer().apply({"params": _big_share(params)}, x[None],
+                                mutable=list(COUNTER_FOLDS))
+    count = sown_counters(mut)["counters_sum"]
+    c, worst, groups = 512, 2048, len(BIG["held"])
+    room = groups * row_tile(c)
+    if LOADS[load]:
+        room += groups * row_tile(worst - c)
+    assert pairs <= float(count["moe_gmm_rows"]) <= pairs + room
+    assert float(count["moe_gmm_rows"]) % min(row_tile(c),
+                                              row_tile(worst - c)) == 0
+
+
 def _gap(a, b):
     """Norm of the difference over the norm of ``b``, in float64."""
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
@@ -355,17 +377,23 @@ def test_rows_past_the_groups_may_hold_anything(layer, big, monkeypatch,
     def loss(p, x):
         return (model.apply({"params": p}, x) * w).sum()
 
-    want = jax.value_and_grad(loss, argnums=(0, 1))(share, x)
-    real = jax.lax.ragged_dot
+    real = expert.grouped_dot
     planted = []
 
-    def garbage_past_the_groups(lhs, rhs, group_sizes, **kw):
-        out = real(lhs, rhs, group_sizes, **kw)
+    def zeros_past_the_groups(lhs, rhs, group_sizes):
+        out = real(lhs, rhs, group_sizes)
+        live = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(live[:, None], out, 0.0)
+
+    def garbage_past_the_groups(lhs, rhs, group_sizes):
+        out = real(lhs, rhs, group_sizes)
         live = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
         planted.append(out.shape[0])
         return jnp.where(live[:, None], out, jnp.nan)
 
-    monkeypatch.setattr(jax.lax, "ragged_dot", garbage_past_the_groups)
+    monkeypatch.setattr(expert, "grouped_dot", zeros_past_the_groups)
+    want = jax.value_and_grad(loss, argnums=(0, 1))(share, x)
+    monkeypatch.setattr(expert, "grouped_dot", garbage_past_the_groups)
     got = jax.value_and_grad(loss, argnums=(0, 1))(share, x)
     # a product of the pass the case names was among them: T rows for
     # some 50 held pairs; 1,536 for the one pair past C
@@ -426,7 +454,8 @@ def test_no_pass_over_rows_has_the_worst_case_outside_the_overflow_branch(
     assert any(p == "gather" and s == (worst - c, h)
                for p, s in _rows_by_columns(jaxpr, None, []))
     found = _rows_by_columns(jaxpr, 1, [])
-    assert any(p.startswith("ragged_dot") for p, _ in found)
+    assert any(p == "pallas_call" for p, _ in found)
+    assert not any(p.startswith("ragged_dot") for p, _ in found)
     assert any(p == "gather" and s == (c, h) for p, s in found)
     # nothing of T * k rows, and nothing of more rows than C either
     for prim, shape in found:
