@@ -12,11 +12,14 @@ names; a new mixer or feed-forward is one module and one table entry.
 
 The mixers there are: grouped-query attention, no bias, rotary embedding
 (:class:`Attention`), ``full_attention`` or ``sliding_attention`` (a query
-at ``p`` sees keys ``p - window + 1 .. p``), each kind with its own RoPE.
-The feed-forwards: a ``dense`` SwiGLU, ``sparse`` experts of which this
-program may hold a share
-(:class:`~split_learning_tpu.parallel.expert.HeldMoEMLP`), and ``capacity``
-experts that drop what overflows
+at ``p`` sees keys ``p - window + 1 .. p``), each kind with its own RoPE;
+and ``latent_attention`` (:class:`LatentAttention`: keys and values
+expanded from one low-rank latent a token, a rotary part that all heads
+share).  The feed-forwards: a ``dense`` SwiGLU, ``sparse`` experts of which
+this program may hold a share
+(:class:`~split_learning_tpu.parallel.expert.HeldMoEMLP`),
+``sparse_shared`` (the same beside a shared SwiGLU that every token takes),
+and ``capacity`` experts that drop what overflows
 (:class:`~split_learning_tpu.parallel.expert.MoEMLP`).
 
 Split-layer contract: 1 = token embedding, 2..n+1 = decoder blocks,
@@ -46,9 +49,16 @@ from split_learning_tpu.models.split import (
 )
 # parallel/pipeline.py imports this package for ``build_model``, which
 # models/__init__.py binds before it imports this module
-from split_learning_tpu.parallel.expert import HeldMoEMLP, MoEMLP, swiglu
+from split_learning_tpu.parallel.expert import (
+    ExpertFFN, HeldMoEMLP, MoEMLP, swiglu,
+)
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+LATENT = "latent_attention"
+#: epsilon of the norm inside latent attention (``kv_a_layernorm``): the
+#: published implementation builds it with its class's default, not with
+#: the configuration's ``rms_norm_eps``
+LATENT_EPS = 1e-6
 #: Mellum-2's published ``rope_parameters`` (both kinds at theta 500,000)
 ROPE_PARAMETERS = {
     FULL: {"rope_type": "yarn", "rope_theta": 500000.0, "factor": 16.0,
@@ -111,7 +121,10 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: np.ndarray,
     cos = factor * jnp.cos(freqs)[None, :, None, :]
     sin = factor * jnp.sin(freqs)[None, :, None, :]
     if interleaved:
-        x1, x2 = x[..., 0::2], x[..., 1::2]
+        # the pairs by a reshape: ``x[..., 0::2]`` is a gather (and its
+        # gradient a scatter-add) in the compiled step
+        pairs = x.reshape(*x.shape[:-1], -1, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
         rot = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                         axis=-1).reshape(x.shape)
     else:
@@ -124,6 +137,29 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: np.ndarray,
 # --------------------------------------------------------------------------
 # the mixer and the block
 # --------------------------------------------------------------------------
+
+def _causal_attention(q, k, v, dtype, use_flash: bool, flash_block: int,
+                      window: int | None = None):
+    """Causal attention over whole rows or the last ``window`` keys: ``q``
+    (B, S, H, D), ``k`` (B, S, G, D), ``v`` (B, S, G, Dv), a key-value head
+    for every ``H / G`` query heads; the result reshapes to (B, S, H * Dv).
+    The Pallas kernel, or (small sizes only; the kernel's parity oracle)
+    an einsum with the mask applied to the scores."""
+    if use_flash:
+        from split_learning_tpu.ops.flash_attention import flash_attention
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=flash_block, block_k=flash_block)
+    b, s, h, hd = q.shape
+    groups, pos = k.shape[2], jnp.arange(s)
+    qg = q.reshape(b, s, groups, h // groups, hd)
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k) / np.sqrt(hd)
+    seen = pos[None, :] <= pos[:, None]
+    if window is not None:
+        seen &= pos[None, :] > pos[:, None] - window
+    probs = nn.softmax(jnp.where(
+        seen, scores.astype(jnp.float32), -1e30)).astype(dtype)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
+
 
 class Attention(nn.Module):
     """Causal grouped-query attention with the RoPE of its ``kind`` of
@@ -182,26 +218,81 @@ class Attention(nn.Module):
                 out = ring_attention(
                     q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
                     axis_name=self.seq_axis, causal=True)
-            elif self.use_flash:
-                from split_learning_tpu.ops.flash_attention import (
-                    flash_attention,
-                )
-                out = flash_attention(
-                    q, k, v, causal=True, window=window,
-                    block_q=self.flash_block, block_k=self.flash_block)
             else:
-                qg = q.reshape(b, s, self.num_kv_heads, rep, hd)
-                scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k) \
-                    / np.sqrt(hd)
-                seen = pos[None, :] <= pos[:, None]
-                if window is not None:
-                    seen &= pos[None, :] > pos[:, None] - window
-                probs = nn.softmax(jnp.where(
-                    seen, scores.astype(jnp.float32), -1e30)).astype(
-                        self.dtype)
-                out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
+                out = _causal_attention(q, k, v, self.dtype, self.use_flash,
+                                        self.flash_block, window)
         return dense(self.hidden_size, name="o_proj")(
             out.reshape(b, s, self.num_heads * hd))
+
+
+class LatentAttention(nn.Module):
+    """Causal multi-head latent attention (DeepSeek-V2/V3's layer, under
+    the published keys' names), over all earlier keys.
+
+    ``q = x W_q``, a head ``qk_nope_head_dim + qk_rope_head_dim`` wide
+    (``q_lora_rank`` null: no low-rank step on the queries).
+    ``[c, k_rope] = x W_kva``: a latent of ``kv_lora_rank`` and ONE rotary
+    key of ``qk_rope_head_dim`` a token, for all heads.  ``[k_nope, v] =
+    RMSNorm(c) W_kvb``, a head ``qk_nope_head_dim + v_head_dim`` wide.
+    RoPE (``rope_theta`` over ``qk_rope_head_dim``, no scaling) turns the
+    rotary part of every query head and the one rotary key.  Head ``i``
+    scores ``(q_nope_i . k_nope_i + q_rope_i . k_rope) / sqrt(nope +
+    rope)``; its result is ``v_head_dim`` wide.
+
+    The kernel (``ops/flash_attention.py``, ``use_flash``) is handed the
+    concatenated form: keys ``nope + rope`` wide with the rotary key copied
+    to every head, values ``v_head_dim`` wide.  Else the einsum, its
+    parity oracle at small sizes.  Scopes, in both passes: ``mla_latent``
+    (the projections, the latent's norm, the rotary part and the shaping of
+    the kernel's operands, ``o_proj``) and ``attn_full`` around the scores
+    and values, as :class:`Attention` has it.
+    """
+    hidden_size: int
+    num_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    use_flash: bool = False
+    flash_block: int = 512
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, _ = x.shape
+        h, nope, rot = self.num_heads, self.qk_nope_head_dim, \
+            self.qk_rope_head_dim
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        with jax.named_scope("mla_latent"):
+            q = dense(h * (nope + rot), name="q_proj")(x).reshape(
+                b, s, h, nope + rot)
+            kva = dense(self.kv_lora_rank + rot,
+                        name="kv_a_proj_with_mqa")(x)
+            latent = nn.RMSNorm(epsilon=LATENT_EPS, dtype=self.dtype,
+                                name="kv_a_layernorm")(
+                kva[..., :self.kv_lora_rank])
+            kv = dense(h * (nope + self.v_head_dim), name="kv_b_proj")(
+                latent).reshape(b, s, h, nope + self.v_head_dim)
+            inv_freq, pos = rope_inv_freq(rot, self.rope_theta), \
+                jnp.arange(s)
+            # DeepSeek stores the rotary part's pairs as (2i, 2i + 1)
+            q = jnp.concatenate([
+                q[..., :nope],
+                rope(q[..., nope:], pos, inv_freq, interleaved=True)], -1)
+            k_rope = rope(
+                jnp.expand_dims(kva[..., self.kv_lora_rank:], 2), pos,
+                inv_freq, interleaved=True)
+            k = jnp.concatenate([
+                kv[..., :nope],
+                jnp.broadcast_to(k_rope, (b, s, h, rot))], -1)
+        with jax.named_scope("attn_full"):
+            out = _causal_attention(q, k, kv[..., nope:], self.dtype,
+                                    self.use_flash, self.flash_block)
+        with jax.named_scope("mla_latent"):
+            return dense(self.hidden_size, name="o_proj")(
+                out.reshape(b, s, h * self.v_head_dim))
 
 
 class DecoderBlock(nn.Module):
@@ -235,13 +326,26 @@ def _experts(layer) -> Callable:
     return apply
 
 
+def _experts_and_shared(x, shared_intermediate_size: int, **kw):
+    """Held experts beside a shared SwiGLU (the caller's submodule
+    ``shared_experts``) that every token takes: computed whole here, by
+    every chip of an expert-parallel job over its own rows alike, and
+    added to the held experts' partial sum.  Scope ``moe_shared``."""
+    with jax.named_scope("moe_shared"):
+        shared = ExpertFFN(shared_intermediate_size, kw.get(
+            "dtype", jnp.float32), name="shared_experts")(x)
+    return _experts(HeldMoEMLP)(x, **kw) + shared
+
+
 # The one place where a layer's kind becomes code.  A mixer is a module
 # class (the block names it ``attention``); a feed-forward a function of the
 # normed state that runs in the block's scope.  Both take that kind's
 # keywords from the builder.
 MIXERS = {FULL: functools.partial(Attention, kind=FULL),
-          SLIDING: functools.partial(Attention, kind=SLIDING)}
+          SLIDING: functools.partial(Attention, kind=SLIDING),
+          LATENT: LatentAttention}
 FEED_FORWARDS = {"dense": swiglu, "sparse": _experts(HeldMoEMLP),
+                 "sparse_shared": _experts_and_shared,
                  "capacity": _experts(MoEMLP)}
 
 
@@ -325,6 +429,13 @@ def tinyllama_moe_tinystories(dtype=jnp.float32, num_experts: int = 8,
     return _llama_specs(dtype=dtype, num_experts=num_experts, **kw)
 
 
+def _held(experts_held) -> tuple | None:
+    """``experts_held`` as ids: a count means experts ``0 .. n - 1``."""
+    if isinstance(experts_held, int):
+        return tuple(range(experts_held))
+    return None if experts_held is None else tuple(experts_held)
+
+
 @register_model("Mellum2_TINYSTORIES")
 def mellum2_tinystories(
         vocab_size: int = 98304, hidden_size: int = 2304,
@@ -353,8 +464,6 @@ def mellum2_tinystories(
         FULL if i % 4 == 3 else SLIDING for i in range(num_hidden_layers))
     if len(kinds) != num_hidden_layers:
         raise ValueError(f"{num_hidden_layers} layers, layer_types {kinds}")
-    held = tuple(range(experts_held)) if isinstance(experts_held, int) \
-        else (tuple(experts_held) if experts_held is not None else None)
     attention = dict(
         hidden_size=hidden_size, num_heads=num_attention_heads,
         num_kv_heads=num_key_value_heads, head_dim=head_dim,
@@ -365,6 +474,73 @@ def mellum2_tinystories(
         {FULL: attention, SLIDING: dict(attention, window=sliding_window)},
         {"sparse": dict(intermediate_size=moe_intermediate_size,
                         num_experts=num_experts, k=num_experts_per_tok,
-                        held=held, dtype=dtype)},
+                        held=_held(experts_held), dtype=dtype)},
+        vocab_size=vocab_size, hidden_size=hidden_size, eps=rms_norm_eps,
+        dtype=dtype)
+
+
+@register_model("Moonlight_TINYSTORIES")
+def moonlight_tinystories(
+        vocab_size: int = 163840, hidden_size: int = 2048,
+        num_attention_heads: int = 16, num_hidden_layers: int = 27,
+        first_k_dense_replace: int = 1, moe_layer_freq: int = 1,
+        intermediate_size: int = 11264, moe_intermediate_size: int = 1408,
+        n_routed_experts: int = 64, n_shared_experts: int = 2,
+        num_experts_per_tok: int = 6, scoring_func: str = "sigmoid",
+        topk_method: str = "noaux_tc", norm_topk_prob: bool = True,
+        routed_scaling_factor: float = 2.446, n_group: int = 1,
+        topk_group: int = 1, kv_lora_rank: int = 512,
+        q_lora_rank: int | None = None, qk_nope_head_dim: int = 128,
+        qk_rope_head_dim: int = 64, v_head_dim: int = 128,
+        rope_theta: float = 50000.0, rms_norm_eps: float = 1e-5,
+        experts_held: int | tuple | None = None, use_flash: bool = False,
+        flash_block: int = 512, dtype=jnp.float32) -> tuple:
+    """Moonlight-16B-A3B geometry (``model_type`` ``deepseek_v3``) under
+    the keys of the published configuration
+    (https://huggingface.co/moonshotai/Moonlight-16B-A3B/blob/main/config.json);
+    input (B, S) int32 token ids, output (B, S, vocab) next-token logits.
+    Latent attention in every block (:class:`LatentAttention`).  The first
+    ``first_k_dense_replace`` blocks have a dense SwiGLU of
+    ``intermediate_size``; of the others every ``moe_layer_freq``-th has
+    ``n_routed_experts`` experts of ``moe_intermediate_size`` beside
+    ``n_shared_experts`` shared ones (one SwiGLU of that many times the
+    width): ``num_experts_per_tok`` a token, chosen by ``scoring_func`` of
+    the router's logits plus the bias ``e_score_correction_bias``
+    (``topk_method`` ``noaux_tc``), weighted by the scores alone,
+    renormalized and multiplied by ``routed_scaling_factor``.
+    ``experts_held`` as in ``Mellum2_TINYSTORIES``.  What has no module
+    here is refused: a low-rank step on the queries (``q_lora_rank``), a
+    choice limited to groups of experts (``n_group`` over 1) or made
+    without the bias (a ``topk_method`` other than ``noaux_tc``), weights
+    that are not renormalized, a stack without routed or without shared
+    experts."""
+    if q_lora_rank is not None or n_group != 1 or topk_group != 1 \
+            or not norm_topk_prob or topk_method != "noaux_tc" \
+            or n_routed_experts < 1 or n_shared_experts < 1:
+        raise ValueError(
+            f"no module for q_lora_rank={q_lora_rank}, n_group={n_group}, "
+            f"topk_group={topk_group}, norm_topk_prob={norm_topk_prob}, "
+            f"topk_method={topk_method!r}, "
+            f"n_routed_experts={n_routed_experts}, "
+            f"n_shared_experts={n_shared_experts}")
+    experts = dict(
+        intermediate_size=moe_intermediate_size,
+        num_experts=n_routed_experts, k=num_experts_per_tok,
+        held=_held(experts_held), scoring=scoring_func, score_bias=True,
+        factor=routed_scaling_factor, dtype=dtype)
+    return decoder_specs(
+        [(LATENT, "sparse_shared" if i >= first_k_dense_replace
+          and i % moe_layer_freq == 0 else "dense")
+         for i in range(num_hidden_layers)],
+        {LATENT: dict(
+            hidden_size=hidden_size, num_heads=num_attention_heads,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            rope_theta=rope_theta, use_flash=use_flash,
+            flash_block=flash_block, dtype=dtype)},
+        {"dense": dict(intermediate_size=intermediate_size, dtype=dtype),
+         "sparse_shared": dict(
+             experts, shared_intermediate_size=(
+                 n_shared_experts * moe_intermediate_size))},
         vocab_size=vocab_size, hidden_size=hidden_size, eps=rms_norm_eps,
         dtype=dtype)

@@ -148,7 +148,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros(q.shape, jnp.float32)
+    acc0 = jnp.zeros(o_ref.shape[1:], jnp.float32)
 
     def body(kb, carry, masked):
         m, l, acc = carry
@@ -180,13 +180,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
                     block_q: int, block_k: int, window, rep: int):
     """(BH, S, D) flattened forward via pallas_call -> (o, lse); ``k``
-    and ``v`` are ``(BH / rep, S, D)``.
+    is ``(BH / rep, S, D)`` and ``v`` ``(BH / rep, S, Dv)``: the scores
+    are taken over ``D``, the result is ``Dv`` wide.
 
     ``lse`` (and the backward's ``delta``) are per-row values kept as
     ``(BH, S, 1)`` columns: a ``(block_q, 1)`` block is legal on TPU
     where a ``(1, block_q)`` slice of a ``(BH, S)`` array is not, and
     it is the shape the kernels' row statistics already have."""
     bh, s, d = q.shape
+    dv = v.shape[-1]
     scale = 1.0 / np.sqrt(d)
     grid = (bh, s // block_q)
     precision = _pick_precision(q.dtype)
@@ -196,15 +198,15 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool,
                                window=window)
     return pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, s, d), lambda b, i: (b // rep, 0, 0)),
-            pl.BlockSpec((1, s, d), lambda b, i: (b // rep, 0, 0)),
+            pl.BlockSpec((1, s, dv), lambda b, i: (b // rep, 0, 0)),
         ],
-        out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+        out_specs=[pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))],
         interpret=interpret,
         name="slt_flash_fwd",
@@ -309,6 +311,7 @@ def _flash_bwd_rule(causal, interpret, block_q, block_k, window, rep, res,
                     do):
     q, k, v, o, lse = res
     bh, s, d = q.shape
+    d_v = v.shape[-1]
     scale = 1.0 / np.sqrt(d)
     precision = _pick_precision(q.dtype)
     # delta = rowsum(dO * O): cheap elementwise pre-pass, XLA fuses it
@@ -320,30 +323,33 @@ def _flash_bwd_rule(causal, interpret, block_q, block_k, window, rep, res,
     # dKV: one instance a (key-value head, key block, query head of the
     # group); the float32 tiles stay resident over the last axis
     head = lambda b, j, r: (b * rep + r, 0, 0)         # noqa: E731
-    kv_block = pl.BlockSpec((1, block_k, d), lambda b, j, r: (b, j, 0))
+    k_block = pl.BlockSpec((1, block_k, d), lambda b, j, r: (b, j, 0))
+    v_block = pl.BlockSpec((1, block_k, d_v), lambda b, j, r: (b, j, 0))
     as_row = lambda t: t.reshape(bh, 1, s)             # noqa: E731
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **static),
         out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
         grid=(bh // rep, s // block_k, rep),
-        in_specs=[pl.BlockSpec((1, s, d), head), kv_block, kv_block,
-                  pl.BlockSpec((1, s, d), head),
+        in_specs=[pl.BlockSpec((1, s, d), head), k_block, v_block,
+                  pl.BlockSpec((1, s, d_v), head),
                   pl.BlockSpec((1, 1, s), head),
                   pl.BlockSpec((1, 1, s), head)],
-        out_specs=[kv_block, kv_block],
+        out_specs=[k_block, v_block],
         interpret=interpret,
         name="slt_flash_bwd_dkv",
     )(q, k, v, do, as_row(lse), as_row(delta))
 
-    full = pl.BlockSpec((1, s, d), lambda b, i: (b // rep, 0, 0))
+    kv_head = lambda b, i: (b // rep, 0, 0)            # noqa: E731
     q_block = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    do_block = pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0))
     row_q = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **static),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(bh, s // block_q),
-        in_specs=[q_block, full, full, q_block, row_q, row_q],
+        in_specs=[q_block, pl.BlockSpec((1, s, d), kv_head),
+                  pl.BlockSpec((1, s, d_v), kv_head), do_block, row_q, row_q],
         out_specs=q_block,
         interpret=interpret,
         name="slt_flash_bwd_dq",
@@ -359,9 +365,12 @@ def flash_attention(q, k, v, causal: bool = False,
                     interpret: bool | None = None,
                     block_q: int = 128, block_k: int = 128,
                     window: int | None = None) -> jnp.ndarray:
-    """Fused attention over ``q`` (B, S, H, D) and ``k``, ``v``
-    (B, S, KV, D) with ``H`` a multiple of ``KV``: query head ``h`` reads
-    key-value head ``h // (H / KV)``.
+    """Fused attention over ``q`` (B, S, H, D), ``k`` (B, S, KV, D) and
+    ``v`` (B, S, KV, Dv) with ``H`` a multiple of ``KV``: query head ``h``
+    reads key-value head ``h // (H / KV)``.  The scores are taken over
+    ``D`` and scaled by ``1 / sqrt(D)``; the result is (B, S, H, Dv), and
+    ``Dv`` may differ from ``D`` (latent attention: a rotary part beside
+    the keys' own, none beside the values).
 
     ``window`` (with ``causal``): a query at ``p`` sees keys
     ``p - window + 1 .. p``.  ``interpret=None`` runs the Pallas
@@ -371,7 +380,7 @@ def flash_attention(q, k, v, causal: bool = False,
     interpret = resolve_interpret(interpret)
     b, s, h, d = q.shape
     kv = k.shape[2]
-    if h % kv or v.shape != k.shape:
+    if h % kv or v.shape[:3] != k.shape[:3] or k.shape[3] != d:
         raise ValueError(f"{h} query heads over key/value {k.shape}, "
                          f"{v.shape}")
     if window is not None and (not causal or window < 1):
@@ -381,7 +390,7 @@ def flash_attention(q, k, v, causal: bool = False,
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(s, block_k)
     to_bhsd = lambda t: t.transpose(0, 2, 1, 3).reshape(  # noqa: E731
-        b * t.shape[2], s, d)
+        b * t.shape[2], s, t.shape[3])
     out = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v), causal, interpret,
                  block_q, block_k, window, h // kv)
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, s, v.shape[3]).transpose(0, 2, 1, 3)

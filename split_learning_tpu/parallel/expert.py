@@ -16,8 +16,8 @@ hand-written collectives:
   NCCL MoE implementation would issue by hand, but fused and
   overlapped by the compiler.
 
-Routing math: softmax router in fp32, top-k experts per token with
-renormalized gate weights, tokens over capacity dropped (their combine
+Routing math (:class:`MoEMLP`): softmax router in fp32, top-k experts per
+token with renormalized gate weights, tokens over capacity dropped (their combine
 weight is zero, so they pass through the residual unchanged — standard
 Switch semantics).  The load-balance auxiliary loss is sown into the
 ``intermediates`` collection; :func:`moe_aux_loss` or the bundled
@@ -197,14 +197,31 @@ def common_rows(t: int, k: int, n_held: int, e: int) -> int:
     return min(min(k, n_held) * t, -(-COMMON_SHARE * t * k * n_held // e))
 
 
-def route_held(probs: jnp.ndarray, k: int, first: int, n_held: int):
+#: A router's scoring function by its published name (``scoring_func``):
+#: the scores of the logits; whether the load-balancing term first divides
+#: them by their sum over the experts (a softmax's already sum to one); and
+#: what guards the division by the chosen scores' sum (DeepSeek-V3's 1e-20:
+#: chosen sigmoids may all underflow, chosen softmax shares cannot).
+SCORING = {"softmax": (functools.partial(jax.nn.softmax, axis=-1), False, 0.0),
+           "sigmoid": (jax.nn.sigmoid, True, 1e-20)}
+
+
+def route_held(logits: jnp.ndarray, k: int, first: int, n_held: int,
+               scoring: str = "softmax", bias=None, factor: float = 1.0):
     """Top-``k`` routing over ALL experts, and the plan for the experts
     ``first .. first + n_held - 1`` that live here.
 
-    Returns ``(weights, plan, aux)``: ``weights`` (T, k) are the chosen
-    experts' probabilities renormalized over the k chosen; ``aux`` is the
-    load-balancing term ``E * sum_e f_e P_e`` over all experts (``f_e``
-    the pairs routed to ``e`` over T, ``P_e`` the mean probability);
+    ``scoring`` names the function that turns the router's ``logits``
+    (T, E) into scores (:data:`SCORING`).  The ``k`` experts of a token are
+    those with the largest ``score + bias`` (``bias`` (E,), a buffer that
+    no gradient reaches; ``None``: the scores alone); their weights are
+    the scores WITHOUT the bias, renormalized over the k chosen, times
+    ``factor``.
+
+    Returns ``(weights, plan, aux)``: ``weights`` (T, k) as above; ``aux``
+    is the load-balancing term ``E * sum_e f_e P_e`` over all experts
+    (``f_e`` the pairs routed to ``e`` over T, ``P_e`` the mean of the
+    scores, each token's divided by their sum over the experts);
     ``plan`` holds the (token, choice) pairs sorted by held expert, the
     pairs of absent experts last.  Sorted row ``r`` is where a pair's
     token is multiplied; no buffer has all the rows: a pass
@@ -216,11 +233,23 @@ def route_held(probs: jnp.ndarray, k: int, first: int, n_held: int):
       ``group_sizes``, itself at most ``min(k, n_held) * T``);
     * ``group_sizes`` (n_held,): pairs of each held expert, in order.
     """
-    t, e = probs.shape
-    top_p, top_i = jax.lax.top_k(probs, k)
-    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    t, e = logits.shape
+    score_fn, divide, guard = SCORING[scoring]
+    scores = score_fn(logits)
+    if bias is None:
+        top_s, top_i = jax.lax.top_k(scores, k)
+    else:
+        _, top_i = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias)[None, :], k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    chosen = jnp.sum(top_s, axis=-1, keepdims=True)
+    weights = top_s / (chosen + guard if guard else chosen)
+    if factor != 1.0:
+        weights = weights * factor
     counts = jnp.zeros((e,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
-    aux = e * jnp.sum(counts / t * jnp.mean(probs, axis=0))
+    shares = scores / jnp.sum(scores, axis=-1, keepdims=True) if divide \
+        else scores
+    aux = e * jnp.sum(counts / t * jnp.mean(shares, axis=0))
 
     local = top_i.reshape(-1) - first
     held = (local >= 0) & (local < n_held)
@@ -457,7 +486,14 @@ class HeldMoEMLP(nn.Module):
     its OWN experts give, and drops nothing.
 
     The router is ``num_experts`` wide and picks ``k`` of ALL experts,
-    their weights renormalized over the k chosen.  Of the (token, expert)
+    their weights renormalized over the k chosen (:func:`route_held`:
+    ``scoring`` names the scores' function, ``factor`` multiplies the
+    weights; with ``score_bias`` the choice is by ``score + bias``, the
+    weights by the score alone.  The bias, ``e_score_correction_bias``
+    (``num_experts``,), is no parameter: it lives in ``batch_stats``, so no
+    gradient and no weight decay reach it, and the step, FedAvg and the
+    checkpoint carry it as they carry a BatchNorm's statistics; nothing
+    here moves it).  Of the (token, expert)
     pairs, those whose expert is one of ``held`` (consecutive ids; ``None``:
     all) are sorted by expert, their rows gathered, run through the
     experts' SwiGLU as grouped matrix products, scaled by their weights
@@ -502,6 +538,9 @@ class HeldMoEMLP(nn.Module):
     num_experts: int = 8
     k: int = 2
     held: tuple | None = None
+    scoring: str = "softmax"
+    score_bias: bool = False
+    factor: float = 1.0
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -524,8 +563,12 @@ class HeldMoEMLP(nn.Module):
                               dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST,
                               name="router")(xt.astype(jnp.float32))
+            bias = self.variable(
+                "batch_stats", "e_score_correction_bias", jnp.zeros,
+                (self.num_experts,), jnp.float32).value \
+                if self.score_bias else None
             weights, plan, aux = route_held(
-                jax.nn.softmax(logits, axis=-1), k, first, n_held)
+                logits, k, first, n_held, self.scoring, bias, self.factor)
         sizes = plan["group_sizes"]
         worst = min(k, n_held) * t
         c = common_rows(t, k, n_held, self.num_experts)

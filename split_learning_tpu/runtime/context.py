@@ -749,8 +749,14 @@ class MeshContext(TrainContext):
 
         laps.lap("fedavg")
         weights = jnp.asarray(np.maximum(consumed, 1).astype(np.float32))
+        if not self.cfg.learning.opt_resident:
+            # nothing reads the optimizer's state after the last step:
+            # held to this function's end, its two trees stood beside
+            # the boundary's copies of the parameters
+            del opt_c
         avg_params_c = fedavg(params_c, weights)
         avg_stats_c = fedavg(stats_c, weights)
+        del params_c, stats_c       # the mean takes the columns' place
         ret_params = strip(avg_params_c)
         ret_stats = strip(avg_stats_c)
         laps.stop()

@@ -18,7 +18,7 @@ from typing import Any
 
 from split_learning_tpu.config import Config
 from split_learning_tpu.runtime.checkpoint import (
-    load_checkpoint, save_checkpoint,
+    load_checkpoint, release_freed_memory, save_checkpoint,
 )
 from split_learning_tpu.runtime.context import TrainContext
 from split_learning_tpu.runtime.log import Logger
@@ -45,10 +45,20 @@ class TrainResult:
 
 
 def _write_checkpoint(tracer, parent: str | None, r: int, directory,
-                      model_key: str, params, stats) -> None:
+                      model_key: str, held: list) -> None:
     """Round ``r``'s save, on the pool's thread: one ``checkpoint_write``
     span over the whole of it, a child of the round's ``checkpoint``
-    span on the loop's thread (hence the explicit parent)."""
+    span on the loop's thread (hence the explicit parent).
+
+    ``held`` is ``[params, stats]``; the trees are taken out of it and let
+    go of HERE, before this returns: once the loop has waited for the
+    write, no thread but the loop's holds them.  A worker that still held
+    its arguments freed the round's tree while the next round allocated:
+    in a round that waited 0.2 s for the write the device's peak read
+    15.72 GiB against 15.01, with this 15.02 (PERF.md section 6,
+    PR 34)."""
+    params, stats = held
+    held.clear()
     with tracer.span("checkpoint_write", parent=parent, round=r):
         save_checkpoint(directory, model_key, params, stats,
                         round_idx=r + 1, tracer=tracer)
@@ -81,6 +91,7 @@ def run_training(cfg: Config, ctx: TrainContext,
             start_round = ck["round_idx"]
             logger.info(f"Loaded checkpoint at round {start_round}.",
                         "green")
+        del ck      # or the host keeps the loaded tree for the whole run
     if params is None:
         variables = ctx.init_variables()
         params = variables["params"]
@@ -179,7 +190,10 @@ def run_training(cfg: Config, ctx: TrainContext,
                     round_span.end()
                     tracer.flush()
                     continue
-                prev_params, prev_stats = params, stats
+                # the round's input, kept for the rollback below and no
+                # longer: held into the next round it was one more copy
+                # of the tree on the device than training needs
+                prev = (params, stats)
                 params, stats = outcome.params, outcome.stats
                 if outcome.validate and cfg.checkpoint.validate:
                     with timer.phase("validate"), \
@@ -197,10 +211,11 @@ def run_training(cfg: Config, ctx: TrainContext,
                         # rather than training on from garbage
                         logger.error(f"Round {r}: Training failed! "
                                      f"(validation loss exploded)")
-                        params, stats = prev_params, prev_stats
+                        params, stats = prev
                 else:
                     logger.info(f"Round {r}: samples={outcome.num_samples} "
                                 f"({wall:.1f}s)", "green")
+                del prev
                 if rec.ok and cfg.checkpoint.save:
                     with timer.phase("checkpoint"), \
                             tracer.span("checkpoint", round=r) as ck_span:
@@ -209,7 +224,7 @@ def run_training(cfg: Config, ctx: TrainContext,
                         ck_future = ck_pool.submit(
                             _write_checkpoint, tracer, ck_span.id, r,
                             cfg.checkpoint.directory, cfg.model_key,
-                            params, stats)
+                            [params, stats])
                 history.append(rec)
                 logger.metric(kind="round", **dataclasses.asdict(rec),
                               phases=timer.summary(),
@@ -249,6 +264,7 @@ def run_training(cfg: Config, ctx: TrainContext,
         if ck_future is not None:
             ck_future.result()  # the last checkpoint must be durable
         ck_pool.shutdown(wait=True)
+        release_freed_memory()
         if own_tracer:
             ctx.tracer = None
             tracer.close()

@@ -1,9 +1,12 @@
-"""The benchmark's token-model cell at toy size, through the harness's own
+"""The benchmark's token-model cells at toy size, through the harness's own
 entry point on the CPU: ``mellum2_12b_c3.round`` (windowed and full
 attention through the interpreted flash kernel, a held share of experts,
-the load-balancing term, AdamW, FedAvg, validation, checkpoint) comes out
-``correct`` against its plain reference, and the expert layer's counters
-reach the round records and the result line."""
+the load-balancing term, AdamW, FedAvg, validation, checkpoint) and
+``moonlight_16b_c3.round`` (latent attention through the same kernels at
+two widths, a dense first block, a shared expert, sigmoid routing with a
+bias that rides in ``batch_stats``) come out ``correct`` against their
+plain references, and the expert layer's counters reach the round records
+and the result line."""
 
 import json
 import pathlib
@@ -12,7 +15,7 @@ import sys
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
-CELL = "mellum2_12b_c3.round"
+CELLS = ["mellum2_12b_c3.round", "moonlight_16b_c3.round"]
 
 
 @pytest.fixture()
@@ -31,12 +34,13 @@ def run_cell(monkeypatch):
     yield module
     context._GLOBAL_STEP_CACHE.clear()
     for name in ("run_cell", "compare", "traffic", "program_trace",
-                 "trace_reduce", "mixer_trace"):
+                 "trace_reduce", "mixer_trace", "mla_trace"):
         sys.modules.pop(name, None)
 
 
-def test_toy_rehearsal_of_the_token_cell_is_correct(run_cell, capsys):
-    assert run_cell.main(["--workload", CELL, "--seed", "3000000019",
+@pytest.mark.parametrize("cell", CELLS)
+def test_toy_rehearsal_of_the_token_cell_is_correct(run_cell, capsys, cell):
+    assert run_cell.main(["--workload", cell, "--seed", "3000000019",
                           "--seconds", "1", "--trace", "1"]) == 0
     captured = capsys.readouterr()
     out = json.loads(captured.out.strip().splitlines()[-1])

@@ -200,3 +200,84 @@ def test_window_needs_causal():
     q, k, v = _qkv(jax.random.key(0), s=16)
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, k, v, causal=False, window=4)
+
+
+# -- scores over one width, values over another (latent attention) -------------
+
+def _qkv_two_widths(key, b=2, s=32, h=4, kv=4, d=24, dv=16):
+    kq, kk, kv_ = jax.random.split(key, 3)
+    return (jax.random.normal(kq, (b, s, h, d)),
+            jax.random.normal(kk, (b, s, kv, d)),
+            jax.random.normal(kv_, (b, s, kv, dv)))
+
+
+def _dense_two_widths(q, k, v, window=None):
+    """Causal softmax attention with ``1 / sqrt(score width)``, a query
+    head reading key-value head ``h // rep``."""
+    import jax.numpy as jnp
+    rep = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
+    s = q.shape[1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        precision="highest") / np.sqrt(q.shape[-1])
+    pos = jnp.arange(s)
+    seen = pos[None, :] <= pos[:, None]
+    if window is not None:
+        seen &= pos[None, :] > pos[:, None] - window
+    probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v, precision="highest")
+
+
+@pytest.mark.parametrize("kv,window,d,dv", [
+    (4, None, 24, 16), (4, None, 16, 24), (2, None, 24, 16),
+    (2, 8, 24, 16)], ids=["wide_scores", "wide_values", "grouped",
+                          "grouped_window"])
+def test_unequal_score_and_value_widths_forward_and_all_gradients(
+        kv, window, d, dv):
+    """The three kernels with scores over ``d`` and values over ``dv``:
+    the result is ``dv`` wide, the scale is ``1 / sqrt(d)``, and ``dq``,
+    ``dk`` (``d`` wide) and ``dv`` match the dense oracle's."""
+    q, k, v = _qkv_two_widths(jax.random.key(7), kv=kv, d=d, dv=dv)
+    w = jax.random.normal(jax.random.key(8), (*q.shape[:3], dv))
+    out = flash_attention(q, k, v, causal=True, window=window, block_q=8,
+                          block_k=16)
+    assert out.shape == (*q.shape[:3], dv)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_dense_two_widths(q, k, v, window)),
+        rtol=2e-5, atol=2e-5)
+    g1 = jax.grad(lambda *a: (flash_attention(
+        *a, causal=True, window=window, block_q=8, block_k=16) * w).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(lambda *a: (_dense_two_widths(*a, window) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-4)
+
+
+def test_keys_of_another_width_than_the_queries_are_refused():
+    q, k, v = _qkv_two_widths(jax.random.key(9))
+    with pytest.raises(ValueError, match="query heads over key/value"):
+        flash_attention(q, k[..., :16], v, causal=True)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_the_latent_kernels_lower_for_tpu_at_the_cells_widths(kernel):
+    """Scores over 192 (no multiple of the 128 lanes) and values over
+    128, rows of 4,096 in blocks of 512, bfloat16: forward and backward
+    kernels lower natively (``interpret=False``)."""
+    import jax.numpy as jnp
+    shape = lambda d: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, 4096, 16, d), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               block_q=512, block_k=512)
+    f = fwd if kernel == "fwd" else jax.grad(
+        lambda *a: fwd(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    text = jax.jit(f).trace(shape(192), shape(192), shape(128)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    names = ["slt_flash_fwd"] if kernel == "fwd" else [
+        "slt_flash_bwd_dq", "slt_flash_bwd_dkv"]
+    assert all(name in text for name in names)

@@ -121,6 +121,19 @@ BLOCK_PATHS = {
         {**TINY, "num_hidden_layers": 1, "layer_types": ("full_attention",)},
         ATTENTION + NORMS + ["moe/router/kernel"]
         + [f"moe/experts/{p}" for p in SWIGLU]),
+    # one sparse block: latent attention, held experts, a shared expert
+    "Moonlight_TINYSTORIES": (
+        dict(vocab_size=128, hidden_size=64, num_attention_heads=2,
+             num_hidden_layers=1, first_k_dense_replace=0,
+             moe_intermediate_size=16, n_routed_experts=4,
+             num_experts_per_tok=2, kv_lora_rank=16, qk_nope_head_dim=8,
+             qk_rope_head_dim=4, v_head_dim=8, experts_held=2),
+        [f"attention/{p}" for p in (
+            "q_proj/kernel", "kv_a_proj_with_mqa/kernel",
+            "kv_a_layernorm/scale", "kv_b_proj/kernel", "o_proj/kernel")]
+        + NORMS + ["moe/router/kernel"]
+        + [f"moe/experts/{p}" for p in SWIGLU]
+        + [f"shared_experts/{p}" for p in SWIGLU]),
 }
 
 

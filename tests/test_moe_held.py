@@ -126,9 +126,8 @@ def test_no_pair_is_dropped_when_every_token_picks_the_same_expert(
     want, _ = _ref_layer(params, x.reshape(T, H), (2, 3))
     np.testing.assert_allclose(np.asarray(y).reshape(T, H),
                                np.asarray(want), rtol=1e-5, atol=1e-6)
-    probs = jax.nn.softmax(_mm("td,de->te", x.reshape(T, H),
-                               params["router"]["kernel"]))
-    _, plan, _ = route_held(probs, K, 2, 2)
+    _, plan, _ = route_held(_mm("td,de->te", x.reshape(T, H),
+                                params["router"]["kernel"]), K, 2, 2)
     assert int(plan["group_sizes"][1]) == T      # every token, none lost
     assert int(plan["held"].sum()) == pairs
     # the held pairs' sorted rows are the first ``pairs``, each once
@@ -337,8 +336,9 @@ def test_the_fold_in_bfloat16_is_within_one_more_rounding_of_the_sum(
     read: 0.0021 and 0.0031)."""
     x, params, _ = big[load]
     t, h, k, held = (BIG[n] for n in ("t", "h", "k", "held"))
-    probs = jax.nn.softmax(_mm("td,de->te", x, params["router"]["kernel"]))
-    weights, plan, _ = route_held(probs, k, held[0], len(held))
+    weights, plan, _ = route_held(
+        _mm("td,de->te", x, params["router"]["kernel"]), k, held[0],
+        len(held))
     rows = min(k, len(held)) * t
     p = pass_plan(plan, 0, rows, t, k)
     live = np.asarray(p["live"])
@@ -472,3 +472,206 @@ def test_expert_parameters_keep_the_leading_axis_ep_spec_shards(layer):
             assert leaf.shape[0] == 4 and spec[0] == "expert"
         else:
             assert tuple(spec) == ()
+
+
+# -- sigmoid scores, a bias on the choice, a factor, a shared expert -----------
+
+MOON = bench_reference("moonlight_16b_c3")
+FACTOR = 2.446
+
+
+def _parent_route_held(probs, k, first, n_held):
+    """``route_held`` as it stood before it took a scoring function, a bias
+    and a factor (PR 33), word for word."""
+    t, e = probs.shape
+    top_p, top_i = jax.lax.top_k(probs, k)
+    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    counts = jnp.zeros((e,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
+    aux = e * jnp.sum(counts / t * jnp.mean(probs, axis=0))
+    local = top_i.reshape(-1) - first
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    pair_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    plan = {"order": order, "pair_row": pair_row, "held": held,
+            "group_sizes": counts[first:first + n_held].astype(jnp.int32)}
+    return weights, plan, aux
+
+
+def test_the_softmax_setting_is_the_parents_routing_bit_for_bit(layer):
+    x, params = layer
+    logits = _mm("td,de->te", x.reshape(T, H), params["router"]["kernel"])
+    got = jax.jit(lambda z: route_held(z, K, 2, 4))(logits)
+    want = jax.jit(lambda z: _parent_route_held(
+        jax.nn.softmax(z, axis=-1), K, 2, 4))(logits)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.fixture(scope="module")
+def biased(layer):
+    """The layer's router with a bias that CHANGES the choice: it lifts
+    every token's third-best expert over its second-best."""
+    x, params = layer
+    scores = jax.nn.sigmoid(_mm("td,de->te", x.reshape(T, H),
+                                params["router"]["kernel"]))
+    bias = 0.3 * jax.random.normal(jax.random.key(21), (E,))
+    plain = np.asarray(jax.lax.top_k(scores, K)[1])
+    lifted = np.asarray(jax.lax.top_k(scores + bias, K)[1])
+    assert (np.sort(plain, 1) != np.sort(lifted, 1)).any(axis=1).mean() > 0.3
+    return x, params, bias, scores
+
+
+def _moon_layer(params, bias, m, held, shared=None, factor=FACTOR):
+    """The Moonlight reference's expert layer holding ``held``."""
+    share = {"router": params["router"],
+             "experts": jax.tree_util.tree_map(
+                 lambda a: a[np.asarray(held)], params["experts"])}
+    s = {"n_routed_experts": E, "num_experts_per_tok": K,
+         "experts_held": held, "routed_scaling_factor": factor}
+    return MOON.moe_layer(share, bias, m, s, _mm, shared)
+
+
+def _sigmoid_layer(held=None, **kw):
+    return HeldMoEMLP(H, F, num_experts=E, k=K, held=held,
+                      scoring="sigmoid", score_bias=True, factor=FACTOR,
+                      **kw)
+
+
+def _bias_stats(bias):
+    return {"e_score_correction_bias": bias}
+
+
+def test_sigmoid_routing_chooses_by_the_bias_and_weighs_without_it(biased):
+    """Against the reference, and against the two mistakes: weights taken
+    from ``s + b``, and the factor left out."""
+    x, params, bias, scores = biased
+    m = x.reshape(T, H)
+    y, mut = _sigmoid_layer().apply(
+        {"params": params, "batch_stats": _bias_stats(bias)}, x,
+        mutable=["intermediates"])
+    want, aux = _moon_layer(params, bias, m, tuple(range(E)))
+    np.testing.assert_allclose(np.asarray(y).reshape(T, H),
+                               np.asarray(want), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        float(moe_aux_loss(mut["intermediates"])), float(aux), rtol=1e-6)
+    logits = _mm("td,de->te", m, params["router"]["kernel"])
+    weights, plan, _ = route_held(logits, K, 0, E, "sigmoid", bias, FACTOR)
+    top_i = np.asarray(jax.lax.top_k(scores + bias, K)[1])
+    top_s = np.take_along_axis(np.asarray(scores), top_i, axis=1)
+    np.testing.assert_allclose(
+        np.asarray(weights), FACTOR * top_s / top_s.sum(1, keepdims=True),
+        rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(weights).sum(1), FACTOR, rtol=1e-6)
+    # weights from the biased scores are another result
+    top_b = np.take_along_axis(np.asarray(scores + bias), top_i, axis=1)
+    wrong = FACTOR * top_b / top_b.sum(1, keepdims=True)
+    assert np.abs(wrong - np.asarray(weights)).max() > 1e-2
+    # so is the layer without the factor, and the choice without the bias
+    for other in (_moon_layer(params, bias, m, tuple(range(E)), factor=1.0),
+                  _moon_layer(params, jnp.zeros((E,)), m, tuple(range(E)))):
+        assert float(jnp.abs(other[0] - want).max()) > 1e-3
+
+
+def test_no_gradient_reaches_the_bias_and_the_routers_matches(biased):
+    x, params, bias, _ = biased
+    w = jax.random.normal(jax.random.key(22), (T, H))
+
+    def prog(p, b, x):
+        return (_sigmoid_layer(held=(4, 5, 6, 7)).apply(
+            {"params": p, "batch_stats": _bias_stats(b)}, x).reshape(T, H)
+            * w).sum()
+
+    def ref(p, b, x):
+        return (_moon_layer(p, b, x.reshape(T, H), (4, 5, 6, 7))[0]
+                * w).sum()
+    ids = (4, 5, 6, 7)
+    got = jax.grad(prog, argnums=(0, 1, 2))(_share(params, ids), bias, x)
+    want = jax.grad(ref, argnums=(0, 1, 2))(params, bias, x)
+    assert not np.asarray(got[1]).any() and not np.asarray(want[1]).any()
+    for a, b in zip(
+            jax.tree_util.tree_leaves((got[0], got[2])),
+            jax.tree_util.tree_leaves((_share(want[0], ids), want[2]))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.fixture(scope="module")
+def with_shared(biased):
+    """A block's feed-forward as ``models/decoder.py`` builds it: the held
+    experts beside a shared SwiGLU of twice an expert's width."""
+    import flax.linen as nn
+    from split_learning_tpu.models.decoder import FEED_FORWARDS
+
+    class Both(nn.Module):
+        held: tuple | None = None
+
+        @nn.compact
+        def __call__(self, x):
+            return FEED_FORWARDS["sparse_shared"](
+                x, shared_intermediate_size=2 * F, intermediate_size=F,
+                num_experts=E, k=K, held=self.held, scoring="sigmoid",
+                score_bias=True, factor=FACTOR)
+    x, params, bias, _ = biased
+    shared = Both().init(jax.random.key(23), x)["params"]["shared_experts"]
+    return x, params, bias, shared, Both
+
+
+def test_the_shares_add_up_with_the_shared_expert_counted_once(with_shared):
+    """Four chips of two experts each, every chip computing the shared
+    expert alike: the routed parts sum, the shared expert counts ONCE, and
+    that is the uncut reference's whole layer."""
+    x, params, bias, shared, Both = with_shared
+    m = x.reshape(T, H)
+    own = MOON._swiglu(shared, m, _mm)
+    routed = 0.0
+    for first in range(0, E, 2):
+        held = (first, first + 1)
+        y = Both(held=held).apply(
+            {"params": {"moe": _share(params, held),
+                        "shared_experts": shared},
+             "batch_stats": {"moe": _bias_stats(bias)}}, x).reshape(T, H)
+        want, _ = _moon_layer(params, bias, m, held, shared)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+        routed = routed + (y - own)
+    whole, _ = _moon_layer(params, bias, m, tuple(range(E)), shared)
+    np.testing.assert_allclose(np.asarray(routed + own), np.asarray(whole),
+                               rtol=1e-5, atol=2e-6)
+    # counted on every chip it would be another result
+    assert float(jnp.abs(routed + 4 * own - whole).max()) > 1e-3
+
+
+def test_rows_past_the_groups_may_hold_anything_beside_a_shared_expert(
+        with_shared, monkeypatch):
+    """NaN in the buffer rows past the groups, as the chip has: the held
+    experts' part and the shared expert's, and every gradient, are what
+    they were."""
+    x, params, bias, shared, Both = with_shared
+    model = Both(held=(2, 3))
+    p = {"moe": _share(params, (2, 3)), "shared_experts": shared}
+    w = jax.random.normal(jax.random.key(24), x.shape)
+
+    def loss(p, x):
+        return (model.apply({"params": p, "batch_stats": {
+            "moe": _bias_stats(bias)}}, x) * w).sum()
+    real = expert.grouped_dot
+
+    def past_the_groups(fill):
+        def dot(lhs, rhs, group_sizes):
+            out = real(lhs, rhs, group_sizes)
+            live = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+            return jnp.where(live[:, None], out, fill)
+        return dot
+    monkeypatch.setattr(expert, "grouped_dot", past_the_groups(0.0))
+    want = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    monkeypatch.setattr(expert, "grouped_dot", past_the_groups(jnp.nan))
+    got = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
