@@ -138,18 +138,32 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: np.ndarray,
 # the mixer and the block
 # --------------------------------------------------------------------------
 
-def _causal_attention(q, k, v, dtype, use_flash: bool, flash_block: int,
-                      window: int | None = None):
+def _causal_attention(module: nn.Module, q, k, v, dtype, use_flash: bool,
+                      flash_block: int, window: int | None = None):
     """Causal attention over whole rows or the last ``window`` keys: ``q``
     (B, S, H, D), ``k`` (B, S, G, D), ``v`` (B, S, G, Dv), a key-value head
     for every ``H / G`` query heads; the result reshapes to (B, S, H * Dv).
     The Pallas kernel, or (small sizes only; the kernel's parity oracle)
-    an einsum with the mask applied to the scores."""
+    an einsum with the mask applied to the scores.
+
+    With the kernel, ``module`` sows what its forward walks in this call
+    under ``counters_sum`` (``parallel/pipeline.py COUNTER_FOLDS``), over
+    all rows and heads: ``flash_pairs_seen`` (the (query, key) pairs the
+    algorithm owes), ``flash_pairs_visited`` (those the kernel's blocks
+    cover) and ``flash_pairs_masked`` (those of them in blocks run with the
+    mask), by the walk the kernel takes."""
+    b, s, h, hd = q.shape
     if use_flash:
-        from split_learning_tpu.ops.flash_attention import flash_attention
+        from split_learning_tpu.ops.flash_attention import (
+            flash_attention, forward_pairs, tiling,
+        )
+        tile = tiling("fwd", s, window, flash_block, flash_block)
+        for name, pairs in zip(("seen", "visited", "masked"),
+                               forward_pairs(s, window, tile)):
+            module.sow("counters_sum", f"flash_pairs_{name}",
+                       jnp.float32(b * h * pairs))
         return flash_attention(q, k, v, causal=True, window=window,
                                block_q=flash_block, block_k=flash_block)
-    b, s, h, hd = q.shape
     groups, pos = k.shape[2], jnp.arange(s)
     qg = q.reshape(b, s, groups, h // groups, hd)
     scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k) / np.sqrt(hd)
@@ -219,8 +233,9 @@ class Attention(nn.Module):
                     q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
                     axis_name=self.seq_axis, causal=True)
             else:
-                out = _causal_attention(q, k, v, self.dtype, self.use_flash,
-                                        self.flash_block, window)
+                out = _causal_attention(self, q, k, v, self.dtype,
+                                        self.use_flash, self.flash_block,
+                                        window)
         return dense(self.hidden_size, name="o_proj")(
             out.reshape(b, s, self.num_heads * hd))
 
@@ -288,7 +303,7 @@ class LatentAttention(nn.Module):
                 kv[..., :nope],
                 jnp.broadcast_to(k_rope, (b, s, h, rot))], -1)
         with jax.named_scope("attn_full"):
-            out = _causal_attention(q, k, kv[..., nope:], self.dtype,
+            out = _causal_attention(self, q, k, kv[..., nope:], self.dtype,
                                     self.use_flash, self.flash_block)
         with jax.named_scope("mla_latent"):
             return dense(self.hidden_size, name="o_proj")(

@@ -1,11 +1,15 @@
 """Flash attention kernel vs dense reference: forward, gradients, causal,
 blocks, and the model-level use_flash path (Pallas interpreter on CPU)."""
 
+import importlib
+
 import jax
 import numpy as np
 import pytest
 
-from split_learning_tpu.ops.flash_attention import flash_attention
+from split_learning_tpu.ops.flash_attention import (
+    KERNELS, Tiling, flash_attention, forward_pairs, tiling,
+)
 from tests.conftest import dense_attention, qkv_batch
 
 
@@ -281,3 +285,219 @@ def test_the_latent_kernels_lower_for_tpu_at_the_cells_widths(kernel):
     names = ["slt_flash_fwd"] if kernel == "fwd" else [
         "slt_flash_bwd_dq", "slt_flash_bwd_dkv"]
     assert all(name in text for name in names)
+
+
+# -- the tiling of a call, and the walk it gives ------------------------------
+
+# the package exports the function under the module's name
+fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
+
+# (S, window, cap_q, cap_k): both cells' calls, the tests' small shapes,
+# and awkward ones (a window that is no multiple of any tile, of one key,
+# of the row and more; a row with no divisor of 128; caps that differ)
+_CALLS = [
+    (4096, 1024, 512, 512), (4096, None, 512, 512), (4096, 1024, 128, 128),
+    (2048, None, 128, 128), (32, None, 8, 8), (32, 5, 8, 8), (32, 20, 8, 8),
+    (32, 8, 8, 16), (32, 12, 16, 8), (64, 12, 8, 16), (48, None, 128, 128),
+    (48, 7, 128, 128), (96, 1, 32, 32), (96, 95, 32, 32), (96, 96, 32, 32),
+    (96, 200, 32, 32), (1000, 300, 512, 512), (1000, None, 512, 256),
+    (4096, 1000, 512, 512), (4096, 2048, 512, 512), (4096, 3900, 512, 512),
+    (120, 33, 24, 40), (120, None, 24, 40), (256, 64, 64, 64),
+]
+_CALL_IDS = ["-".join(map(str, c)) for c in _CALLS]
+
+
+def _band_mask(s, window):
+    pos = np.arange(s)
+    seen = pos[None, :] <= pos[:, None]
+    if window is not None:
+        seen &= pos[None, :] > pos[:, None] - window
+    return seen
+
+
+def _walked(s, window, tile, over_keys):
+    """Per tile of ``tile``'s walk (over keys: a query tile's; else a key
+    tile's): ``[(start of the other side's block, edges)]`` as the kernel
+    takes it, by the walk run on Python ints."""
+    window = fa._band_window(s, window)
+    own = tile.block_q if over_keys else tile.block_k
+    out = {}
+    for start in range(0, s, own):
+        done = []
+        fa._walk(start, tile, s, True, window, over_keys,
+                 lambda at, c, edges: c + [(int(at), edges)], [],
+                 done.append, on=fa._COUNTED)
+        assert len(done) == 1               # ``finish`` runs exactly once
+        out[start] = done[0]
+    return out
+
+
+@pytest.mark.parametrize("call", _CALLS, ids=_CALL_IDS)
+def test_every_tile_divides_the_row_and_stays_under_the_cap(call):
+    s, window, cap_q, cap_k = call
+    for kernel in KERNELS:
+        grid, block_q, block_k = tile = tiling(kernel, s, window, cap_q,
+                                               cap_k)
+        assert s % block_q == 0 and s % block_k == 0, tile
+        assert block_q <= cap_q and block_k <= cap_k, tile
+        own, cap = (block_k, cap_k) if kernel == "dkv" else (block_q, cap_q)
+        assert s % grid == 0 and grid % own == 0 and grid <= cap, tile
+    with pytest.raises(ValueError, match="one of"):
+        tiling("bwd", s, window, cap_q, cap_k)
+
+
+def test_small_caps_resolve_to_themselves_and_the_cells_to_what_was_measured():
+    """Explicit small blocks stay the tiles a test names; the cells' calls
+    get the tilings PERF.md section 6 (PR 35) measured."""
+    for kernel in KERNELS:
+        assert tiling(kernel, 32, None, 8, 16)[1:] == (8, 16)
+        assert tiling(kernel, 32, 12, 16, 8)[1:] == (16, 8)
+        assert tiling(kernel, 4096, None, 512, 512) == (512, 512, 512)
+    assert tiling("fwd", 4096, 1024, 512, 512) == (512, 512, 512)
+    assert tiling("dq", 4096, 1024, 512, 512) == (512, 256, 256)
+    assert tiling("dkv", 4096, 1024, 512, 512) == (512, 256, 256)
+
+
+@pytest.mark.parametrize("call", _CALLS, ids=_CALL_IDS)
+def test_the_exported_counts_equal_a_brute_force_count_over_the_band(call):
+    s, window, cap_q, cap_k = call
+    tile = tiling("fwd", s, window, cap_q, cap_k)
+    visited = np.zeros((s, s), int)
+    masked = np.zeros((s, s), int)
+    for q0, blocks in _walked(s, window, tile, True).items():
+        for k0, edges in blocks:
+            here = (slice(q0, q0 + tile.block_q),
+                    slice(k0, k0 + tile.block_k))
+            visited[here] += 1
+            masked[here] += edges is not None
+    assert forward_pairs(s, window, tile) == (
+        _band_mask(s, window).sum(), visited.sum(), masked.sum())
+
+
+@pytest.mark.parametrize("over_keys", [True, False], ids=["keys", "queries"])
+@pytest.mark.parametrize("call", _CALLS, ids=_CALL_IDS)
+def test_every_seen_pair_is_visited_once_and_no_unmasked_block_hides_one(
+        call, over_keys):
+    """The property whose failure is a silent wrong answer: with each
+    block's scores masked by the edges the walk names for it (none: not at
+    all), the walks of all tiles rebuild the band exactly, every pair
+    once; blocks stay inside the row."""
+    s, window, cap_q, cap_k = call
+    band = _band_mask(s, window)
+    w = fa._band_window(s, window)
+    pos = np.arange(s)
+    causal = pos[None, :] <= pos[:, None]
+    behind = pos[None, :] > pos[:, None] - (w or 0)
+    for kernel in ("fwd", "dq") if over_keys else ("dkv",):
+        tile = tiling(kernel, s, window, cap_q, cap_k)
+        built = np.zeros((s, s), int)
+        for start, blocks in _walked(s, window, tile, over_keys).items():
+            for at, edges in blocks:
+                q0, k0 = (start, at) if over_keys else (at, start)
+                assert 0 <= at and at + (
+                    tile.block_k if over_keys else tile.block_q) <= s
+                here = (slice(q0, q0 + tile.block_q),
+                        slice(k0, k0 + tile.block_k))
+                kept = np.ones((s, s), bool)
+                if edges is not None and edges[0]:
+                    kept &= causal
+                if edges is not None and edges[1]:
+                    kept &= behind
+                built[here] += kept[here]
+        np.testing.assert_array_equal(built, band.astype(int))
+
+
+def test_a_banded_walk_enters_no_loop_where_the_band_lies_inside_the_row():
+    """A steady tile of the cell's windowed call walks the same three
+    blocks laid from the band's end: the window's edge, a block seen
+    whole, the diagonal."""
+    walks = _walked(4096, 1024, Tiling(512, 512, 512), True)
+    for q0 in range(1024, 4096, 512):
+        assert walks[q0] == [(q0 - 1024, (False, True)), (q0 - 512, None),
+                             (q0, (True, False))]
+    assert walks[0] == [(0, (True, True))]
+    assert forward_pairs(4096, 1024, Tiling(512, 512, 512)) == (
+        3670528, 21 * 512 * 512, 15 * 512 * 512)
+    assert forward_pairs(4096, 1024, Tiling(512, 256, 256))[1] == 4587520
+    assert forward_pairs(4096, None, Tiling(512, 512, 512)) == (
+        8390656, 36 * 512 * 512, 8 * 512 * 512)
+
+
+# the cells' calls scaled down by 16 (rows of 256, a window of 64, a cap of
+# 32) under every tiling the function can return for them, sub-tiles walked
+# inside a grid step, a window that crosses sub-tiles, a band past
+# ``_UNROLL`` blocks, tiles that divide neither way
+_SCALED = [
+    ("cap", 64, (Tiling(32, 32, 32),) * 3),
+    ("halved_backward", 64, (Tiling(32, 32, 32), Tiling(32, 16, 16),
+                             Tiling(32, 16, 16))),
+    ("sub_tiles", 64, (Tiling(64, 16, 32), Tiling(64, 32, 16),
+                       Tiling(64, 16, 32))),
+    ("window_crosses_sub_tiles", 50, (Tiling(32, 16, 16),) * 3),
+    ("long_band", 100, (Tiling(32, 8, 8),) * 3),
+    ("full", None, (Tiling(32, 32, 32),) * 3),
+    ("full_sub_tiles", None, (Tiling(64, 16, 32), Tiling(64, 32, 16),
+                              Tiling(32, 16, 32))),
+]
+
+
+@pytest.mark.parametrize("rep,d,dv", [(1, 16, 16), (8, 24, 16)],
+                         ids=["heads_alone", "grouped_two_widths"])
+@pytest.mark.parametrize("name,window,tiles", _SCALED,
+                         ids=[c[0] for c in _SCALED])
+def test_forward_and_all_gradients_at_the_cells_tilings_scaled_down(
+        name, window, tiles, rep, d, dv):
+    b, s, h = 1, 256, 8
+    kq, kk, kv_, kw = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(kq, (b, s, h, d))
+    k = jax.random.normal(kk, (b, s, h // rep, d))
+    v = jax.random.normal(kv_, (b, s, h // rep, dv))
+    w = jax.random.normal(kw, (b, s, h, dv))
+    to_bhsd = lambda t: t.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+        b * t.shape[2], s, t.shape[3])
+
+    def flash(q, k, v):
+        out = fa._flash(to_bhsd(q), to_bhsd(k), to_bhsd(v), True, True,
+                        tiles, window, rep)
+        return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)),
+        np.asarray(_dense_two_widths(q, k, v, window)), rtol=2e-5, atol=2e-5)
+    g1 = jax.grad(lambda *a: (flash(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: (_dense_two_widths(*a, window) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for which, a, b_ in zip("qkv", g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-4, atol=5e-4, err_msg=which)
+
+
+# (heads, key-value heads, D, Dv, window): the cells' widths under every
+# kind of walk ``tiling`` can give them at a cap of 512: the backward's
+# halved tiles as sub-tiles (a band of at most two tiles), the cap with
+# three blocks between the edges, a band past ``_UNROLL`` blocks (one loop
+# between the edges), and the same at the latent layer's two widths
+_LOWERED = [(32, 4, 128, 128, 1024), (32, 4, 128, 128, 2048),
+            (32, 4, 128, 128, 3072), (16, 16, 192, 128, 1024),
+            (16, 16, 192, 128, 3072)]
+
+
+@pytest.mark.parametrize("h,kv,d,dv,window", _LOWERED,
+                         ids=["-".join(map(str, c)) for c in _LOWERED])
+def test_the_cells_widths_lower_for_tpu_under_each_walk(h, kv, d, dv,
+                                                        window):
+    import jax.numpy as jnp
+    shape = lambda n, w: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, 4096, n, w), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False,
+                               block_q=512, block_k=512, window=window)
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: fwd(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).trace(
+            shape(h, d), shape(kv, d), shape(kv, dv)).lower(
+                lowering_platforms=("tpu",)).as_text()
+    assert all(name in text for name in (
+        "slt_flash_fwd", "slt_flash_bwd_dq", "slt_flash_bwd_dkv"))

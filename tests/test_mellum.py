@@ -323,6 +323,28 @@ def test_the_step_hands_back_what_the_expert_layers_counted():
         rtol=1e-6)
 
 
+@pytest.mark.parametrize("flash_block", [8, 16])
+def test_the_step_hands_back_what_the_flash_kernels_walk(flash_block):
+    """``flash_pairs_*`` summed over the four layers (three windowed, one
+    full) and the microbatches: rows x heads x what one head's forward
+    walks under the call's tiling."""
+    from split_learning_tpu.ops.flash_attention import forward_pairs, tiling
+    _, _, ids, out = _step_outputs(
+        "Mellum2_TINYSTORIES",
+        dict(TINY, use_flash=True, flash_block=flash_block))
+    got = {name: float(v[0]) for name, v in out[4]["counters_sum"].items()
+           if name.startswith("flash_pairs_")}
+    m, mb = ids.shape[1:3]
+    want = np.zeros(3)
+    for window in (TINY["sliding_window"],) * 3 + (None,):
+        tile = tiling("fwd", SEQ, window, flash_block, flash_block)
+        want += np.array(forward_pairs(SEQ, window, tile)) * m * mb \
+            * TINY["num_attention_heads"]
+    assert got == dict(zip(("flash_pairs_seen", "flash_pairs_visited",
+                            "flash_pairs_masked"), want))
+    assert got["flash_pairs_seen"] <= got["flash_pairs_visited"]
+
+
 def test_a_model_that_sows_no_counter_hands_back_an_empty_tree():
     pipe, _, _, out = _step_outputs(
         "TinyLlama_TINYSTORIES",

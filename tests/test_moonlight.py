@@ -186,6 +186,34 @@ def test_the_latent_mixer_alone_matches_the_references(use_flash):
     assert off > 100 * float(jnp.abs(mine(a, n) - ref(a, n)).max())
 
 
+@pytest.mark.parametrize("use_flash", [False, True],
+                         ids=["einsum", "flash"])
+def test_the_latent_mixer_sows_what_its_kernel_walks(use_flash):
+    """``flash_pairs_*`` of one call (rows x heads x one head's causal
+    row), and nothing where the einsum runs."""
+    from split_learning_tpu.ops.flash_attention import forward_pairs, tiling
+    from split_learning_tpu.parallel.pipeline import (
+        COUNTER_FOLDS, sown_counters,
+    )
+    s = REF.sizes(TINY)
+    params, _ = REF.init(jax.random.key(5), TINY)
+    mixer = decoder.MIXERS[decoder.LATENT](
+        hidden_size=s["hidden_size"], num_heads=s["num_attention_heads"],
+        kv_lora_rank=s["kv_lora_rank"],
+        qk_nope_head_dim=s["qk_nope_head_dim"],
+        qk_rope_head_dim=s["qk_rope_head_dim"], v_head_dim=s["v_head_dim"],
+        rope_theta=s["rope_theta"], use_flash=use_flash, flash_block=8)
+    _, mut = mixer.apply({"params": params["layer3"]["attention"]},
+                         jnp.zeros((2, SEQ, TINY["hidden_size"])),
+                         mutable=list(COUNTER_FOLDS))
+    got = {k: float(v) for k, v in sown_counters(mut).get(
+        "counters_sum", {}).items()}
+    pairs = forward_pairs(SEQ, None, tiling("fwd", SEQ, None, 8, 8))
+    assert got == ({f"flash_pairs_{name}": 2.0 * s["num_attention_heads"] * n
+                    for name, n in zip(("seen", "visited", "masked"), pairs)}
+                   if use_flash else {})
+
+
 def test_what_has_no_module_is_refused():
     for kw in (dict(q_lora_rank=1536), dict(n_group=8, topk_group=4),
                dict(norm_topk_prob=False), dict(topk_method="group")):
