@@ -73,9 +73,13 @@ FLASH_GROUPED = ((2, 4096, 32, 4, 128, 1024, 512),
                  (2, 4096, 32, 4, 128, None, 512))
 #: (rows, K, N, groups) of the token cell's grouped products: a common
 #: pass's ``C`` rows against the 8 held experts' ``gate``/``up`` and
-#: ``down`` matrices, and the overflow pass's ``worst - C`` rows
+#: ``down`` matrices, and the overflow pass's ``worst - C`` rows; and the
+#: same of the cell whose experts are 1,856 wide (14.5 lane widths: a
+#: matrix block stands over the edge)
 GROUPED_SHAPES = ((16384, 2304, 896, 8), (16384, 896, 2304, 8),
-                  (49152, 2304, 896, 8), (49152, 896, 2304, 8))
+                  (49152, 2304, 896, 8), (49152, 896, 2304, 8),
+                  (6144, 2688, 1856, 8), (6144, 1856, 2688, 8),
+                  (43008, 2688, 1856, 8), (43008, 1856, 2688, 8))
 #: one microbatch of the VGG16 cut-7 boundary (configs/baseline1.yaml):
 #: the activation the codec quantizes, and its gradient
 CUT7_BOUNDARY = (32, 16, 16, 64)

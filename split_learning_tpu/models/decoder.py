@@ -2,24 +2,28 @@
 whose mixer and feed-forward each layer picks from a table.
 
 A block is ``x + mixer(norm(x))``; ``x + feed_forward(norm(x))``
-(:class:`DecoderBlock`).  Which mixer and which feed-forward layer ``i``
-has is decided here and nowhere else: :data:`MIXERS` and
-:data:`FEED_FORWARDS` map a configuration's kind names (the published
-``layer_types`` / ``mlp_layer_types`` values) to code, and
-:func:`decoder_specs` looks each layer's pair up.  An architecture of this
-family is a registered builder that translates its configuration's own key
-names; a new mixer or feed-forward is one module and one table entry.
+(:class:`DecoderBlock`), and either half may be empty: a layer that is a
+mixer OR a feed-forward alone has one norm and one residual.  Which mixer
+and which feed-forward layer ``i`` has is decided here and nowhere else:
+:data:`MIXERS` and :data:`FEED_FORWARDS` map a configuration's kind names
+(the published ``layer_types`` / ``mlp_layer_types`` values, the letters of
+a ``hybrid_override_pattern``) to code, and :func:`decoder_specs` looks each
+layer's pair up.  An architecture of this family is a registered builder
+that translates its configuration's own key names; a new mixer or
+feed-forward is one module and one table entry.
 
-The mixers there are: grouped-query attention, no bias, rotary embedding
-(:class:`Attention`), ``full_attention`` or ``sliding_attention`` (a query
-at ``p`` sees keys ``p - window + 1 .. p``), each kind with its own RoPE;
-and ``latent_attention`` (:class:`LatentAttention`: keys and values
-expanded from one low-rank latent a token, a rotary part that all heads
-share).  The feed-forwards: a ``dense`` SwiGLU, ``sparse`` experts of which
-this program may hold a share
+The mixers there are: grouped-query attention, no bias (:class:`Attention`),
+``full_attention`` or ``sliding_attention`` (a query at ``p`` sees keys
+``p - window + 1 .. p``), each kind with its own RoPE or none;
+``latent_attention`` (:class:`LatentAttention`: keys and values expanded
+from one low-rank latent a token, a rotary part that all heads share); and
+``mamba2`` (:class:`Mamba2`: a state-space layer, its recurrence the
+chunked scan of ``ops/ssd_scan.py``).  The feed-forwards: a ``dense``
+SwiGLU, ``sparse`` experts of which this program may hold a share
 (:class:`~split_learning_tpu.parallel.expert.HeldMoEMLP`),
-``sparse_shared`` (the same beside a shared SwiGLU that every token takes),
-and ``capacity`` experts that drop what overflows
+``sparse_shared`` (the same beside a shared expert that every token takes;
+the experts' form, SwiGLU or ``relu2``, is a keyword), and ``capacity``
+experts that drop what overflows
 (:class:`~split_learning_tpu.parallel.expert.MoEMLP`).
 
 Split-layer contract: 1 = token embedding, 2..n+1 = decoder blocks,
@@ -50,11 +54,12 @@ from split_learning_tpu.models.split import (
 # parallel/pipeline.py imports this package for ``build_model``, which
 # models/__init__.py binds before it imports this module
 from split_learning_tpu.parallel.expert import (
-    ExpertFFN, HeldMoEMLP, MoEMLP, swiglu,
+    ExpertFFN, HeldMoEMLP, MoEMLP, feed_forward,
 )
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 LATENT = "latent_attention"
+MAMBA2 = "mamba2"
 #: epsilon of the norm inside latent attention (``kv_a_layernorm``): the
 #: published implementation builds it with its class's default, not with
 #: the configuration's ``rms_norm_eps``
@@ -100,9 +105,13 @@ def yarn_inv_freq(head_dim: int, rope_theta: float, factor: float,
     return interp * ramp + extrap * (1 - ramp)
 
 
-def rope_of(kind: str, head_dim: int, rope_parameters: dict) -> tuple:
-    """``(inv_freq, factor on cos and sin)`` of one kind of layer."""
+def rope_of(kind: str, head_dim: int, rope_parameters: dict):
+    """``(inv_freq, factor on cos and sin)`` of one kind of layer; None
+    where its ``rope_type`` is ``none`` (a layer that turns nothing:
+    position reaches it through other layers)."""
     p = rope_parameters[kind]
+    if p.get("rope_type", "default") == "none":
+        return None
     if p.get("rope_type", "default") == "yarn":
         return yarn_inv_freq(head_dim, **{k: v for k, v in p.items()
                                           if k != "rope_type"}), \
@@ -177,7 +186,8 @@ def _causal_attention(module: nn.Module, q, k, v, dtype, use_flash: bool,
 
 class Attention(nn.Module):
     """Causal grouped-query attention with the RoPE of its ``kind`` of
-    layer, over all earlier keys or the last ``window`` of them.
+    layer (or none: :func:`rope_of`), over all earlier keys or the last
+    ``window`` of them.
 
     Three back ends, same math.  With ``seq_axis`` set the module runs
     inside ``shard_map`` (``parallel/sequence.py``): ``x`` is the LOCAL
@@ -215,12 +225,14 @@ class Attention(nn.Module):
         q = q.reshape(b, s, self.num_heads, hd)
         k = k.reshape(b, s, self.num_kv_heads, hd)
         v = v.reshape(b, s, self.num_kv_heads, hd)
-        inv_freq, factor = rope_of(self.kind, hd, self.rope_parameters)
-        pos = jnp.arange(s)
-        if self.seq_axis is not None:
-            pos = jax.lax.axis_index(self.seq_axis) * s + pos
-        q = rope(q, pos, inv_freq, self.interleaved, factor)
-        k = rope(k, pos, inv_freq, self.interleaved, factor)
+        turned = rope_of(self.kind, hd, self.rope_parameters)
+        if turned is not None:
+            inv_freq, factor = turned
+            pos = jnp.arange(s)
+            if self.seq_axis is not None:
+                pos = jax.lax.axis_index(self.seq_axis) * s + pos
+            q = rope(q, pos, inv_freq, self.interleaved, factor)
+            k = rope(k, pos, inv_freq, self.interleaved, factor)
         rep = self.num_heads // self.num_kv_heads
         with jax.named_scope(
                 "attn_window" if window is not None else "attn_full"):
@@ -310,8 +322,124 @@ class LatentAttention(nn.Module):
                 out.reshape(b, s, h * self.v_head_dim))
 
 
+# The element-wise halves of a Mamba-2 mixer keep their operands for the
+# backward pass and nothing between them: the float32 steps are as cheap to
+# make again as to read back, and a stage holds a layer's residuals whole.
+
+@jax.checkpoint
+def _causal_conv_silu(x, taps, bias):
+    """``silu`` of a causal depthwise convolution over the last
+    ``len(taps)`` positions of every channel of ``x`` (B, S, C): position
+    ``t`` reads ``t - len(taps) + 1 .. t``, the row shifted against itself
+    (no convolution instruction)."""
+    width, seq = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return nn.silu(bias + sum(taps[i] * padded[:, i:i + seq]
+                              for i in range(width)))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(5, 6))
+def _skip_gate_norm(y, x, z, skip, scale, groups: int, eps: float):
+    """``GroupRMSNorm((y + skip * x) * silu(z))`` in float32, cast back:
+    ``y`` and ``x`` (B, S, H, P), ``z`` (B, S, H * P), ``skip`` (H,), the
+    norm over each of ``groups`` shares of the width with a learnt
+    ``scale``: the gate BEFORE the norm."""
+    b, s, h, p = y.shape
+    f32 = jnp.float32
+    y = y.astype(f32) + skip[:, None] * x.astype(f32)
+    y = (y.reshape(b, s, h * p) * nn.silu(z.astype(f32))).reshape(
+        b, s, groups, h * p // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + eps)
+    return (y.reshape(b, s, h * p) * scale).astype(x.dtype)
+
+
+def _dt_bias_init(dt_min: float, dt_max: float, floor: float):
+    """``dt`` log-uniform in ``[dt_min, dt_max]``, floored, stored through
+    the inverse of the softplus (the published Mamba-2 initial value)."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.maximum(jnp.exp(
+            jax.random.uniform(key, shape, dtype)
+            * (math.log(dt_max) - math.log(dt_min)) + math.log(dt_min)),
+            floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return init
+
+
+class Mamba2(nn.Module):
+    """A Mamba-2 state-space mixer (the published ``nemotron_h`` layer,
+    under its configuration's names).
+
+    ``[z, xBC, dt] = x W_in`` (``num_heads * head_dim`` + that and ``2 *
+    n_groups * ssm_state_size`` + ``num_heads`` wide, no bias).  ``xBC =
+    silu(conv(xBC))``: a causal depthwise convolution over the last
+    ``conv_kernel`` positions of every channel, with bias; split into ``x``
+    (heads x ``head_dim``), ``B`` and ``C`` (``n_groups`` x
+    ``ssm_state_size`` each; head ``h`` reads group ``h // (num_heads /
+    n_groups)``).  ``D_t = softplus(dt_t + dt_bias)`` a head, ``A =
+    -exp(A_log)``; per head, with a float32 state ``S``: ``S_t = exp(D_t
+    A) S_{t-1} + D_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``
+    (``ops/ssd_scan.py``: in chunks of ``chunk_size``).  Then ``y =
+    GroupRMSNorm(y * silu(z))`` (``n_groups`` groups, a learnt scale: the
+    gate BEFORE the norm) and ``y W_out``.
+
+    Scopes, in both passes: ``ssm_mixer`` around the whole mixer and
+    ``ssm_scan`` inside it around the scan alone."""
+    hidden_size: int
+    num_heads: int
+    head_dim: int
+    n_groups: int
+    ssm_state_size: int
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    eps: float = 1e-5
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        from split_learning_tpu.ops.ssd_scan import ssd_scan
+        b, s, _ = x.shape
+        h, p, g, n = self.num_heads, self.head_dim, self.n_groups, \
+            self.ssm_state_size
+        inner, wide = h * p, h * p + 2 * g * n
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        f32 = jnp.float32
+        with jax.named_scope("ssm_mixer"):
+            z, xbc, dt = jnp.split(
+                dense(inner + wide + h, name="in_proj")(x),
+                [inner, inner + wide], axis=-1)
+            taps = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                              (self.conv_kernel, wide)).astype(self.dtype)
+            bias = self.param("conv_bias", nn.initializers.zeros,
+                              (wide,)).astype(self.dtype)
+            xbc = _causal_conv_silu(xbc, taps, bias)
+            xs, bm, cm = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+            xs = xs.reshape(b, s, h, p)
+            dt = jax.nn.softplus(dt.astype(f32) + self.param(
+                "dt_bias", _dt_bias_init(
+                    self.time_step_min, self.time_step_max,
+                    self.time_step_floor), (h,)))
+            a = -jnp.exp(self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, f32, 1.0, 16.0)), (h,)))
+            skip = self.param("D", nn.initializers.ones, (h,))
+            with jax.named_scope("ssm_scan"):
+                y = ssd_scan(xs, dt, a, bm.reshape(b, s, g, n),
+                             cm.reshape(b, s, g, n), self.chunk_size)
+            y = _skip_gate_norm(
+                y, xs, z, skip, self.param(
+                    "norm_scale", nn.initializers.ones, (inner,)), g,
+                self.eps)
+            return dense(self.hidden_size, name="out_proj")(y)
+
+
 class DecoderBlock(nn.Module):
-    """``h = x + mixer(norm(x))``; ``h + feed_forward(norm(h))``.
+    """``h = x + mixer(norm(x))``; ``h + feed_forward(norm(h))``; a half
+    that is None is left out, with its norm and its residual.
 
     ``mixer`` makes the mixer's module, given its name; ``feed_forward``
     is applied to the normed state and creates what it needs in this
@@ -320,8 +448,8 @@ class DecoderBlock(nn.Module):
     :data:`MIXERS` / :data:`FEED_FORWARDS`, and the block knows neither
     attention nor experts.
     """
-    mixer: Callable[..., nn.Module]
-    feed_forward: Callable[[jnp.ndarray], jnp.ndarray]
+    mixer: Callable[..., nn.Module] | None
+    feed_forward: Callable[[jnp.ndarray], jnp.ndarray] | None
     eps: float = 1e-5
     dtype: jnp.dtype = jnp.float32
 
@@ -329,8 +457,11 @@ class DecoderBlock(nn.Module):
     def __call__(self, x):
         norm = functools.partial(nn.RMSNorm, epsilon=self.eps,
                                  dtype=self.dtype)
-        x = x + self.mixer(name="attention")(norm(name="input_norm")(x))
-        return x + self.feed_forward(norm(name="post_norm")(x))
+        if self.mixer is not None:
+            x = x + self.mixer(name="attention")(norm(name="input_norm")(x))
+        if self.feed_forward is not None:
+            x = x + self.feed_forward(norm(name="post_norm")(x))
+        return x
 
 
 def _experts(layer) -> Callable:
@@ -342,13 +473,14 @@ def _experts(layer) -> Callable:
 
 
 def _experts_and_shared(x, shared_intermediate_size: int, **kw):
-    """Held experts beside a shared SwiGLU (the caller's submodule
-    ``shared_experts``) that every token takes: computed whole here, by
-    every chip of an expert-parallel job over its own rows alike, and
-    added to the held experts' partial sum.  Scope ``moe_shared``."""
+    """Held experts beside a shared expert of the same form (the caller's
+    submodule ``shared_experts``) that every token takes: computed whole
+    here, by every chip of an expert-parallel job over its own rows alike,
+    and added to the held experts' partial sum.  Scope ``moe_shared``."""
     with jax.named_scope("moe_shared"):
-        shared = ExpertFFN(shared_intermediate_size, kw.get(
-            "dtype", jnp.float32), name="shared_experts")(x)
+        shared = ExpertFFN(
+            shared_intermediate_size, kw.get("dtype", jnp.float32),
+            kw.get("form", "swiglu"), name="shared_experts")(x)
     return _experts(HeldMoEMLP)(x, **kw) + shared
 
 
@@ -358,13 +490,15 @@ def _experts_and_shared(x, shared_intermediate_size: int, **kw):
 # keywords from the builder.
 MIXERS = {FULL: functools.partial(Attention, kind=FULL),
           SLIDING: functools.partial(Attention, kind=SLIDING),
-          LATENT: LatentAttention}
-FEED_FORWARDS = {"dense": swiglu, "sparse": _experts(HeldMoEMLP),
+          LATENT: LatentAttention, MAMBA2: Mamba2}
+FEED_FORWARDS = {"dense": feed_forward, "sparse": _experts(HeldMoEMLP),
                  "sparse_shared": _experts_and_shared,
                  "capacity": _experts(MoEMLP)}
 
 
-def _entry(table: dict, what: str, kind: str, keywords: dict):
+def _entry(table: dict, what: str, kind: str | None, keywords: dict):
+    if kind is None:        # the block's empty half
+        return None
     if kind not in table:
         raise ValueError(f"{what} {kind!r}: the known ones are "
                          f"{sorted(table)}")
@@ -376,8 +510,9 @@ def decoder_specs(layers, mixers: dict, feed_forwards: dict, *,
                   dtype=jnp.float32) -> tuple:
     """The split layers of a decoder: the embedding, one
     :class:`DecoderBlock` for each ``(mixer kind, feed-forward kind)`` of
-    ``layers``, the final RMSNorm, the untied head.  ``mixers`` and
-    ``feed_forwards`` hold, by kind, the keywords of the table's entry."""
+    ``layers`` (None for a half the layer lacks), the final RMSNorm, the
+    untied head.  ``mixers`` and ``feed_forwards`` hold, by kind, the
+    keywords of the table's entry."""
     specs = [LayerSpec("layer1", make=functools.partial(
         nn.Embed, num_embeddings=vocab_size, features=hidden_size,
         dtype=dtype), fn=_plain_fn)]
@@ -559,3 +694,88 @@ def moonlight_tinystories(
                  n_shared_experts * moe_intermediate_size))},
         vocab_size=vocab_size, hidden_size=hidden_size, eps=rms_norm_eps,
         dtype=dtype)
+
+
+#: ``hybrid_override_pattern``'s letters: a layer is ONE sublayer, a mixer
+#: or a feed-forward alone.  ``-`` (a dense feed-forward layer) has no entry:
+#: the published tower has none, and :data:`FEED_FORWARDS`' ``dense`` is a
+#: SwiGLU.
+NEMOTRON_H_LAYERS = {"M": (MAMBA2, None), "*": (FULL, None),
+                     "E": (None, "sparse_shared")}
+NEMOTRON_H_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+@register_model("NemotronH_TINYSTORIES")
+def nemotron_h_tinystories(
+        vocab_size: int = 131072, hidden_size: int = 2688,
+        num_hidden_layers: int = 52,
+        hybrid_override_pattern: str = NEMOTRON_H_PATTERN,
+        num_attention_heads: int = 32, num_key_value_heads: int = 2,
+        head_dim: int = 128, sliding_window: int | None = None,
+        mamba_num_heads: int = 64, mamba_head_dim: int = 64,
+        n_groups: int = 8,
+        ssm_state_size: int = 128, conv_kernel: int = 4,
+        chunk_size: int = 128, time_step_min: float = 0.001,
+        time_step_max: float = 0.1, time_step_floor: float = 1e-4,
+        mlp_hidden_act: str = "relu2", moe_intermediate_size: int = 1856,
+        moe_shared_expert_intermediate_size: int = 3712,
+        n_routed_experts: int = 128, n_shared_experts: int = 1,
+        num_experts_per_tok: int = 6, routed_scaling_factor: float = 2.5,
+        norm_topk_prob: bool = True, n_group: int = 1, topk_group: int = 1,
+        layer_norm_epsilon: float = 1e-5,
+        experts_held: int | tuple | None = None, use_flash: bool = False,
+        flash_block: int = 512, dtype=jnp.float32) -> tuple:
+    """One ``nemotron_h`` tower (the language model that
+    https://huggingface.co/nvidia/Nemotron-Labs-TwoTower-30B-A3B-Base-BF16/blob/main/config.json
+    states) under the keys of the published configuration; input (B, S)
+    int32 token ids, output (B, S, vocab) next-token logits.  Layer ``i`` is
+    the ONE sublayer ``hybrid_override_pattern[i]`` names
+    (:data:`NEMOTRON_H_LAYERS`): ``M`` a Mamba-2 mixer (:class:`Mamba2`),
+    ``*`` grouped-query attention over all earlier keys (``sliding_window``
+    null, as published) with NO rotary embedding (position reaches it
+    through the Mamba-2 layers), ``E``
+    ``n_routed_experts`` experts of ``moe_intermediate_size`` beside a
+    shared one of ``moe_shared_expert_intermediate_size``, all of the form
+    ``mlp_hidden_act`` (``relu2``: two matrices, no gate), ``num_experts_per_tok``
+    a token chosen by the sigmoid of the router's logits plus the bias
+    ``e_score_correction_bias``, weighted by the scores alone, renormalized
+    and multiplied by ``routed_scaling_factor``.  ``experts_held`` as in
+    ``Mellum2_TINYSTORIES``.  What has no module here is refused: a choice
+    limited to groups of experts, weights that are not renormalized, more
+    than one shared expert, a pattern's letter outside the table.  The
+    second, denoising tower of the published pair and its objective are
+    not in the configuration and not here."""
+    pattern = hybrid_override_pattern
+    unknown = sorted(set(pattern) - set(NEMOTRON_H_LAYERS))
+    if n_group != 1 or topk_group != 1 or not norm_topk_prob \
+            or n_shared_experts != 1 or unknown \
+            or len(pattern) != num_hidden_layers:
+        raise ValueError(
+            f"no module for n_group={n_group}, topk_group={topk_group}, "
+            f"norm_topk_prob={norm_topk_prob}, "
+            f"n_shared_experts={n_shared_experts}, the letters {unknown} "
+            f"of hybrid_override_pattern, or {num_hidden_layers} layers "
+            f"for a pattern of {len(pattern)}")
+    return decoder_specs(
+        [NEMOTRON_H_LAYERS[c] for c in pattern],
+        {MAMBA2: dict(
+            hidden_size=hidden_size, num_heads=mamba_num_heads,
+            head_dim=mamba_head_dim, n_groups=n_groups,
+            ssm_state_size=ssm_state_size, conv_kernel=conv_kernel,
+            chunk_size=chunk_size, eps=layer_norm_epsilon,
+            time_step_min=time_step_min, time_step_max=time_step_max,
+            time_step_floor=time_step_floor, dtype=dtype),
+         FULL: dict(
+            hidden_size=hidden_size, num_heads=num_attention_heads,
+            num_kv_heads=num_key_value_heads, head_dim=head_dim,
+            rope_parameters={FULL: {"rope_type": "none"}},
+            window=sliding_window, use_flash=use_flash,
+            flash_block=flash_block, dtype=dtype)},
+        {"sparse_shared": dict(
+            intermediate_size=moe_intermediate_size,
+            num_experts=n_routed_experts, k=num_experts_per_tok,
+            held=_held(experts_held), scoring="sigmoid", score_bias=True,
+            factor=routed_scaling_factor, form=mlp_hidden_act, dtype=dtype,
+            shared_intermediate_size=moe_shared_expert_intermediate_size)},
+        vocab_size=vocab_size, hidden_size=hidden_size,
+        eps=layer_norm_epsilon, dtype=dtype)

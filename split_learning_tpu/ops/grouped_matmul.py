@@ -77,12 +77,16 @@ def row_tile(rows: int) -> int:
 
 def _col_tile(width: int, depth: int, itemsize: int) -> int:
     """Columns of a matrix block ``depth`` deep: the whole ``width`` where
-    that fits :data:`MATRIX_BLOCK_BYTES` or cannot be cut (no multiple of
-    the lane count), else its largest lane-aligned divisor that fits."""
-    if width % LANES or depth * width * itemsize <= MATRIX_BLOCK_BYTES:
+    that fits :data:`MATRIX_BLOCK_BYTES`, else its largest lane-aligned
+    divisor that fits; a width that is no multiple of the lane count has
+    no such divisor and takes the largest lane-aligned tile that fits, its
+    last block standing over the edge (the calls' grids round up: what a
+    block reads past the edge only reaches columns that are not written)."""
+    if depth * width * itemsize <= MATRIX_BLOCK_BYTES:
         return width
     fits = [c for c in range(LANES, width, LANES)
-            if width % c == 0 and depth * c * itemsize <= MATRIX_BLOCK_BYTES]
+            if (width % c == 0 or width % LANES)
+            and depth * c * itemsize <= MATRIX_BLOCK_BYTES]
     return max(fits, default=LANES)
 
 
@@ -202,7 +206,7 @@ def _gmm(lhs, rhs, group_sizes, transposed: bool, tm: int, tn: int,
         out_shape=jax.ShapeDtypeStruct((rows, width), lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(width // tn, plan[1].shape[0]),
+            grid=(pl.cdiv(width, tn), plan[1].shape[0]),
             in_specs=[pl.BlockSpec((tm, depth),
                                    lambda j, v, o, g, t, n: (t[v], 0)),
                       rhs_spec],
@@ -287,7 +291,7 @@ def _gmm_drhs(lhs, dout, group_sizes, tm: int, tk: int, tn: int,
                                        lhs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(k // tk, n // tn, plan[1].shape[0]),
+            grid=(pl.cdiv(k, tk), pl.cdiv(n, tn), plan[1].shape[0]),
             in_specs=[pl.BlockSpec((tm, tk),
                                    lambda i, j, v, o, g, t, n: (t[v], i)),
                       pl.BlockSpec((tm, tn),

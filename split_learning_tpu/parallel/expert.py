@@ -97,26 +97,44 @@ def topk_dispatch(probs: jnp.ndarray, k: int, capacity: int):
     return combine, dispatch, aux
 
 
-def swiglu(x, intermediate_size: int, dtype=jnp.float32):
-    """The SwiGLU feed-forward (LLaMA geometry) over the last axis of ``x``.
-    Its kernels ``gate_proj``, ``up_proj``, ``down_proj`` are created in the
-    CALLER's scope: a dense decoder block's own (``models/decoder.py``), or
-    one expert's (:class:`ExpertFFN`)."""
+#: A feed-forward's FORM by name: the names of its kernels in the order
+#: they are applied (the last one maps back to the state), and what stands
+#: between the first products and the last, given ``dot(x, kernel)`` and the
+#: kernels before the last.  A SwiGLU has three matrices (LLaMA geometry),
+#: a ``relu2`` (the published ``mlp_hidden_act``: ``relu(x W_up)^2 W_down``)
+#: two and no gate.
+FORMS = {
+    "swiglu": (("gate_proj", "up_proj", "down_proj"),
+               lambda dot, x, gate, up: nn.silu(dot(x, gate)) * dot(x, up)),
+    "relu2": (("up_proj", "down_proj"),
+              lambda dot, x, up: jnp.square(nn.relu(dot(x, up)))),
+}
+
+
+def feed_forward(x, intermediate_size: int, dtype=jnp.float32,
+                 form: str = "swiglu"):
+    """The feed-forward of the ``form`` named (:data:`FORMS`) over the last
+    axis of ``x``.  Its kernels are created in the CALLER's scope: a dense
+    decoder block's own (``models/decoder.py``), or one expert's
+    (:class:`ExpertFFN`)."""
+    names, between = FORMS[form]
     dense = functools.partial(nn.Dense, use_bias=False, dtype=dtype)
-    gate = nn.silu(dense(intermediate_size, name="gate_proj")(x))
-    up = dense(intermediate_size, name="up_proj")(x)
-    return dense(x.shape[-1], name="down_proj")(gate * up)
+    hidden = between(lambda x, name: dense(intermediate_size, name=name)(x),
+                     x, *names[:-1])
+    return dense(x.shape[-1], name=names[-1])(hidden)
 
 
 class ExpertFFN(nn.Module):
-    """One expert of :class:`MoEMLP`: :func:`swiglu` as the module that
-    ``nn.vmap`` needs."""
+    """One feed-forward of the ``form`` named (:data:`FORMS`) as a module:
+    an expert of :class:`MoEMLP` (what ``nn.vmap`` needs), or the shared
+    expert beside a held share."""
     intermediate_size: int
     dtype: jnp.dtype = jnp.float32
+    form: str = "swiglu"
 
     @nn.compact
     def __call__(self, x):
-        return swiglu(x, self.intermediate_size, self.dtype)
+        return feed_forward(x, self.intermediate_size, self.dtype, self.form)
 
 
 class MoEMLP(nn.Module):
@@ -385,28 +403,30 @@ def _fold_rows_bwd(res, g):
 fold_rows.defvjp(_fold_rows_fwd, _fold_rows_bwd)
 
 
-def _pass(x, weights, kernels, plan, lo: int, hi: int):
+def _pass(x, weights, kernels, plan, lo: int, hi: int,
+          form: str = "swiglu"):
     """The held experts' part of the result from the sorted rows
-    ``[lo, hi)``: gather the rows' tokens, the SwiGLU as three grouped
-    products (:func:`grouped_dot`: float32 operands at
-    ``Precision.HIGHEST``) over the group sizes clipped to the range,
-    fold the weighted results into their tokens."""
+    ``[lo, hi)``: gather the rows' tokens, the experts' ``form``
+    (:data:`FORMS`: a SwiGLU's three grouped products, a ``relu2``'s two;
+    :func:`grouped_dot`: float32 operands at ``Precision.HIGHEST``) over
+    the group sizes clipped to the range, fold the weighted results into
+    their tokens."""
     t = x.shape[0]
     with jax.named_scope("moe_route"):
         p = pass_plan(plan, lo, hi, t, weights.shape[0] // t)
         rows = spread_rows(x, p)
     with jax.named_scope("moe_experts"):
-        gate, up, down = kernels
         def dot(lhs, rhs):
             return grouped_dot(lhs, rhs, p["sizes"])
 
-        y = dot(nn.silu(dot(rows, gate)) * dot(rows, up), down)
+        y = dot(FORMS[form][1](dot, rows, *kernels[:-1]), kernels[-1])
     with jax.named_scope("moe_route"):
         return fold_rows(y, weights, p)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def both_passes(x, weights, kernels, plan, c: int, worst: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def both_passes(x, weights, kernels, plan, c: int, worst: int,
+                form: str = "swiglu"):
     """The common pass over the sorted rows ``[0, c)`` and, where the held
     pairs number more, the overflow pass over ``[c, worst)`` added to it,
     under one ``cond``: every pair is computed whatever the load.
@@ -417,30 +437,30 @@ def both_passes(x, weights, kernels, plan, c: int, worst: int):
     ``cond``), and the skipped branch hands the common pass's cotangents
     through as they are, where a differentiated ``cond`` would zero-fill
     every residual of the branch it did not take."""
-    out = _pass(x, weights, kernels, plan, 0, c)
-    return _add_overflow(out, x, weights, kernels, plan, c, worst)
+    out = _pass(x, weights, kernels, plan, 0, c, form)
+    return _add_overflow(out, x, weights, kernels, plan, c, worst, form)
 
 
-def _add_overflow(out, x, weights, kernels, plan, c, worst):
+def _add_overflow(out, x, weights, kernels, plan, c, worst, form):
     return jax.lax.cond(
         jnp.sum(plan["group_sizes"]) > c,
-        lambda o: o + _pass(x, weights, kernels, plan, c, worst),
+        lambda o: o + _pass(x, weights, kernels, plan, c, worst, form),
         lambda o: o, out)
 
 
-def _both_passes_fwd(x, weights, kernels, plan, c, worst):
+def _both_passes_fwd(x, weights, kernels, plan, c, worst, form):
     out, pull = jax.vjp(
-        lambda *a: _pass(*a, plan, 0, c), x, weights, kernels)
-    out = _add_overflow(out, x, weights, kernels, plan, c, worst)
+        lambda *a: _pass(*a, plan, 0, c, form), x, weights, kernels)
+    out = _add_overflow(out, x, weights, kernels, plan, c, worst, form)
     return out, (pull, x, weights, kernels, plan)
 
 
-def _both_passes_bwd(c, worst, res, g):
+def _both_passes_bwd(c, worst, form, res, g):
     pull, x, weights, kernels, plan = res
 
     def and_overflow(grads):
         _, pull_over = jax.vjp(
-            lambda *a: _pass(*a, plan, c, worst), x, weights, kernels)
+            lambda *a: _pass(*a, plan, c, worst, form), x, weights, kernels)
         return jax.tree_util.tree_map(jnp.add, grads, pull_over(g))
 
     grads = jax.lax.cond(jnp.sum(plan["group_sizes"]) > c, and_overflow,
@@ -463,22 +483,24 @@ class _ExpertKernel(nn.Module):
 
 
 class _ExpertBank(nn.Module):
-    """The held experts' SwiGLU kernels ``(gate, up, down)`` in the compute
-    type, the experts leading: what the grouped products of a pass
-    (:func:`_pass`) multiply the rows by."""
+    """The held experts' kernels in the compute type, the experts leading,
+    by the names and in the order of their ``form`` (:data:`FORMS`: a
+    SwiGLU's ``(gate, up, down)``, a ``relu2``'s ``(up, down)``): what the
+    grouped products of a pass (:func:`_pass`) multiply the rows by."""
     n: int
     hidden_size: int
     intermediate_size: int
     dtype: jnp.dtype = jnp.float32
+    form: str = "swiglu"
 
     @nn.compact
     def __call__(self):
         n, d, f = self.n, self.hidden_size, self.intermediate_size
+        names = FORMS[self.form][0]
         return tuple(
-            _ExpertKernel(shape, name=name)().astype(self.dtype)
-            for name, shape in (("gate_proj", (n, d, f)),
-                                ("up_proj", (n, d, f)),
-                                ("down_proj", (n, f, d))))
+            _ExpertKernel((n, d, f) if name != names[-1] else (n, f, d),
+                          name=name)().astype(self.dtype)
+            for name in names)
 
 
 class HeldMoEMLP(nn.Module):
@@ -496,7 +518,8 @@ class HeldMoEMLP(nn.Module):
     here moves it).  Of the (token, expert)
     pairs, those whose expert is one of ``held`` (consecutive ids; ``None``:
     all) are sorted by expert, their rows gathered, run through the
-    experts' SwiGLU as grouped matrix products, scaled by their weights
+    experts (``form``, :data:`FORMS`: a SwiGLU of three matrices or a
+    ``relu2`` of two) as grouped matrix products, scaled by their weights
     and summed back into their tokens.  What the absent experts would add
     is left out: on a chip of an expert-parallel job that partial sum is
     what the exchange would complete; the shares of all chips add up to
@@ -541,6 +564,7 @@ class HeldMoEMLP(nn.Module):
     scoring: str = "softmax"
     score_bias: bool = False
     factor: float = 1.0
+    form: str = "swiglu"
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -587,12 +611,13 @@ class HeldMoEMLP(nn.Module):
         # kernels' gradients back to float32), are the experts' work
         with jax.named_scope("moe_experts"):
             kernels = _ExpertBank(n_held, h, self.intermediate_size,
-                                  self.dtype, name="experts")()
+                                  self.dtype, self.form, name="experts")()
         if c < worst:
             out = both_passes(xt, weights.reshape(-1), kernels, plan,
-                              c, worst)
+                              c, worst, self.form)
         else:
-            out = _pass(xt, weights.reshape(-1), kernels, plan, 0, worst)
+            out = _pass(xt, weights.reshape(-1), kernels, plan, 0, worst,
+                        self.form)
         return out.reshape(b, s, h).astype(x.dtype)
 
 

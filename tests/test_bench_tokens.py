@@ -4,8 +4,11 @@ attention through the interpreted flash kernel, a held share of experts,
 the load-balancing term, AdamW, FedAvg, validation, checkpoint) and
 ``moonlight_16b_c3.round`` (latent attention through the same kernels at
 two widths, a dense first block, a shared expert, sigmoid routing with a
-bias that rides in ``batch_stats``) come out ``correct`` against their
-plain references, and the expert layer's counters reach the round records
+bias that rides in ``batch_stats``) and
+``nemotron_twotower_30b_c5.round`` (Mamba-2 mixers over the chunked scan,
+attention without rotary embedding, ``relu2`` experts beside a shared one,
+every layer one sublayer) come out ``correct`` against their plain
+references, and the expert layer's counters reach the round records
 and the result line."""
 
 import json
@@ -15,7 +18,8 @@ import sys
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
-CELLS = ["mellum2_12b_c3.round", "moonlight_16b_c3.round"]
+CELLS = ["mellum2_12b_c3.round", "moonlight_16b_c3.round",
+         "nemotron_twotower_30b_c5.round"]
 
 
 @pytest.fixture()
@@ -34,7 +38,7 @@ def run_cell(monkeypatch):
     yield module
     context._GLOBAL_STEP_CACHE.clear()
     for name in ("run_cell", "compare", "traffic", "program_trace",
-                 "trace_reduce", "mixer_trace", "mla_trace"):
+                 "trace_reduce", "mixer_trace", "mla_trace", "ssm_trace"):
         sys.modules.pop(name, None)
 
 

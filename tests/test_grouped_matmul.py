@@ -111,6 +111,34 @@ def test_the_operation_and_its_gradients_match_the_oracle(case, dtype,
     _close(drhs, np.asarray(drhs_want), dtype)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_a_matrix_block_may_stand_over_the_edge(dtype):
+    """A width of 200 in blocks of 128 columns (the last holds 72): every
+    kernel against the dense product a group, whichever operand is the
+    one whose blocks stand over the edge."""
+    rows, k, n, sizes = 64, 40, 200, [20, 0, 30]
+    keys = jax.random.split(jax.random.key(5), 3)
+    lhs = jax.random.normal(keys[0], (rows, k)).astype(dtype)
+    rhs = jax.random.normal(keys[1], (3, k, n)).astype(dtype)
+    dout = jax.random.normal(keys[2], (rows, n)).astype(dtype)
+    group_sizes, live = jnp.asarray(sizes, jnp.int32), sum(sizes)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
+    out = np.zeros((rows, n), np.float32)
+    drhs, lo = np.zeros((3, k, n), np.float32), 0
+    for g, size in enumerate(sizes):
+        out[lo:lo + size] = f32(lhs)[lo:lo + size] @ f32(rhs)[g]
+        drhs[g] = f32(lhs)[lo:lo + size].T @ f32(dout)[lo:lo + size]
+        lo += size
+    _close(gm.gmm(lhs, rhs, group_sizes, tm=16, tn=128), out, dtype, live)
+    _close(gm.gmm(lhs, jnp.swapaxes(rhs, 1, 2), group_sizes,
+                  transposed=True, tm=16, tn=128), out, dtype, live)
+    _close(gm.gmm_drhs(lhs, dout, group_sizes, tm=16, tk=k, tn=128), drhs,
+           dtype)
+    _close(gm.gmm_drhs(dout, lhs, group_sizes, tm=16, tk=128, tn=k),
+           drhs.transpose(0, 2, 1), dtype)
+
+
 def test_a_product_visits_the_tiles_that_hold_live_rows_and_no_other():
     """The visit plan: a tile a group for every tile the group has rows
     in, in row order; past the last visit the indices stay the last
@@ -146,6 +174,9 @@ def test_the_time_cannot_follow_the_buffer():
     (2304, 896, 4, 1152),       # float32: the largest aligned divisor
     (40, 24, 4, 40),            # no multiple of 128: the whole dimension
     (128 * 7, 1 << 16, 4, 128),
+    (1856, 2688, 2, 768),       # 14.5 lane widths, too large whole: the
+    (1856, 2688, 4, 384),       # largest aligned tile, the last over the edge
+    (1856, 384, 4, 1856),
 ])
 def test_a_matrix_block_is_whole_or_a_lane_aligned_divisor(width, depth,
                                                            itemsize, want):
@@ -175,7 +206,7 @@ def test_grouped_kernels_lower_for_tpu(name):
     from split_learning_tpu.analysis.pallas_check import (
         check_tpu_lowering,
     )
-    assert len(_GROUPED_CASES) == 12    # four shapes, three kernels
+    assert len(_GROUPED_CASES) == 24    # eight shapes, three kernels
     assert check_tpu_lowering(*_GROUPED_CASES[name]) == []
 
 

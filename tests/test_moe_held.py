@@ -675,3 +675,198 @@ def test_rows_past_the_groups_may_hold_anything_beside_a_shared_expert(
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-6)
+
+
+# -- the experts' form: relu2 (two matrices, no gate) beside the SwiGLU -------
+
+NEMO = bench_reference("nemotron_twotower_30b_c5")
+E16, FACTOR2 = 16, 2.5
+
+
+def _parent_pass(x, weights, kernels, plan, lo, hi):
+    """``_pass`` as it stood before the experts' form was a field (PR 35),
+    word for word."""
+    import flax.linen as nn
+    t = x.shape[0]
+    with jax.named_scope("moe_route"):
+        p = pass_plan(plan, lo, hi, t, weights.shape[0] // t)
+        rows = expert.spread_rows(x, p)
+    with jax.named_scope("moe_experts"):
+        gate, up, down = kernels
+        def dot(lhs, rhs):
+            return expert.grouped_dot(lhs, rhs, p["sizes"])
+
+        y = dot(nn.silu(dot(rows, gate)) * dot(rows, up), down)
+    with jax.named_scope("moe_route"):
+        return expert.fold_rows(y, weights, p)
+
+
+def test_the_swiglu_form_is_the_parents_pass_bit_for_bit(layer):
+    """Values and every gradient of one pass, and the lowered text of the
+    pass itself: the form named ``swiglu`` computes what the parent's
+    ``_pass`` computed."""
+    x, params = layer
+    m = x.reshape(T, H)
+    logits = _mm("td,de->te", m, params["router"]["kernel"])
+    weights, plan, _ = route_held(logits, K, 2, 4)
+    kernels = tuple(params["experts"][n]["kernel"][2:6]
+                    for n in ("gate_proj", "up_proj", "down_proj"))
+    w = jax.random.normal(jax.random.key(31), (T, H))
+    c = common_rows(T, K, 4, E)
+
+    def run(fn):
+        def loss(m, weights, kernels):
+            return (fn(m, weights, kernels, plan, 0, c) * w).sum()
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    mine, parents = run(expert._pass), run(_parent_pass)
+    args = (m, weights.reshape(-1), kernels)
+    assert mine.lower(*args).as_text() == parents.lower(*args).as_text()
+    for a, b in zip(jax.tree_util.tree_leaves(mine(*args)),
+                    jax.tree_util.tree_leaves(parents(*args))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.fixture(scope="module")
+def relu2():
+    """Sixteen relu2 experts, sigmoid scores, a bias that changes the
+    choice, the factor 2.5, beside a shared relu2 expert of twice the
+    width: an expert layer as ``models/decoder.py`` builds it for
+    ``NemotronH_TINYSTORIES``."""
+    import flax.linen as nn
+    from split_learning_tpu.models.decoder import FEED_FORWARDS
+
+    class Both(nn.Module):
+        held: tuple | None = None
+
+        @nn.compact
+        def __call__(self, x):
+            return FEED_FORWARDS["sparse_shared"](
+                x, shared_intermediate_size=2 * F, intermediate_size=F,
+                num_experts=E16, k=K, held=self.held, scoring="sigmoid",
+                score_bias=True, factor=FACTOR2, form="relu2")
+    x = jax.random.normal(jax.random.key(40), (2, T // 2, H))
+    variables = Both().init(jax.random.key(41), x)
+    bias = 0.3 * jax.random.normal(jax.random.key(42), (E16,))
+    return x, variables["params"], bias, Both
+
+
+def _nemo_layer(params, bias, m, held, shared=True):
+    share = {"router": params["moe"]["router"],
+             "experts": jax.tree_util.tree_map(
+                 lambda a: a[np.asarray(held)], params["moe"]["experts"])}
+    s = {"n_routed_experts": E16, "num_experts_per_tok": K,
+         "experts_held": held, "routed_scaling_factor": FACTOR2}
+    return NEMO.moe_layer(share, bias, m, s, _mm,
+                          params["shared_experts"] if shared else None)
+
+
+def _relu2_share(params, held):
+    return {"moe": {"router": params["moe"]["router"],
+                    "experts": jax.tree_util.tree_map(
+                        lambda a: a[held[0]:held[-1] + 1],
+                        params["moe"]["experts"])},
+            "shared_experts": params["shared_experts"]}
+
+
+def test_relu2_experts_have_two_matrices_and_match_the_reference(relu2):
+    """The tree (``up_proj`` and ``down_proj``, no gate, in the routed and
+    in the shared expert), the whole layer and a share against the
+    reference's; a SwiGLU or a plain ``relu`` would be another result."""
+    x, params, bias, Both = relu2
+    m = x.reshape(T, H)
+    assert set(params["moe"]["experts"]) == {"up_proj", "down_proj"}
+    assert set(params["shared_experts"]) == {"up_proj", "down_proj"}
+    assert params["moe"]["experts"]["up_proj"]["kernel"].shape == (E16, H, F)
+    stats = {"moe": _bias_stats(bias)}
+    for held in (tuple(range(E16)), (4, 5, 6, 7)):
+        y = Both(held=held).apply(
+            {"params": _relu2_share(params, held), "batch_stats": stats}, x)
+        want, _ = _nemo_layer(params, bias, m, held)
+        np.testing.assert_allclose(np.asarray(y).reshape(T, H),
+                                   np.asarray(want), rtol=1e-5, atol=1e-6)
+    up = params["shared_experts"]["up_proj"]["kernel"]
+    down = params["shared_experts"]["down_proj"]["kernel"]
+    whole, _ = _nemo_layer(params, bias, m, tuple(range(E16)))
+    routed, _ = _nemo_layer(params, bias, m, tuple(range(E16)), shared=False)
+    np.testing.assert_allclose(
+        np.asarray(whole - routed),
+        np.asarray(jnp.square(jax.nn.relu(m @ up)) @ down), rtol=1e-4,
+        atol=1e-6)
+    assert float(jnp.abs(jax.nn.relu(m @ up) @ down
+                         - (whole - routed)).max()) > 1e-3
+
+
+def test_relu2_gradients_match_the_reference(relu2):
+    x, params, bias, Both = relu2
+    held = (8, 9, 10, 11)
+    w = jax.random.normal(jax.random.key(43), (T, H))
+
+    def prog(p, x):
+        return (Both(held=held).apply(
+            {"params": p, "batch_stats": {"moe": _bias_stats(bias)}},
+            x).reshape(T, H) * w).sum()
+
+    def ref(p, x):
+        return (_nemo_layer(p, bias, x.reshape(T, H), held)[0] * w).sum()
+    got = jax.grad(prog, argnums=(0, 1))(_relu2_share(params, held), x)
+    want = jax.grad(ref, argnums=(0, 1))(params, x)
+    for a, b in zip(
+            jax.tree_util.tree_leaves(got),
+            jax.tree_util.tree_leaves((_relu2_share(want[0], held),
+                                       want[1]))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_sixteen_chips_shares_add_up_with_the_shared_expert_counted_once(
+        relu2):
+    """Sixteen chips of one relu2 expert each, every chip computing the
+    shared expert alike: the routed parts sum, the shared expert counts
+    ONCE, and that is the uncut reference's whole layer."""
+    x, params, bias, Both = relu2
+    m = x.reshape(T, H)
+    own = NEMO._relu2(m, params["shared_experts"]["up_proj"]["kernel"],
+                      params["shared_experts"]["down_proj"]["kernel"], _mm)
+    routed = 0.0
+    for chip in range(E16):
+        y = Both(held=(chip,)).apply(
+            {"params": _relu2_share(params, (chip,)),
+             "batch_stats": {"moe": _bias_stats(bias)}}, x).reshape(T, H)
+        routed = routed + (y - own)
+    whole, _ = _nemo_layer(params, bias, m, tuple(range(E16)))
+    np.testing.assert_allclose(np.asarray(routed + own), np.asarray(whole),
+                               rtol=1e-5, atol=5e-6)
+    # counted on every chip it would be another result
+    assert float(jnp.abs(routed + E16 * own - whole).max()) > 1e-3
+
+
+def test_rows_past_the_groups_may_hold_anything_under_relu2(relu2,
+                                                            monkeypatch):
+    """NaN in the buffer rows past the groups, as the chip has: the
+    square of a ``relu`` keeps it, the fold masks it, forward and
+    backward."""
+    x, params, bias, Both = relu2
+    held = (2, 3)
+    p = _relu2_share(params, held)
+    w = jax.random.normal(jax.random.key(44), x.shape)
+
+    def loss(p, x):
+        return (Both(held=held).apply({"params": p, "batch_stats": {
+            "moe": _bias_stats(bias)}}, x) * w).sum()
+    real = expert.grouped_dot
+
+    def past_the_groups(fill):
+        def dot(lhs, rhs, group_sizes):
+            out = real(lhs, rhs, group_sizes)
+            live = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+            return jnp.where(live[:, None], out, fill)
+        return dot
+    monkeypatch.setattr(expert, "grouped_dot", past_the_groups(0.0))
+    want = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    monkeypatch.setattr(expert, "grouped_dot", past_the_groups(jnp.nan))
+    got = jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
