@@ -27,7 +27,9 @@ it):
   nothing asserted that);
 * grouped products — ``parallel/expert.py HeldMoEMLP`` traced through
   value and gradient: the ``pallas_call``s of ``ops/grouped_matmul.py``
-  are there and no ``ragged_dot`` is.
+  are there and no ``ragged_dot`` is;
+* the state-space scan — ``models/decoder.py Mamba2`` traced through
+  value and gradient: both kernels of ``ops/ssd_scan.py`` are there.
 
 :func:`check_lowering` is a pure jaxpr->findings helper so the
 negative test can prove the gate actually fires on a pallas-free
@@ -60,6 +62,7 @@ _REL_QUANT = "split_learning_tpu/runtime/codec/quant.py"
 _REL_AGG = "split_learning_tpu/runtime/aggregate.py"
 _REL_FLASH = "split_learning_tpu/ops/flash_attention.py"
 _REL_GMM = "split_learning_tpu/ops/grouped_matmul.py"
+_REL_SSD = "split_learning_tpu/ops/ssd_scan.py"
 _REL_KQUANT = "split_learning_tpu/ops/kernels/quant.py"
 _REL_KUPDATE = "split_learning_tpu/ops/kernels/update.py"
 
@@ -80,6 +83,10 @@ GROUPED_SHAPES = ((16384, 2304, 896, 8), (16384, 896, 2304, 8),
                   (49152, 2304, 896, 8), (49152, 896, 2304, 8),
                   (6144, 2688, 1856, 8), (6144, 1856, 2688, 8),
                   (43008, 2688, 1856, 8), (43008, 1856, 2688, 8))
+#: (B, S, H, P, G, N, chunk) of the state-space cell's scans
+#: (``nemotron_twotower_30b_c5``): 64 heads of 64 in 8 groups, a state of
+#: 128, chunks of 128
+SSD_SHAPES = ((2, 4096, 64, 64, 8, 128, 128),)
 #: one microbatch of the VGG16 cut-7 boundary (configs/baseline1.yaml):
 #: the activation the codec quantizes, and its gradient
 CUT7_BOUNDARY = (32, 16, 16, 64)
@@ -88,19 +95,28 @@ CUT7_BOUNDARY = (32, 16, 16, 64)
 QUANT_TILES = (64, 256)
 
 
-def primitives(jaxpr, found=None) -> set:
-    """Names of every primitive of the (closed) jaxpr and of what it
-    calls: custom_vjp/jit carry ClosedJaxprs, ``pallas_call`` its kernel
-    as a plain Jaxpr, a ``cond`` its branches as a list."""
-    found = set() if found is None else found
+def equations(jaxpr):
+    """Every equation of the (closed) jaxpr and of what it calls:
+    custom_vjp/jit carry ClosedJaxprs, ``pallas_call`` its kernel as a
+    plain Jaxpr, a ``cond`` its branches as a list."""
     for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
-        found.add(eqn.primitive.name)
+        yield eqn
         for param in eqn.params.values():
             for sub in param if isinstance(param, (list, tuple)) \
                     else (param,):
                 if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
-                    primitives(sub, found)
-    return found
+                    yield from equations(sub)
+
+
+def primitives(jaxpr) -> set:
+    """Names of every primitive of :func:`equations`."""
+    return {eqn.primitive.name for eqn in equations(jaxpr)}
+
+
+def pallas_calls(jaxpr) -> list:
+    """Every ``pallas_call`` equation of :func:`equations`."""
+    return [eqn for eqn in equations(jaxpr)
+            if eqn.primitive.name == "pallas_call"]
 
 
 def contains_pallas_call(jaxpr) -> bool:
@@ -151,6 +167,32 @@ def grouped_lowering_cases() -> list[tuple]:
     return cases
 
 
+def ssd_lowering_cases() -> list[tuple]:
+    """The scan's two kernels (``slt_ssd_fwd``; ``slt_ssd_bwd`` under a
+    gradient) in bfloat16 at :data:`SSD_SHAPES`."""
+    import jax
+    import jax.numpy as jnp
+
+    from split_learning_tpu.ops.ssd_scan import ssd_scan
+
+    cases = []
+    for b, s, h, p, g, n, chunk in SSD_SHAPES:
+        def scan(*operands, chunk=chunk):
+            return ssd_scan(*operands, chunk, False)
+        operands = (jax.ShapeDtypeStruct((b, s, h, p), jnp.bfloat16),
+                    jax.ShapeDtypeStruct((b, s, h), jnp.float32),
+                    jax.ShapeDtypeStruct((h,), jnp.float32),
+                    jax.ShapeDtypeStruct((b, s, g, n), jnp.bfloat16),
+                    jax.ShapeDtypeStruct((b, s, g, n), jnp.bfloat16))
+        shape = f"({b}, {s}, {h}, {p}, {g}, {n}, {chunk})"
+        cases += [
+            (f"ssd_fwd{shape}", _REL_SSD, scan, operands),
+            (f"ssd_bwd{shape}", _REL_SSD, jax.grad(
+                lambda *o, f=scan: f(*o).astype(jnp.float32).sum(),
+                argnums=range(5)), operands)]
+    return cases
+
+
 def lowering_cases() -> list[tuple]:
     """``(name, rel, fn, abstract_args)`` for every Pallas kernel at
     the shapes the chip smoke runs: flash forward and backward, the
@@ -196,6 +238,7 @@ def lowering_cases() -> list[tuple]:
             jax.grad(lambda q, k, v, f=grouped: f(q, k, v).astype(
                 jnp.float32).sum(), argnums=(0, 1, 2)), qkv))
     cases += grouped_lowering_cases()
+    cases += ssd_lowering_cases()
     n = int(np.prod(CUT7_BOUNDARY))
     for tile in QUANT_TILES:
         t = n // tile
@@ -363,6 +406,30 @@ def _check_grouped_dispatch() -> list[Finding]:
     return findings
 
 
+def _check_ssd_dispatch() -> list[Finding]:
+    """``models/decoder.py Mamba2``, value and gradient: the scan's forward
+    and backward kernels are ``pallas_call``s in the traced program."""
+    import jax
+    import jax.numpy as jnp
+
+    from split_learning_tpu.models.decoder import Mamba2
+
+    layer = Mamba2(hidden_size=32, num_heads=4, head_dim=8, n_groups=2,
+                   ssm_state_size=16, chunk_size=8)
+    x = jnp.zeros((1, 16, 32))
+    params = jax.eval_shape(
+        lambda k: layer.init(k, x), jax.random.key(0))["params"]
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda p, xx: layer.apply({"params": p}, xx).sum(),
+        argnums=(0, 1)))(params, x)
+    found = {eqn.params["name"] for eqn in pallas_calls(jaxpr)}
+    missing = sorted({"slt_ssd_fwd", "slt_ssd_bwd"} - found)
+    return [Finding(
+        "PK001", _REL_SSD, 0, "mamba2-ssd-scan",
+        f"Mamba2's scan runs no {missing}: the scan's only path is "
+        "ops/ssd_scan.py's kernels")] if missing else []
+
+
 def run(root: pathlib.Path, trace: bool = True) -> list[Finding]:
     if not trace:
         return []
@@ -370,6 +437,7 @@ def run(root: pathlib.Path, trace: bool = True) -> list[Finding]:
     findings += _check_stage_update_kernel()
     findings += _check_flash_lowering()
     findings += _check_grouped_dispatch()
+    findings += _check_ssd_dispatch()
     for case in lowering_cases():
         findings += check_tpu_lowering(*case)
     return findings

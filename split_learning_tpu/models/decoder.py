@@ -379,12 +379,15 @@ class Mamba2(nn.Module):
     n_groups)``).  ``D_t = softplus(dt_t + dt_bias)`` a head, ``A =
     -exp(A_log)``; per head, with a float32 state ``S``: ``S_t = exp(D_t
     A) S_{t-1} + D_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``
-    (``ops/ssd_scan.py``: in chunks of ``chunk_size``).  Then ``y =
-    GroupRMSNorm(y * silu(z))`` (``n_groups`` groups, a learnt scale: the
-    gate BEFORE the norm) and ``y W_out``.
+    (``ops/ssd_scan.py``'s kernels: in chunks of ``chunk_size``).  Then
+    ``y = GroupRMSNorm(y * silu(z))`` (``n_groups`` groups, a learnt scale:
+    the gate BEFORE the norm) and ``y W_out``.
 
     Scopes, in both passes: ``ssm_mixer`` around the whole mixer and
-    ``ssm_scan`` inside it around the scan alone."""
+    ``ssm_scan`` inside it around the scan alone.  Under ``counters_sum``
+    (``parallel/pipeline.py COUNTER_FOLDS``) the mixer sows
+    ``ssd_kernel_chunks``: the (row, group, chunk) blocks the scan's
+    forward kernel walks in this call."""
     hidden_size: int
     num_heads: int
     head_dim: int
@@ -430,6 +433,8 @@ class Mamba2(nn.Module):
             with jax.named_scope("ssm_scan"):
                 y = ssd_scan(xs, dt, a, bm.reshape(b, s, g, n),
                              cm.reshape(b, s, g, n), self.chunk_size)
+            self.sow("counters_sum", "ssd_kernel_chunks",
+                     jnp.float32(b * g * -(-s // self.chunk_size)))
             y = _skip_gate_norm(
                 y, xs, z, skip, self.param(
                     "norm_scale", nn.initializers.ones, (inner,)), g,

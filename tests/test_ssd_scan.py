@@ -1,14 +1,20 @@
-"""The chunked state-space scan (``ops/ssd_scan.py``) against the
-recurrence a position at a time: forward and every gradient, at a row
-several chunks long, at one that is no multiple of the chunk, and with a
-decay so strong that a chunk's whole decay underflows."""
+"""The state-space scan's kernels (``ops/ssd_scan.py``, through the Pallas
+interpreter here) against the recurrence a position at a time, and against
+the plain chunked form at a group's real widths: forward and every
+gradient, at a row several chunks long, at one that is no multiple of the
+chunk, one shorter than a chunk, and with a decay so strong that a chunk's
+whole decay underflows."""
+
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from split_learning_tpu.ops.ssd_scan import ssd_scan, ssd_scan_reference
+from split_learning_tpu.ops.ssd_scan import (
+    ssd_scan, ssd_scan_chunked, ssd_scan_reference,
+)
 
 NAMES = ("x", "dt", "A", "B", "C", "D_skip")
 
@@ -140,12 +146,126 @@ def test_the_backward_pass_keeps_the_operands_and_nothing_else():
 
 
 def test_the_scan_has_no_loop_over_positions():
-    """Its loops are over the groups and over the chunks: none as long as
-    the row."""
+    """The kernels' sequential grid axis is the chunks (forward once, the
+    backward pass's there and back): the only loops, and none is as long
+    as the row."""
     args, w = _operands(64)
-    text = str(jax.make_jaxpr(jax.grad(
+    jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: (ssd_scan(*a, 16) * w).sum(), argnums=range(5)))(
-            *args[:5]))
+            *args[:5])
+    from split_learning_tpu.analysis.pallas_check import pallas_calls
+    calls = {eqn.params["name"]: eqn for eqn in pallas_calls(jaxpr)}
+    assert sorted(calls) == ["slt_ssd_bwd", "slt_ssd_fwd"]
+    # (rows, groups, chunks) and (rows, groups, chunks there and back)
+    assert calls["slt_ssd_fwd"].params["grid_mapping"].grid == (2, 2, 4)
+    assert calls["slt_ssd_bwd"].params["grid_mapping"].grid == (2, 2, 8)
+    for eqn in calls.values():
+        semantics = eqn.params["compiler_params"]["mosaic_tpu"] \
+            .dimension_semantics
+        assert tuple(semantics) == ("parallel", "parallel", "arbitrary")
+    text = str(jaxpr)
     lengths = {int(part.split("length=")[1].split()[0].rstrip(","))
                for part in text.split("scan[")[1:] if "length=" in part}
-    assert lengths and max(lengths) <= 4        # 64 / 16 chunks, 2 groups
+    assert not lengths or max(lengths) < 64
+
+
+def test_a_mamba2_layers_kernels_run_under_its_scan_scope_in_both_passes():
+    """The compiled gradient of a small ``Mamba2`` names ``ssm_scan`` in the
+    ``op_name`` of the kernels' operations, forward and backward: the
+    scope the benchmark's ``ssm_scan_ms`` and ``ssd_roofline`` read."""
+    from split_learning_tpu.models import decoder
+    mixer = decoder.Mamba2(hidden_size=32, num_heads=2, head_dim=64,
+                           n_groups=1, ssm_state_size=16, chunk_size=128)
+    x = jnp.zeros((1, 256, 32))
+    params = jax.eval_shape(lambda k: mixer.init(k, x),
+                            jax.random.key(0))["params"]
+    text = jax.jit(jax.grad(
+        lambda p, xx: mixer.apply({"params": p}, xx).sum(),
+        argnums=(0, 1))).trace(params, x).lower().compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*slt_ssd_(?:fwd|bwd))', text))
+    assert any(n.endswith("slt_ssd_fwd") and "/jvp(Mamba2)/ssm_mixer/"
+               "ssm_scan/" in n for n in names), names
+    assert any(n.endswith("slt_ssd_bwd") and "/transpose(jvp(Mamba2))/"
+               "ssm_mixer/ssm_scan/" in n for n in names), names
+
+
+def test_a_mamba2_layer_counts_the_blocks_its_forward_kernel_walks():
+    """``ssd_kernel_chunks``: (row, group, chunk) blocks, a row that is no
+    multiple of the chunk counting its last, partial chunk."""
+    from split_learning_tpu.models import decoder
+    mixer = decoder.Mamba2(hidden_size=32, num_heads=4, head_dim=8,
+                           n_groups=2, ssm_state_size=16, chunk_size=8)
+    x = jnp.zeros((3, 20, 32))
+    params = mixer.init(jax.random.key(0), x)["params"]
+    _, sown = mixer.apply({"params": params}, x, mutable=["counters_sum"])
+    count = jax.tree_util.tree_leaves(sown["counters_sum"])
+    assert [float(v) for v in count] == [3 * 2 * 3]
+
+
+# the cases above at a group's real widths (8 heads of 64 a group, a state
+# of 128, chunks of 128) in bfloat16: the kernels against the plain form
+WIDE = {"three_chunks": (384, 0.1), "two_chunks_and_a_bit": (300, 0.1),
+        "shorter_than_a_chunk": (100, 0.1), "strong_decay": (256, 8.0)}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE))
+def test_forward_and_every_gradient_match_the_chunked_form_at_real_widths(
+        case):
+    seq, dt_scale = WIDE[case]
+    args, w = _operands(seq, dt_scale, rows=1, heads=16, head_dim=64,
+                        groups=2, state=128, dtype=jnp.bfloat16)
+    args = args[:5]
+    w = w.astype(jnp.bfloat16)
+    y = ssd_scan(*args, 128)
+    y_c = ssd_scan_chunked(*args, 128)
+    assert y.dtype == y_c.dtype == jnp.bfloat16
+
+    def rel(got, want):
+        got, want = (np.asarray(v, np.float32) for v in (got, want))
+        return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+    assert rel(y, y_c) < 2e-3
+    g = jax.grad(lambda *a: (ssd_scan(*a, 128) * w).astype(
+        jnp.float32).sum(), argnums=range(5))(*args)
+    g_c = jax.grad(lambda *a: (ssd_scan_chunked(*a, 128) * w).astype(
+        jnp.float32).sum(), argnums=range(5))(*args)
+    for name, got, want in zip(NAMES, g, g_c):
+        assert got.dtype == want.dtype, name
+        assert bool(jnp.isfinite(got).all()), name
+        assert rel(got, want) < 1e-2, name
+
+
+# --------------------------------------------------------------------------
+# PK001: the kernels lower for the TPU, and the layer dispatches to them
+# --------------------------------------------------------------------------
+
+def _ssd_cases():
+    from split_learning_tpu.analysis.pallas_check import ssd_lowering_cases
+    return {c[0]: c for c in ssd_lowering_cases()}
+
+
+_SSD_CASES = _ssd_cases()
+
+
+@pytest.mark.parametrize("name", sorted(_SSD_CASES))
+def test_the_kernels_lower_for_tpu_at_the_cells_shapes(name):
+    """Forward, and backward under a gradient, at the state-space cell's
+    shapes (2 rows of 4,096, 64 heads of 64 in 8 groups, a state of 128,
+    chunks of 128, bfloat16) lower for the TPU natively."""
+    from split_learning_tpu.analysis.pallas_check import (
+        check_tpu_lowering, lowering_cases,
+    )
+    assert check_tpu_lowering(*_SSD_CASES[name]) == []
+    assert name in {c[0] for c in lowering_cases()}
+
+
+def test_the_mamba2_layer_dispatches_to_the_kernels():
+    from split_learning_tpu.analysis import pallas_check
+    assert pallas_check._check_ssd_dispatch() == []
+
+
+def test_a_shape_the_kernels_cannot_tile_on_the_tpu_is_refused():
+    """Off the interpreter a call the blocks cannot tile raises; it does
+    not fall back to another path."""
+    args, _ = _operands(64)
+    with pytest.raises(ValueError, match="cannot tile"):
+        jax.eval_shape(lambda *a: ssd_scan(*a, 16, False), *args[:5])
