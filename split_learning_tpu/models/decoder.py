@@ -196,7 +196,10 @@ class Attention(nn.Module):
     kernel (``ops/flash_attention.py``: O(S) memory; it picks a query
     head's key-value head by index and skips the key blocks outside the
     window).  Else the scores are an einsum and the mask is applied to
-    them (small sizes only; the kernel's parity oracle).
+    them (small sizes only; the kernel's parity oracle).  Scopes, in both
+    passes: ``attn_proj`` around the projections, their reshapes and the
+    RoPE, and again around ``o_proj``; ``attn_window`` or ``attn_full``
+    between them, around the scores and values.
     """
     hidden_size: int
     num_heads: int
@@ -219,20 +222,21 @@ class Attention(nn.Module):
             raise ValueError("the ring (seq_axis) has no window")
         dense = functools.partial(nn.Dense, use_bias=False,
                                   dtype=self.dtype)
-        q = dense(self.num_heads * hd, name="q_proj")(x)
-        k = dense(self.num_kv_heads * hd, name="k_proj")(x)
-        v = dense(self.num_kv_heads * hd, name="v_proj")(x)
-        q = q.reshape(b, s, self.num_heads, hd)
-        k = k.reshape(b, s, self.num_kv_heads, hd)
-        v = v.reshape(b, s, self.num_kv_heads, hd)
-        turned = rope_of(self.kind, hd, self.rope_parameters)
-        if turned is not None:
-            inv_freq, factor = turned
-            pos = jnp.arange(s)
-            if self.seq_axis is not None:
-                pos = jax.lax.axis_index(self.seq_axis) * s + pos
-            q = rope(q, pos, inv_freq, self.interleaved, factor)
-            k = rope(k, pos, inv_freq, self.interleaved, factor)
+        with jax.named_scope("attn_proj"):
+            q = dense(self.num_heads * hd, name="q_proj")(x)
+            k = dense(self.num_kv_heads * hd, name="k_proj")(x)
+            v = dense(self.num_kv_heads * hd, name="v_proj")(x)
+            q = q.reshape(b, s, self.num_heads, hd)
+            k = k.reshape(b, s, self.num_kv_heads, hd)
+            v = v.reshape(b, s, self.num_kv_heads, hd)
+            turned = rope_of(self.kind, hd, self.rope_parameters)
+            if turned is not None:
+                inv_freq, factor = turned
+                pos = jnp.arange(s)
+                if self.seq_axis is not None:
+                    pos = jax.lax.axis_index(self.seq_axis) * s + pos
+                q = rope(q, pos, inv_freq, self.interleaved, factor)
+                k = rope(k, pos, inv_freq, self.interleaved, factor)
         rep = self.num_heads // self.num_kv_heads
         with jax.named_scope(
                 "attn_window" if window is not None else "attn_full"):
@@ -248,8 +252,9 @@ class Attention(nn.Module):
                 out = _causal_attention(self, q, k, v, self.dtype,
                                         self.use_flash, self.flash_block,
                                         window)
-        return dense(self.hidden_size, name="o_proj")(
-            out.reshape(b, s, self.num_heads * hd))
+        with jax.named_scope("attn_proj"):
+            return dense(self.hidden_size, name="o_proj")(
+                out.reshape(b, s, self.num_heads * hd))
 
 
 class LatentAttention(nn.Module):
@@ -451,7 +456,8 @@ class DecoderBlock(nn.Module):
     block's scope (a dense SwiGLU its three kernels, an expert layer its
     submodule ``moe``): both are the builder's partials over an entry of
     :data:`MIXERS` / :data:`FEED_FORWARDS`, and the block knows neither
-    attention nor experts.
+    attention nor experts.  Each norm and each residual add is under the
+    scope ``norm_residual``, in both passes.
     """
     mixer: Callable[..., nn.Module] | None
     feed_forward: Callable[[jnp.ndarray], jnp.ndarray] | None
@@ -460,12 +466,18 @@ class DecoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        norm = functools.partial(nn.RMSNorm, epsilon=self.eps,
-                                 dtype=self.dtype)
+        def half(x, norm_name, f):
+            with jax.named_scope("norm_residual"):
+                normed = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                                    name=norm_name)(x)
+            out = f(normed)
+            with jax.named_scope("norm_residual"):
+                return x + out
+
         if self.mixer is not None:
-            x = x + self.mixer(name="attention")(norm(name="input_norm")(x))
+            x = half(x, "input_norm", self.mixer(name="attention"))
         if self.feed_forward is not None:
-            x = x + self.feed_forward(norm(name="post_norm")(x))
+            x = half(x, "post_norm", self.feed_forward)
         return x
 
 
@@ -489,6 +501,12 @@ def _experts_and_shared(x, shared_intermediate_size: int, **kw):
     return _experts(HeldMoEMLP)(x, **kw) + shared
 
 
+def _dense(x, **kw):
+    """A dense feed-forward in the caller's scope; scope ``ffn_dense``."""
+    with jax.named_scope("ffn_dense"):
+        return feed_forward(x, **kw)
+
+
 # The one place where a layer's kind becomes code.  A mixer is a module
 # class (the block names it ``attention``); a feed-forward a function of the
 # normed state that runs in the block's scope.  Both take that kind's
@@ -496,7 +514,7 @@ def _experts_and_shared(x, shared_intermediate_size: int, **kw):
 MIXERS = {FULL: functools.partial(Attention, kind=FULL),
           SLIDING: functools.partial(Attention, kind=SLIDING),
           LATENT: LatentAttention, MAMBA2: Mamba2}
-FEED_FORWARDS = {"dense": feed_forward, "sparse": _experts(HeldMoEMLP),
+FEED_FORWARDS = {"dense": _dense, "sparse": _experts(HeldMoEMLP),
                  "sparse_shared": _experts_and_shared,
                  "capacity": _experts(MoEMLP)}
 
@@ -510,6 +528,18 @@ def _entry(table: dict, what: str, kind: str | None, keywords: dict):
     return functools.partial(table[kind], **keywords[kind])
 
 
+def _in_scope(scope: str) -> Callable:
+    """A layer's ``fn`` that applies its module under ``scope``."""
+    def fn(mod, x, train):
+        with jax.named_scope(scope):
+            return _plain_fn(mod, x, train)
+    return fn
+
+
+_EMBED_FN, _FINAL_NORM_FN, _HEAD_FN = map(
+    _in_scope, ("embed", "norm_residual", "head"))
+
+
 def decoder_specs(layers, mixers: dict, feed_forwards: dict, *,
                   vocab_size: int, hidden_size: int, eps: float,
                   dtype=jnp.float32) -> tuple:
@@ -517,10 +547,12 @@ def decoder_specs(layers, mixers: dict, feed_forwards: dict, *,
     :class:`DecoderBlock` for each ``(mixer kind, feed-forward kind)`` of
     ``layers`` (None for a half the layer lacks), the final RMSNorm, the
     untied head.  ``mixers`` and ``feed_forwards`` hold, by kind, the
-    keywords of the table's entry."""
+    keywords of the table's entry.  Scopes, in both passes: ``embed``
+    (the gather, and its scatter-add), ``norm_residual`` (the final norm,
+    as a block's norms) and ``head``."""
     specs = [LayerSpec("layer1", make=functools.partial(
         nn.Embed, num_embeddings=vocab_size, features=hidden_size,
-        dtype=dtype), fn=_plain_fn)]
+        dtype=dtype), fn=_EMBED_FN)]
     for mixer, feed_forward in layers:
         specs.append(LayerSpec(
             f"layer{1 + len(specs)}", make=functools.partial(
@@ -530,10 +562,10 @@ def decoder_specs(layers, mixers: dict, feed_forwards: dict, *,
                                     feed_forward, feed_forwards),
                 eps=eps, dtype=dtype), fn=_plain_fn))
     specs.append(LayerSpec(f"layer{1 + len(specs)}", make=functools.partial(
-        nn.RMSNorm, epsilon=eps, dtype=dtype), fn=_plain_fn))
+        nn.RMSNorm, epsilon=eps, dtype=dtype), fn=_FINAL_NORM_FN))
     specs.append(LayerSpec(f"layer{1 + len(specs)}", make=functools.partial(
         nn.Dense, features=vocab_size, use_bias=False, dtype=dtype),
-        fn=_plain_fn))
+        fn=_HEAD_FN))
     return tuple(specs)
 
 
