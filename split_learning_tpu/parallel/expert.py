@@ -224,6 +224,28 @@ SCORING = {"softmax": (functools.partial(jax.nn.softmax, axis=-1), False, 0.0),
            "sigmoid": (jax.nn.sigmoid, True, 1e-20)}
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chosen(scores, top_s, top_i, e: int):
+    """``top_s`` (T, k), the ``scores`` (T, E) at ``top_i``, passed through
+    as they were taken.  The gradient into ``scores`` is a one-hot select
+    over the ``e`` experts, where a gather's transpose would scatter-add: a
+    token's choices are distinct, so each ``(t, e)`` takes at most one
+    term and the sum is exact."""
+    return top_s
+
+
+def _chosen_fwd(scores, top_s, top_i, e):
+    return top_s, top_i
+
+
+def _chosen_bwd(e, top_i, g):
+    hit = top_i[:, :, None] == jnp.arange(e, dtype=top_i.dtype)
+    return jnp.sum(jnp.where(hit, g[:, :, None], 0), axis=1), None, None
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
+
+
 def route_held(logits: jnp.ndarray, k: int, first: int, n_held: int,
                scoring: str = "softmax", bias=None, factor: float = 1.0):
     """Top-``k`` routing over ALL experts, and the plan for the experts
@@ -250,21 +272,30 @@ def route_held(logits: jnp.ndarray, k: int, first: int, n_held: int,
       whether its expert is held (then its row is under the sum of
       ``group_sizes``, itself at most ``min(k, n_held) * T``);
     * ``group_sizes`` (n_held,): pairs of each held expert, in order.
+
+    Neither pass holds a scatter: the counts are a compare-and-sum, a
+    pair's sorted row is its group's start plus its rank in the group
+    (what the stable sort gives), and the chosen scores' gradient is a
+    one-hot select (:func:`_chosen`).  On the chip's host every scatter,
+    gather, sort or top-k costs the compiler a second or more.
     """
     t, e = logits.shape
     score_fn, divide, guard = SCORING[scoring]
     scores = score_fn(logits)
+    fixed = jax.lax.stop_gradient(scores)
     if bias is None:
-        top_s, top_i = jax.lax.top_k(scores, k)
+        top_s, top_i = jax.lax.top_k(fixed, k)
     else:
-        _, top_i = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(bias)[None, :], k)
-        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+        _, top_i = jax.lax.top_k(fixed + jax.lax.stop_gradient(bias), k)
+        top_s = jnp.take_along_axis(fixed, top_i, axis=-1)
+    top_s = _chosen(scores, top_s, top_i, e)
     chosen = jnp.sum(top_s, axis=-1, keepdims=True)
     weights = top_s / (chosen + guard if guard else chosen)
     if factor != 1.0:
         weights = weights * factor
-    counts = jnp.zeros((e,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
+    # exact in float32: at most T * k ones an expert, under 2 ** 24
+    counts = jnp.sum(top_i[:, :, None] == jnp.arange(e, dtype=top_i.dtype),
+                     axis=(0, 1), dtype=jnp.float32)
     shares = scores / jnp.sum(scores, axis=-1, keepdims=True) if divide \
         else scores
     aux = e * jnp.sum(counts / t * jnp.mean(shares, axis=0))
@@ -273,10 +304,17 @@ def route_held(logits: jnp.ndarray, k: int, first: int, n_held: int,
     held = (local >= 0) & (local < n_held)
     key = jnp.where(held, local, n_held).astype(jnp.int32)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    pair_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))
+    sizes = counts[first:first + n_held].astype(jnp.int32)
+    # group g (the absent experts last) starts where the groups before it
+    # end; a pair's rank in its group is the group's pairs before it.  The
+    # groups lead, so the pairs lie along the lanes
+    starts = jnp.cumsum(jnp.concatenate([jnp.zeros((1,), jnp.int32), sizes]))
+    in_group = jnp.arange(n_held + 1, dtype=jnp.int32)[:, None] == key
+    rank = jnp.cumsum(in_group, axis=1, dtype=jnp.int32) - 1
+    pair_row = jnp.sum(jnp.where(in_group, starts[:, None] + rank, 0),
+                       axis=0)
     plan = {"order": order, "pair_row": pair_row, "held": held,
-            "group_sizes": counts[first:first + n_held].astype(jnp.int32)}
+            "group_sizes": sizes}
     return weights, plan, aux
 
 

@@ -9,6 +9,8 @@ mellum2_12b_c3.py moe_layer``): every held expert on every token, weighted
 by what the router gave it."""
 
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -509,6 +511,154 @@ def test_the_softmax_setting_is_the_parents_routing_bit_for_bit(layer):
                     jax.tree_util.tree_leaves(want)):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the plan with no scatter, against the scatter form -------------------------
+
+def _scatter_route_held(logits, k, first, n_held, scoring="softmax",
+                        bias=None, factor=1.0):
+    """``route_held`` as it stood with its scatters (the counts' scatter-add,
+    the inverse permutation's scatter, the gather's transpose in the
+    gradient), word for word."""
+    t, e = logits.shape
+    score_fn, divide, guard = expert.SCORING[scoring]
+    scores = score_fn(logits)
+    if bias is None:
+        top_s, top_i = jax.lax.top_k(scores, k)
+    else:
+        _, top_i = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias)[None, :], k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    chosen = jnp.sum(top_s, axis=-1, keepdims=True)
+    weights = top_s / (chosen + guard if guard else chosen)
+    if factor != 1.0:
+        weights = weights * factor
+    counts = jnp.zeros((e,), jnp.float32).at[top_i.reshape(-1)].add(1.0)
+    shares = scores / jnp.sum(scores, axis=-1, keepdims=True) if divide \
+        else scores
+    aux = e * jnp.sum(counts / t * jnp.mean(shares, axis=0))
+
+    local = top_i.reshape(-1) - first
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    pair_row = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    plan = {"order": order, "pair_row": pair_row, "held": held,
+            "group_sizes": counts[first:first + n_held].astype(jnp.int32)}
+    return weights, plan, aux
+
+
+def _routing_case(name):
+    """(x (T, 16), router (16, E), k, first, n_held, scoring, bias, factor)
+    of a named case."""
+    t, e, k, first, n_held, scoring, biased, factor = {
+        "softmax_k8_of_64": (256, 64, 8, 0, 8, "softmax", False, 1.0),
+        "sigmoid_k6_of_64": (256, 64, 6, 0, 8, "sigmoid", True, 2.446),
+        "sigmoid_k6_of_128": (192, 128, 6, 0, 8, "sigmoid", True, 2.5),
+        "a_share_past_the_first": (256, 64, 8, 20, 5, "softmax", False, 1.0),
+        "ties": (256, 64, 8, 8, 8, "sigmoid", True, 2.446),
+        "none_held": (256, 64, 8, 8, 8, "softmax", False, 1.0),
+        "all_held": (256, 64, 8, 0, 64, "softmax", False, 1.0),
+        "pairs_no_multiple_of_128": (37, 64, 6, 4, 8, "sigmoid", True, 2.5),
+    }[name]
+    x = jax.random.normal(jax.random.key(31), (t, 16))
+    router = jax.random.normal(jax.random.key(32), (16, e))
+    if name == "ties":
+        # coarse inputs and router: many tokens' logits tie exactly, and
+        # so do experts within a token
+        x = jnp.round(x)
+        router = jnp.round(router * 2) / 4
+    if name == "none_held":
+        router = router.at[:, first:first + n_held].set(0.0)
+        x = jnp.concatenate([x[:, :-1], jnp.ones((t, 1))], axis=1)
+        router = router.at[-1, first:first + n_held].set(-1e3)
+    bias = 0.3 * jax.random.normal(jax.random.key(33), (e,)) if biased \
+        else None
+    return x, router, k, first, n_held, scoring, bias, factor
+
+
+ROUTING_CASES = ("softmax_k8_of_64", "sigmoid_k6_of_64", "sigmoid_k6_of_128",
+                 "a_share_past_the_first", "ties", "none_held", "all_held",
+                 "pairs_no_multiple_of_128")
+
+
+@pytest.mark.parametrize("case", ROUTING_CASES)
+def test_the_plan_and_the_routers_gradient_are_the_scatter_forms(case):
+    """Bit for bit: every leaf of what ``route_held`` returns, and the
+    router's gradient of a weighted sum of the weights plus ``aux``."""
+    x, router, k, first, n_held, scoring, bias, factor = _routing_case(case)
+    t = x.shape[0]
+    mix = jax.random.normal(jax.random.key(34), (t, k))
+
+    def routed(fn, w):
+        return fn(_mm("td,de->te", x, w), k, first, n_held, scoring, bias,
+                  factor)
+
+    def objective(fn):
+        def f(w):
+            weights, _, aux = routed(fn, w)
+            return jnp.sum(weights * mix) + aux
+        return jax.jit(jax.grad(f))
+
+    got = jax.jit(lambda w: routed(route_held, w))(router)
+    want = jax.jit(lambda w: routed(_scatter_route_held, w))(router)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    grad, grad_want = (objective(route_held)(router),
+                       objective(_scatter_route_held)(router))
+    assert np.asarray(grad_want).any()
+    np.testing.assert_array_equal(np.asarray(grad), np.asarray(grad_want))
+    held = int(np.asarray(got[1]["held"]).sum())
+    if case == "none_held":
+        assert held == 0
+    elif case == "all_held":
+        assert held == t * k
+    elif case == "ties":
+        scores = jax.nn.sigmoid(_mm("td,de->te", x, router))
+        assert len(np.unique(np.asarray(scores))) < scores.size // 4
+
+
+def _instructions(lowered) -> dict:
+    text = lowered.as_text()
+    return {"scatter": len(re.findall(r'"stablehlo\.scatter"', text)),
+            "sort": len(re.findall(r'"stablehlo\.sort"', text)),
+            "gather": len(re.findall(r'"stablehlo\.gather"', text)),
+            "top_k": len(re.findall(r"chlo\.top_k", text))}
+
+
+@pytest.mark.parametrize("case", ["softmax_k8_of_64", "sigmoid_k6_of_64"])
+def test_the_plan_and_its_gradient_lower_with_no_scatter(case):
+    """Each scatter, gather, sort or top-k costs the chip host's compiler a
+    second or more: ``route_held`` and the VJP of its weights hold no
+    scatter, and no more of the others than the scatter form (one sort; a
+    gather on the biased path alone)."""
+    x, router, k, first, n_held, scoring, bias, factor = _routing_case(case)
+    logits = _mm("td,de->te", x, router)
+    ct = jnp.ones((x.shape[0], k))
+
+    def lowered(fn):
+        def plan(z):
+            return fn(z, k, first, n_held, scoring, bias, factor)
+
+        def vjp(z, g):
+            w, pull = jax.vjp(lambda z: plan(z)[0], z)
+            return w, pull(g)
+        return (_instructions(jax.jit(plan).lower(logits)),
+                _instructions(jax.jit(vjp).lower(logits, ct)))
+
+    (fwd, back), (fwd_was, back_was) = (lowered(route_held),
+                                        lowered(_scatter_route_held))
+    assert fwd_was["scatter"] == 2 and back_was["scatter"] >= 1
+    assert fwd["scatter"] == 0 and back["scatter"] == 0
+    assert fwd["sort"] == 1 and fwd["top_k"] == 1
+    assert fwd["gather"] == (0 if bias is None else 1)
+    for name in ("sort", "gather", "top_k"):
+        assert fwd[name] <= fwd_was[name] and back[name] <= back_was[name]
 
 
 @pytest.fixture(scope="module")
