@@ -25,7 +25,7 @@ import split_learning_tpu.models.kwt  # noqa: F401  (registers KWT_*)
 import split_learning_tpu.models.vit  # noqa: F401  (registers ViT_*)
 import split_learning_tpu.models.mobilenet  # noqa: F401  (MobileNetv1_*)
 import split_learning_tpu.models.resnet  # noqa: F401  (ResNet50_*)
-import split_learning_tpu.models.decoder  # noqa: F401  (TinyLlama*, Mellum2_*, Moonlight_*, NemotronH_*)
+import split_learning_tpu.models.decoder  # noqa: F401  (TinyLlama*, Mellum2_*, Moonlight_*, NemotronH_*, Laguna_*)
 
 __all__ = [
     "LayerSpec", "SplitModel", "build_model", "model_registry",

@@ -14,7 +14,9 @@ feed-forward is one module and one table entry.
 
 The mixers there are: grouped-query attention, no bias (:class:`Attention`),
 ``full_attention`` or ``sliding_attention`` (a query at ``p`` sees keys
-``p - window + 1 .. p``), each kind with its own RoPE or none;
+``p - window + 1 .. p``), each kind with its own head count, its own RoPE
+(over the whole head, a leading share of it, or none) and, where asked, a
+sigmoid gate a head on its result;
 ``latent_attention`` (:class:`LatentAttention`: keys and values expanded
 from one low-rank latent a token, a rotary part that all heads share); and
 ``mamba2`` (:class:`Mamba2`: a state-space layer, its recurrence the
@@ -108,24 +110,36 @@ def yarn_inv_freq(head_dim: int, rope_theta: float, factor: float,
 def rope_of(kind: str, head_dim: int, rope_parameters: dict):
     """``(inv_freq, factor on cos and sin)`` of one kind of layer; None
     where its ``rope_type`` is ``none`` (a layer that turns nothing:
-    position reaches it through other layers)."""
+    position reaches it through other layers).  A ``partial_rotary_factor``
+    under 1 turns the first ``head_dim * factor`` dims of a head alone: the
+    frequencies (YaRN's too) are those of a head that wide, and
+    :func:`rope` passes the rest through."""
     p = rope_parameters[kind]
     if p.get("rope_type", "default") == "none":
         return None
+    rotary_dim = int(head_dim * p.get("partial_rotary_factor", 1))
     if p.get("rope_type", "default") == "yarn":
-        return yarn_inv_freq(head_dim, **{k: v for k, v in p.items()
-                                          if k != "rope_type"}), \
+        return yarn_inv_freq(rotary_dim, **{
+            k: v for k, v in p.items()
+            if k not in ("rope_type", "partial_rotary_factor")}), \
             float(p.get("attention_factor", 1.0))
-    return rope_inv_freq(head_dim, p["rope_theta"]), 1.0
+    return rope_inv_freq(rotary_dim, p["rope_theta"]), 1.0
 
 
 def rope(x: jnp.ndarray, positions: jnp.ndarray, inv_freq: np.ndarray,
          interleaved: bool = True, factor: float = 1.0) -> jnp.ndarray:
-    """Rotary embedding over the last dim of (B, S, H, D).  The caller
-    says which frequencies (``inv_freq``, D/2 of them), which pairing —
+    """Rotary embedding over the first ``2 * len(inv_freq)`` dims of the
+    last axis of (B, S, H, D); the dims past them pass unchanged.  The
+    caller says which frequencies (``inv_freq``), which pairing —
     ``interleaved`` turns the pairs (2i, 2i + 1), the half-split
-    (``rotate_half``) convention the pairs (i, i + D/2) — and a
-    ``factor`` on cos and sin (YaRN's attention factor)."""
+    (``rotate_half``) convention the pairs (i, i + R/2) of the rotated
+    width R — and a ``factor`` on cos and sin (YaRN's attention factor,
+    so it scales the rotated dims alone)."""
+    rotary_dim = 2 * len(inv_freq)
+    if rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, inv_freq, interleaved,
+                  factor), x[..., rotary_dim:]], axis=-1)
     freqs = positions[:, None].astype(jnp.float32) * inv_freq[None, :]
     cos = factor * jnp.cos(freqs)[None, :, None, :]
     sin = factor * jnp.sin(freqs)[None, :, None, :]
@@ -196,10 +210,14 @@ class Attention(nn.Module):
     kernel (``ops/flash_attention.py``: O(S) memory; it picks a query
     head's key-value head by index and skips the key blocks outside the
     window).  Else the scores are an einsum and the mask is applied to
-    them (small sizes only; the kernel's parity oracle).  Scopes, in both
-    passes: ``attn_proj`` around the projections, their reshapes and the
-    RoPE, and again around ``o_proj``; ``attn_window`` or ``attn_full``
-    between them, around the scores and values.
+    them (small sizes only; the kernel's parity oracle).  With ``gating``
+    each head's result is multiplied by its own gate before ``o_proj``:
+    ``sigmoid(x W_g)``, ``g_proj`` one scalar a head and token, no bias
+    (:func:`head_gate`).  Scopes, in both passes: ``attn_proj`` around the
+    projections, their reshapes and the RoPE, and again around the gate
+    and ``o_proj``, with ``attn_gate`` inside it around the gate alone;
+    ``attn_window`` or ``attn_full`` between them, around the scores and
+    values.
     """
     hidden_size: int
     num_heads: int
@@ -209,6 +227,7 @@ class Attention(nn.Module):
     kind: str = FULL
     interleaved: bool = False
     window: int | None = None
+    gating: bool = False
     use_flash: bool = False
     flash_block: int = 512
     seq_axis: str | None = None
@@ -253,8 +272,19 @@ class Attention(nn.Module):
                                         self.use_flash, self.flash_block,
                                         window)
         with jax.named_scope("attn_proj"):
+            if self.gating:
+                with jax.named_scope("attn_gate"):
+                    out = head_gate(
+                        out.reshape(b, s, self.num_heads, hd),
+                        dense(self.num_heads, name="g_proj")(x))
             return dense(self.hidden_size, name="o_proj")(
                 out.reshape(b, s, self.num_heads * hd))
+
+
+def head_gate(out: jnp.ndarray, gate_logits: jnp.ndarray) -> jnp.ndarray:
+    """Each head's result (B, S, H, D) times the sigmoid of its own gate
+    logit (B, S, H)."""
+    return out * nn.sigmoid(gate_logits)[..., None]
 
 
 class LatentAttention(nn.Module):
@@ -729,6 +759,108 @@ def moonlight_tinystories(
          "sparse_shared": dict(
              experts, shared_intermediate_size=(
                  n_shared_experts * moe_intermediate_size))},
+        vocab_size=vocab_size, hidden_size=hidden_size, eps=rms_norm_eps,
+        dtype=dtype)
+
+
+#: Laguna-XS.2's published ``rope_parameters``: YaRN over half of each head
+#: in the full layers, plain RoPE over the whole head in the sliding ones
+LAGUNA_ROPE_PARAMETERS = {
+    FULL: {"rope_theta": 500000.0, "rope_type": "yarn", "factor": 64.0,
+           "original_max_position_embeddings": 4096, "beta_slow": 1.0,
+           "beta_fast": 64.0, "attention_factor": 1.4158883083359672,
+           "partial_rotary_factor": 0.5},
+    SLIDING: {"rope_type": "default", "rope_theta": 10000.0,
+              "partial_rotary_factor": 1}}
+LAGUNA_PERIOD = (FULL, SLIDING, SLIDING, SLIDING)
+
+
+@register_model("Laguna_TINYSTORIES")
+def laguna_tinystories(
+        vocab_size: int = 100352, hidden_size: int = 2048,
+        intermediate_size: int = 8192, num_hidden_layers: int = 40,
+        num_attention_heads: int = 48, num_key_value_heads: int = 8,
+        head_dim: int = 128, rms_norm_eps: float = 1e-6,
+        layer_types: tuple | None = None,
+        mlp_layer_types: tuple | None = None,
+        num_attention_heads_per_layer: tuple | None = None,
+        sliding_window: int = 512, rope_parameters: dict | None = None,
+        partial_rotary_factor: float = 1.0, gating: bool | str = True,
+        attention_bias: bool = False, num_experts: int = 256,
+        num_experts_per_tok: int = 8, moe_intermediate_size: int = 512,
+        shared_expert_intermediate_size: int = 512,
+        moe_routed_scaling_factor: float = 2.5, norm_topk_prob: bool = True,
+        moe_apply_router_weight_on_input: bool = False,
+        moe_router_logit_softcapping: float = 0.0,
+        experts_held: int | tuple | None = None, use_flash: bool = False,
+        flash_block: int = 512, dtype=jnp.float32) -> tuple:
+    """Laguna-XS.2 (``model_type`` ``laguna``) under the keys of the
+    published configuration
+    (https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json);
+    input (B, S) int32 token ids, output (B, S, vocab) next-token logits.
+    Layer ``i`` is the attention ``layer_types[i]`` names (by default the
+    published period: one ``full_attention``, three ``sliding_attention``
+    over the last ``sliding_window`` keys) with
+    ``num_attention_heads_per_layer[i]`` query heads over
+    ``num_key_value_heads``, the RoPE of its kind in ``rope_parameters``
+    (each kind's ``partial_rotary_factor``, else the top-level one: the
+    share of a head that turns) and, with ``gating``, a sigmoid gate a head
+    on its result; then the feed-forward ``mlp_layer_types[i]`` names: a
+    ``dense`` SwiGLU of ``intermediate_size``, or ``sparse``:
+    ``num_experts`` SwiGLU experts of ``moe_intermediate_size``,
+    ``num_experts_per_tok`` a token by softmax score, the weights
+    renormalized and multiplied by ``moe_routed_scaling_factor``, beside a
+    shared SwiGLU expert of ``shared_expert_intermediate_size``.
+    ``experts_held`` as in ``Mellum2_TINYSTORIES``.  What has no module
+    here is refused: a gate that is not one a head, a bias in attention,
+    router logits soft-capped, the router's weight applied to an expert's
+    input, weights that are not renormalized, a head count that differs
+    between two layers of one kind, a kind outside the tables."""
+    kinds = tuple(layer_types) if layer_types is not None else tuple(
+        LAGUNA_PERIOD[i % 4] for i in range(num_hidden_layers))
+    ffs = tuple(mlp_layer_types) if mlp_layer_types is not None else (
+        ("dense",) + ("sparse",) * (num_hidden_layers - 1))
+    heads = tuple(num_attention_heads_per_layer) \
+        if num_attention_heads_per_layer is not None \
+        else (num_attention_heads,) * num_hidden_layers
+    heads_of = dict(zip(kinds, heads))
+    if gating not in (True, False, "per-head", "per_head") or attention_bias \
+            or moe_router_logit_softcapping or moe_apply_router_weight_on_input \
+            or not norm_topk_prob \
+            or any(heads_of[k] != n for k, n in zip(kinds, heads)) \
+            or not set(kinds) <= {FULL, SLIDING} \
+            or not set(ffs) <= {"dense", "sparse"} \
+            or not len(kinds) == len(ffs) == len(heads) == num_hidden_layers:
+        raise ValueError(
+            f"no module for gating={gating!r}, "
+            f"attention_bias={attention_bias}, "
+            f"moe_router_logit_softcapping={moe_router_logit_softcapping}, "
+            f"moe_apply_router_weight_on_input="
+            f"{moe_apply_router_weight_on_input}, "
+            f"norm_topk_prob={norm_topk_prob}, or {num_hidden_layers} "
+            f"layers of kinds {kinds}, {ffs} and heads {heads}")
+    turns = dict(rope_parameters or LAGUNA_ROPE_PARAMETERS)
+    attention = dict(
+        hidden_size=hidden_size, num_kv_heads=num_key_value_heads,
+        head_dim=head_dim, rope_parameters={
+            kind: {"partial_rotary_factor": partial_rotary_factor,
+                   **turns[kind]} for kind in heads_of},
+        gating=bool(gating), use_flash=use_flash, flash_block=flash_block,
+        dtype=dtype)
+    # a published ``sparse`` layer holds the shared expert too
+    return decoder_specs(
+        [(kind, "sparse_shared" if ff == "sparse" else ff)
+         for kind, ff in zip(kinds, ffs)],
+        {kind: dict(attention, num_heads=n,
+                    window=sliding_window if kind == SLIDING else None)
+         for kind, n in heads_of.items()},
+        {"dense": dict(intermediate_size=intermediate_size, dtype=dtype),
+         "sparse_shared": dict(
+             intermediate_size=moe_intermediate_size,
+             num_experts=num_experts, k=num_experts_per_tok,
+             held=_held(experts_held), scoring="softmax",
+             factor=moe_routed_scaling_factor, dtype=dtype,
+             shared_intermediate_size=shared_expert_intermediate_size)},
         vocab_size=vocab_size, hidden_size=hidden_size, eps=rms_norm_eps,
         dtype=dtype)
 
