@@ -18,6 +18,13 @@ matrix never leaves the core: O(S) memory, MXU-shaped (block_q x D) @
   ``slt_flash_bwd_dq``: a grid over query rows, walking key blocks.
   Probabilities are rebuilt as ``exp(s - lse)`` (no second online pass
   needed), and ``delta = rowsum(dO * O)`` is a cheap XLA-fused pre-pass.
+* the per-row statistics ``lse`` and ``delta`` travel between the calls
+  as lane-dense rows (B*heads, 1, S).  As (B*heads, S, 1) columns, the
+  shape a kernel's row statistics have in registers, HBM pads them to
+  128 lanes: XLA then spent more time turning and padding them around
+  the calls than the calls' own copies of ``q``, ``k`` and ``v`` cost
+  (PERF.md, section 6).  A kernel turns a tile's column into a row, or
+  back, on the transpose unit (:func:`_as_row`).
 * ``window`` (causal only): a query at ``p`` sees keys ``p - window + 1
   .. p``.  No kernel walks a block wholly outside that band, so a window
   of a quarter of the row does about a quarter of a full layer's work;
@@ -363,6 +370,23 @@ def _band(s, offsets, q0, k0, window, edges):
     return jnp.where(seen, s, NEG_INF)
 
 
+#: lanes of a vector register
+LANES = 128
+
+
+def _as_row(col):
+    """An (n, 1) float32 column as a (1, n) row: broadcast over the lanes
+    and turned on the transpose unit.  Reshaped in place, a vector turned
+    from a column into a row costs a kernel about a nanosecond an element
+    on a TPU v5e (PERF.md, section 6)."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], LANES)))[:1]
+
+
+def _as_column(row):
+    """:func:`_as_row`'s inverse: a (1, n) row as an (n, 1) column."""
+    return jnp.transpose(jnp.broadcast_to(row, (LANES, row.shape[1])))[:, :1]
+
+
 def _sub_tiles(n: int, body):
     """``body(j)`` for the ``n`` sub-tiles of a grid step's rows."""
     if n == 1:
@@ -418,7 +442,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, tile: Tiling,
             o_ref[0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
             # logsumexp of the SCALED scores: exp(s - lse) rebuilds softmax
             # rows exactly in the backward kernels
-            lse_ref[0, rows, :] = m + jnp.log(l_safe)
+            lse_ref[0, :, rows] = _as_row(m + jnp.log(l_safe))
 
         # causal: K/V blocks entirely in these queries' future (or behind
         # their window) contribute exactly zero — never walked
@@ -437,9 +461,9 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool, tile: Tiling,
     are taken over ``D``, the result is ``Dv`` wide.
 
     ``lse`` (and the backward's ``delta``) are per-row values kept as
-    ``(BH, S, 1)`` columns: a ``(block_q, 1)`` block is legal on TPU
-    where a ``(1, block_q)`` slice of a ``(BH, S)`` array is not, and
-    it is the shape the kernels' row statistics already have."""
+    ``(BH, 1, S)`` rows: a ``(1, 1, block_q)`` block is legal on TPU
+    where a ``(1, block_q)`` slice of a ``(BH, S)`` array is not, and a
+    ``(BH, S, 1)`` column is padded to 128 lanes in HBM."""
     bh, s, d = q.shape
     dv = v.shape[-1]
     scale = 1.0 / np.sqrt(d)
@@ -450,7 +474,7 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool, tile: Tiling,
     return pl.pallas_call(
         kernel,
         out_shape=[jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
-                   jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)],
         grid=(bh, s // tile.grid),
         in_specs=[
             pl.BlockSpec((1, tile.grid, d), lambda b, i: (b, i, 0)),
@@ -458,7 +482,7 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, interpret: bool, tile: Tiling,
             pl.BlockSpec((1, s, dv), lambda b, i: (b // rep, 0, 0)),
         ],
         out_specs=[pl.BlockSpec((1, tile.grid, dv), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, tile.grid, 1), lambda b, i: (b, i, 0))],
+                   pl.BlockSpec((1, 1, tile.grid), lambda b, i: (b, 0, i))],
         interpret=interpret,
         name="slt_flash_fwd",
     )(q, k, v)
@@ -542,8 +566,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q0 = first + j * block_q
         q = q_ref[0, rows, :]                          # (block_q, D)
         do = do_ref[0, rows, :]
-        lse = lse_ref[0, rows, :]                      # (block_q, 1)
-        delta = delta_ref[0, rows, :]
+        lse = _as_column(lse_ref[0, :, rows])          # (block_q, 1)
+        delta = _as_column(delta_ref[0, :, rows])
 
         def block(k0, dq, edges):
             k = k_ref[0, _span(k0, block_k, tile.step), :]
@@ -589,7 +613,7 @@ def _flash_bwd_rule(causal, interpret, tiles, window, rep, res, do):
     _, dq_tile, dkv_tile = tiles
     # delta = rowsum(dO * O): cheap elementwise pre-pass, XLA fuses it
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
-        axis=-1, keepdims=True)
+        axis=-1).reshape(bh, 1, s)
     static = dict(causal=causal, scale=scale, precision=precision,
                   window=window)
 
@@ -599,7 +623,6 @@ def _flash_bwd_rule(causal, interpret, tiles, window, rep, res, do):
     k_block = pl.BlockSpec((1, dkv_tile.grid, d), lambda b, j, r: (b, j, 0))
     v_block = pl.BlockSpec((1, dkv_tile.grid, d_v),
                            lambda b, j, r: (b, j, 0))
-    as_row = lambda t: t.reshape(bh, 1, s)             # noqa: E731
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, tile=dkv_tile, **static),
         out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
@@ -612,12 +635,12 @@ def _flash_bwd_rule(causal, interpret, tiles, window, rep, res, do):
         out_specs=[k_block, v_block],
         interpret=interpret,
         name="slt_flash_bwd_dkv",
-    )(q, k, v, do, as_row(lse), as_row(delta))
+    )(q, k, v, do, lse, delta)
 
     kv_head = lambda b, i: (b // rep, 0, 0)            # noqa: E731
     q_block = pl.BlockSpec((1, dq_tile.grid, d), lambda b, i: (b, i, 0))
     do_block = pl.BlockSpec((1, dq_tile.grid, d_v), lambda b, i: (b, i, 0))
-    row_q = pl.BlockSpec((1, dq_tile.grid, 1), lambda b, i: (b, i, 0))
+    row_q = pl.BlockSpec((1, 1, dq_tile.grid), lambda b, i: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, tile=dq_tile, **static),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),

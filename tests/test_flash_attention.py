@@ -501,3 +501,84 @@ def test_the_cells_widths_lower_for_tpu_under_each_walk(h, kv, d, dv,
                 lowering_platforms=("tpu",)).as_text()
     assert all(name in text for name in (
         "slt_flash_fwd", "slt_flash_bwd_dq", "slt_flash_bwd_dkv"))
+
+
+# -- the cells' head groups and widths -----------------------------------------
+
+# (heads, key-value heads, D, Dv) with heads and rows scaled down: groups of
+# 1, 6 (Laguna's 48 over 8), 8 (its 64 over 8, Mellum's 32 over 4) and 16
+# (Nemotron's 32 over 2) at 128; Moonlight's 192-wide scores over 128-wide
+# values; 64 wide
+_WIDTHS = [(2, 2, 128, 128), (12, 2, 128, 128), (16, 2, 128, 128),
+           (32, 2, 128, 128), (4, 4, 192, 128), (4, 2, 64, 64)]
+_WIDTH_IDS = ["rep1", "rep6", "rep8", "rep16", "mixed_192_128", "narrow_64"]
+
+
+@pytest.mark.parametrize("window", [None, 24], ids=["full", "window"])
+@pytest.mark.parametrize("h,kv,d,dv", _WIDTHS, ids=_WIDTH_IDS)
+def test_the_cells_head_groups_and_widths_forward_and_all_gradients(
+        h, kv, d, dv, window):
+    b, s = 2, 64
+    kq, kk, kv_, kw = jax.random.split(jax.random.key(13), 4)
+    q = jax.random.normal(kq, (b, s, h, d))
+    k = jax.random.normal(kk, (b, s, kv, d))
+    v = jax.random.normal(kv_, (b, s, kv, dv))
+    w = jax.random.normal(kw, (b, s, h, dv))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=16, block_k=32)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)),
+        np.asarray(_dense_two_widths(q, k, v, window)), rtol=2e-5, atol=2e-5)
+    g1 = jax.grad(lambda *a: (flash(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: (_dense_two_widths(*a, window) * w).sum(),
+                  argnums=(0, 1, 2))(q, k, v)
+    for which, a, b_ in zip("qkv", g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=5e-4, atol=5e-4, err_msg=which)
+
+
+def _shapes(jaxpr) -> list:
+    """The shape of every value made in ``jaxpr`` and the jaxprs its
+    equations call."""
+    found = []
+    for eqn in jaxpr.eqns:
+        found += [tuple(v.aval.shape) for v in eqn.outvars
+                  if hasattr(v.aval, "shape")]
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _shapes(inner)
+    return found
+
+
+@pytest.mark.parametrize("window", [None, 96], ids=["full", "window"])
+def test_the_row_statistics_travel_as_rows(window):
+    """``lse`` and ``delta`` pass between the calls as (B*H, 1, S) rows:
+    the forward and its gradients traced whole make no (.., S, 1) column,
+    which HBM would pad to 128 lanes, and both are there as rows."""
+    import jax.numpy as jnp
+    b, s, h, kv, d = 2, 256, 8, 2, 128
+    shapes = _shapes(jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v, w: (flash_attention(
+            q, k, v, causal=True, window=window, block_q=128,
+            block_k=128) * w).sum(), argnums=(0, 1, 2)))(
+        *(jax.ShapeDtypeStruct((b, s, n, d), jnp.bfloat16)
+          for n in (h, kv, kv, h))).jaxpr)
+    assert not [t for t in shapes if t[-2:] == (s, 1)]
+    assert shapes.count((b * h, 1, s)) >= 2
+
+
+def test_a_column_and_a_row_turn_into_each_other_exactly():
+    """:func:`_as_row` and :func:`_as_column` move values, they do not
+    round them (both kernels read the statistics through them)."""
+    col = jax.random.normal(jax.random.key(14), (256, 1)) * 1e3
+    row = fa._as_row(col)
+    assert row.shape == (1, 256)
+    np.testing.assert_array_equal(np.asarray(row), np.asarray(col).T)
+    np.testing.assert_array_equal(np.asarray(fa._as_column(row)),
+                                  np.asarray(col))

@@ -454,18 +454,19 @@ def test_it_trains_through_run_local(tmp_path, monkeypatch):
 #: sha256 of each configuration's toy ``sl_train_step`` lowered on the CPU
 #: (:func:`_lowered_text`), as the commit before these modules' new fields
 #: lowered it: a field whose default changed what an older model compiles
-#: to would show here
+#: to would show here.  The three token configurations' hashes are those of
+#: the flash kernels that pass ``lse`` and ``delta`` as rows
 LOWERED = {
     "bert_base_c7":
         "5dbf7d673644db0b1aab49544c79cba8e8efc00112ada4589b654ff8d47b6c05",
     "vgg16_c7":
         "6a895f5b1b25f335bc9cd759ca240cdd507272267041e41c0df6f7fb9d71b244",
     "mellum2_12b_c3":
-        "358c89a12494f5ad22071e5e881861929df24dccf4840e58c6803e43e313b9d4",
+        "1c0aefd01ee65712910dfebf1a9d7592a065942e79710f63fed9070c0c2fcac1",
     "moonlight_16b_c3":
-        "57c62981cf9ac904513bdea9427cbb7746d29c1bebf37d763262e93109b62a6e",
+        "82b96212a02c519f1799bf2be36aa7bff20754fe33553ddea3f9a69dbb19e04d",
     "nemotron_twotower_30b_c5":
-        "66ed08ce52a5f2f1edbc7dc294faf63add17b628f1b513b4989c01d7175b71d7",
+        "33f6cb00accbada90a59effc0e60214d060edcca72fa6bd802bed375ef407508",
 }
 
 
